@@ -296,9 +296,9 @@ def kernel_vusa_packed():
             got = np.asarray(apply_row_packed(x, p), np.float32)
             np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
             k_blk = autotune_row_packed(x, p)
-            from repro.kernels.ops import on_tpu
+            from repro.kernels.ops import interpret_mode
 
-            interp = not on_tpu()  # both arms on the same execution mode
+            interp = interpret_mode()  # both arms on the same execution mode
             f_dense = jax.jit(lambda a: dense_matmul_ref(a, wj))
             f_before = jax.jit(
                 lambda a: vusa_packed_matmul(

@@ -326,15 +326,13 @@ def validate_rows(p: RowPacked) -> None:
 
 
 def unpack_rows(p: RowPacked) -> np.ndarray:
-    t, k, slots = p.values.shape
-    w = np.zeros((k, t * p.m), dtype=p.values.dtype)
-    for ti in range(t):
-        for r in range(k):
-            for s in range(slots):
-                pos = int(p.row_positions[ti, r, s])
-                if pos >= 0:
-                    w[r, ti * p.m + pos] += p.values[ti, r, s]
-    return w[:, : p.c]
+    t, k, _ = p.values.shape
+    w = np.zeros((k, t, p.m), dtype=p.values.dtype)
+    ti, r, s = np.nonzero(np.asarray(p.row_positions) >= 0)
+    lane = np.asarray(p.row_positions)[ti, r, s].astype(np.int64)
+    # add.at accumulates repeated (row, lane) slots, as the kernel's sum does
+    np.add.at(w, (r, ti, lane), np.asarray(p.values)[ti, r, s])
+    return w.reshape(k, t * p.m)[:, : p.c]
 
 
 # --------------------------------------------------------------------------
@@ -350,7 +348,8 @@ class QuantizedRowPacked:
     """Row-wise VUSA pack with integer-quantized value slots (DESIGN.md §10).
 
     values:    (T, K, S) int8 for ``int8``; (T, K, S//2) int8 for ``int4``
-               (two slots per byte: slot 2i in the low nibble, 2i+1 high)
+               (two slots per byte: slot i in the low nibble of byte i,
+               slot S/2 + i in its high nibble)
     positions: (T, K, S) int8  lane index within window (-1 = idle) —
                always full-resolution regardless of value dtype
     scales:    (T, K) float32  per-(window, row) dequant scale; all-zero
@@ -382,12 +381,14 @@ class QuantizedRowPacked:
 def pack_nibbles(q: np.ndarray) -> np.ndarray:
     """Pack int4-range int8 values (..., S) into (..., S//2) bytes, S even.
 
-    Slot ``2i`` lands in the low nibble, ``2i+1`` in the high nibble, so the
-    kernel's shift/mask decode walks slots in order."""
+    Slot ``i < S/2`` lands in the low nibble of byte ``i`` and slot
+    ``S/2 + i`` in its high nibble, so the kernel decodes each half of the
+    slots with one shift and never interleaves lanes."""
     if q.shape[-1] % 2:
         raise ValueError(f"slot count {q.shape[-1]} must be even to nibble-pack")
     u = q.astype(np.uint8)
-    lo, hi = u[..., 0::2], u[..., 1::2]
+    half = q.shape[-1] // 2
+    lo, hi = u[..., :half], u[..., half:]
     return (((hi & 0xF) << 4) | (lo & 0xF)).astype(np.int8)
 
 
@@ -399,10 +400,7 @@ def unpack_nibbles(b: np.ndarray) -> np.ndarray:
     b = b.astype(np.int8)
     lo = ((b << 4) >> 4).astype(np.int8)
     hi = (b >> 4).astype(np.int8)
-    out = np.empty(b.shape[:-1] + (b.shape[-1] * 2,), dtype=np.int8)
-    out[..., 0::2] = lo
-    out[..., 1::2] = hi
-    return out
+    return np.concatenate([lo, hi], axis=-1)
 
 
 def quantize_rows(p: RowPacked, value_dtype: str) -> QuantizedRowPacked:

@@ -38,7 +38,7 @@ def dense_matmul(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     m, k = x.shape
     _, n = w.shape

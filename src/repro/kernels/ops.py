@@ -1,6 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-* auto-selects interpret mode off-TPU (this container is CPU-only);
+* runs the kernels in interpret mode on the CPU backend and compiled on
+  every other backend (``interpret_mode``);
 * hosts the pack/apply glue so a model layer can swap a dense matmul for a
   VUSA-packed one in a single call.
 """
@@ -20,6 +21,7 @@ from .vusa_spmm import vusa_spmm
 
 __all__ = [
     "on_tpu",
+    "interpret_mode",
     "PackedLinear",
     "pack_linear",
     "apply_packed",
@@ -30,6 +32,7 @@ __all__ = [
     "pack_linear_rows_t",
     "pack_linear_rows_nm",
     "dequantize_linear_values",
+    "pad_slots",
     "apply_row_packed",
     "apply_row_packed_ref",
     "choose_k_blk",
@@ -46,6 +49,20 @@ __all__ = [
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Resolve a wrapper's ``interpret`` argument.  ``None`` interprets on
+    the CPU backend only; an accelerator backend always compiles the
+    kernels, and asking it to interpret is an error, not a fallback."""
+    cpu = jax.default_backend() == "cpu"
+    if interpret is None:
+        return cpu
+    if interpret and not cpu:
+        raise ValueError(
+            f"Pallas interpret mode is for the CPU backend only, not {jax.default_backend()!r}"
+        )
+    return interpret
 
 
 @dataclasses.dataclass
@@ -87,7 +104,7 @@ def pack_linear(
 
 def apply_packed(x: jax.Array, p: PackedLinear, *, interpret: bool | None = None) -> jax.Array:
     """y = x @ W for packed W.  x: (..., K) -> (..., C)."""
-    interp = (not on_tpu()) if interpret is None else interpret
+    interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     if p.k_padded > p.k:  # weight was K-padded at pack time
@@ -108,7 +125,7 @@ def apply_packed_ref(x: jax.Array, p: PackedLinear) -> jax.Array:
 
 def matmul(x: jax.Array, w: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Dense baseline kernel wrapper (pads to MXU-aligned tiles)."""
-    interp = (not on_tpu()) if interpret is None else interpret
+    interp = interpret_mode(interpret)
     m, k = x.shape
     _, n = w.shape
     bm = 128 if m % 128 == 0 else (8 if m % 8 == 0 else 1)
@@ -227,16 +244,44 @@ def pack_linear_rows_nm(
 
 def dequantize_linear_values(p: RowPackedLinear) -> jax.Array:
     """fp32 (T, K, S) value slots of any pack — the jnp twin of the kernel's
-    VMEM dequant (int4 nibbles decoded with the same arithmetic shifts), used
-    by the reference appliers and fault tooling."""
+    VMEM dequant (int4 nibbles decoded with the same arithmetic shifts: low
+    nibbles are slots ``[0, S/2)``, high nibbles ``[S/2, S)``), used by the
+    reference appliers and fault tooling."""
     raw = p.values
     if p.value_dtype == "dense":
         return raw.astype(jnp.float32)
     if p.value_dtype == "int4":
         lo = jnp.right_shift(jnp.left_shift(raw, 4), 4)
         hi = jnp.right_shift(raw, 4)
-        raw = jnp.stack([lo, hi], axis=-1).reshape(raw.shape[:-1] + (raw.shape[-1] * 2,))
+        raw = jnp.concatenate([lo, hi], axis=-1)
     return raw.astype(jnp.float32) * p.scales.astype(jnp.float32)[..., None]
+
+
+def pad_slots(p: RowPackedLinear, slots: int) -> RowPackedLinear:
+    """Pad a pack's slot axis to ``slots`` with idle slots (value 0,
+    position -1), exact no-ops.  An int4 pack pads its value bytes with
+    zeros at the end, which appends idle slots to *both* nibble halves, so
+    its positions pad each half to ``slots / 2``."""
+    s = p.slots
+    if slots == s:
+        return p
+    assert slots > s, (slots, s)
+
+    def idle(q, n):
+        return jnp.pad(q, ((0, 0), (0, 0), (0, n)), constant_values=-1)
+
+    if p.value_dtype == "int4":
+        assert slots % 2 == 0, slots
+        h, pad = s // 2, (slots - s) // 2
+        positions = jnp.concatenate(
+            [idle(p.positions[..., :h], pad), idle(p.positions[..., h:], pad)], axis=-1
+        )
+        vpad = pad
+    else:
+        positions, vpad = idle(p.positions, slots - s), slots - s
+    return dataclasses.replace(
+        p, values=jnp.pad(p.values, ((0, 0), (0, 0), (0, vpad))), positions=positions
+    )
 
 
 # -- k_blk / m tuning ------------------------------------------------------
@@ -251,11 +296,23 @@ _KBLK_CACHE: dict = {}  # (k, slots, m, b, backend) -> k_blk
 _VMEM_SCRATCH_BUDGET = 2 * 1024 * 1024  # bytes for the one-hot scatter tensor
 
 
-def _kblk_candidates(k: int):
-    c = [blk for blk in (64, 128, 256, 512, 1024) if k % blk == 0 and blk <= k]
-    if k <= 2048 and k not in c:
-        c.append(k)
-    return c or [k]
+def _kblk_candidates(k: int, slots: int, m: int):
+    """K blocks worth trying for a reduction of ``k`` rows, ascending.
+
+    On the TPU a block must be lane-aligned — a multiple of 128 that divides
+    ``k``, or all of ``k`` — and its one-hot scatter scratch, ``k_blk *
+    min(slots, slot_chunk) * m * 4`` bytes, must fit the VMEM budget: Mosaic
+    refuses the rest.  When no aligned block fits, the smallest aligned one
+    is the only candidate.  Off the TPU (interpret mode) there is no such
+    wall, and powers of two from 64 plus ``k`` itself are tried."""
+    if not on_tpu():
+        c = [blk for blk in (64, 128, 256, 512, 1024) if k % blk == 0 and blk <= k]
+        if k <= 2048 and k not in c:
+            c.append(k)
+        return c or [k]
+    aligned = [blk for blk in range(128, k + 1, 128) if k % blk == 0] or [k]
+    scratch = min(slots, DEFAULT_SLOT_CHUNK) * m * 4
+    return [blk for blk in aligned if blk * scratch <= _VMEM_SCRATCH_BUDGET] or aligned[:1]
 
 
 def _largest_divisor_leq(k: int, blk: int) -> int:
@@ -276,15 +333,10 @@ def _largest_divisor_leq(k: int, blk: int) -> int:
 
 
 def choose_k_blk(k: int, slots: int, m: int) -> int:
-    """Pick the K block without measuring.
-
-    On TPU the one-hot scatter scratch — k_blk * min(slots, slot_chunk) *
-    m * 4 bytes, since reconstruction runs at most slot_chunk slots per
-    pass — must fit VMEM, so take the largest candidate under the budget.
-    Off-TPU (interpret mode) there is no VMEM wall and fewer, larger grid
-    steps win (measured in benchmarks/run.py kernel_vusa_packed), so take
-    the largest candidate outright.
-    """
+    """Pick the K block without measuring: the largest candidate.  On the
+    TPU the candidates are already VMEM-budgeted; off it (interpret mode)
+    fewer, larger grid steps win (measured in benchmarks/run.py
+    kernel_vusa_packed)."""
     env = os.environ.get("REPRO_VUSA_KBLK")
     if env:
         try:
@@ -292,14 +344,7 @@ def choose_k_blk(k: int, slots: int, m: int) -> int:
         except ValueError as e:
             raise ValueError(f"REPRO_VUSA_KBLK must be an integer, got {env!r}") from e
         return _largest_divisor_leq(k, blk)  # snap down to a divisor of k
-    cands = _kblk_candidates(k)
-    if not on_tpu():
-        return cands[-1]
-    best = 1
-    for blk in cands:
-        if blk * min(slots, DEFAULT_SLOT_CHUNK) * m * 4 <= _VMEM_SCRATCH_BUDGET:
-            best = max(best, blk)
-    return best
+    return _kblk_candidates(k, slots, m)[-1]
 
 
 def _tune_key(
@@ -331,13 +376,13 @@ def autotune_row_packed(
     slot_chunk: int = DEFAULT_SLOT_CHUNK,
 ) -> int:
     """Time the kernel over k_blk candidates; cache + return the winner."""
-    interp = (not on_tpu()) if interpret is None else interpret
+    interp = interpret_mode(interpret)
     xf = x.reshape(-1, x.shape[-1])
     key = _tune_key(xf, p, interp, reconstruct, slot_chunk)
     if key in _KBLK_CACHE:
         return _KBLK_CACHE[key]
     best_blk, best_t = None, float("inf")
-    for blk in _kblk_candidates(xf.shape[-1]):
+    for blk in _kblk_candidates(xf.shape[-1], p.slots, p.m):
         f = lambda a: vusa_packed_matmul(
             a, p.values, p.positions, p.scales, m=p.m, k_blk=blk, interpret=interp,
             reconstruct=reconstruct, slot_chunk=slot_chunk, value_dtype=p.value_dtype,
@@ -367,7 +412,7 @@ def apply_row_packed(
     ``k_blk=None`` consults the autotune cache (populated by
     ``autotune_row_packed``), falling back to the ``choose_k_blk`` heuristic.
     """
-    interp = (not on_tpu()) if interpret is None else interpret
+    interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     k = xf.shape[-1]
@@ -455,14 +500,18 @@ def autotune_fused_mlp(
     The fused shape is its own tuning problem — its k_blk chunks *both* the
     d_model reduction of gate/up and the d_model output rows of the down
     accumulation, so the row-packed winner does not transfer."""
-    interp = (not on_tpu()) if interpret is None else interpret
+    interp = interpret_mode(interpret)
     xf = x.reshape(-1, x.shape[-1])
     _check_fused_packs(xf.shape[-1], gate, up, down_t)
     key = _fused_tune_key(xf, gate, up, down_t, interp, reconstruct, slot_chunk)
     if key in _KBLK_CACHE:
         return _KBLK_CACHE[key]
     best_blk, best_t = None, float("inf")
-    for blk in sorted(set(_kblk_candidates(xf.shape[-1]) + _kblk_candidates(down_t.k))):
+    slots = max(gate.slots, up.slots, down_t.slots)
+    cands = _kblk_candidates(xf.shape[-1], slots, gate.m) + _kblk_candidates(
+        down_t.k, slots, gate.m
+    )
+    for blk in sorted(set(cands)):
         f = lambda a: vusa_fused_mlp_matmul(
             a, gate.values, gate.positions, up.values, up.positions,
             down_t.values, down_t.positions,
@@ -501,7 +550,7 @@ def apply_fused_mlp(
     stays in VMEM.  ``k_blk=None`` consults the autotune cache (populated by
     ``autotune_fused_mlp``), falling back to ``choose_k_blk``; unlike the
     plain row-packed kernel the chunk size need not divide K."""
-    interp = (not on_tpu()) if interpret is None else interpret
+    interp = interpret_mode(interpret)
     lead = x.shape[:-1]
     xf = x.reshape(-1, x.shape[-1])
     k = xf.shape[-1]
@@ -557,7 +606,6 @@ def apply_fused_mlp_ref(
 # case and routes straight to the plain appliers, byte-identical program.
 # --------------------------------------------------------------------------
 
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec as _P  # noqa: E402
 
 
@@ -633,12 +681,12 @@ def apply_row_packed_sharded(
 
     # scales share the leading window axis, so they ride the same spec
     operands = (xf, p.values, p.positions) + ((p.scales,) if quant else ())
-    y = shard_map(
+    y = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(_P(),) + (_P(axis_name),) * (3 if quant else 2),
         out_specs=_P(),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
     return y[..., : p.c].reshape(*lead, p.c).astype(x.dtype)
 
@@ -687,11 +735,11 @@ def apply_fused_mlp_sharded(
         xf, gate.values, gate.positions, up.values, up.positions,
         down_t.values, down_t.positions,
     ) + ((gate.scales, up.scales, down_t.scales) if quant else ())
-    y = shard_map(
+    y = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(_P(),) + (_P(axis_name),) * (9 if quant else 6),
         out_specs=_P(),
-        check_rep=False,
+        check_vma=False,
     )(*operands)
     return y.reshape(*lead, down_t.k).astype(x.dtype)

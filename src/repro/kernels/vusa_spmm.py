@@ -17,7 +17,7 @@ Grid: one step per output tile.  VMEM working set per step:
 (lanes) so every matmul is MXU-aligned.
 
 The in-kernel gather runs along the lane dimension of ``x``; on TPU this
-lowers to a dynamic-gather, on CPU we validate with ``interpret=True``.
+lowers to a dynamic-gather, on the CPU backend it runs in interpret mode.
 """
 
 from __future__ import annotations
@@ -55,7 +55,7 @@ def vusa_spmm(
     values: jax.Array,  # (T, J, A, Tn)
     row_idx: jax.Array,  # (T, J, A) int32
     *,
-    interpret: bool = True,  # CPU container: interpret; set False on TPU
+    interpret: bool = False,  # ops.interpret_mode() picks it from the backend
 ) -> jax.Array:
     b, k = x.shape
     t, j, a, tn = values.shape

@@ -1,12 +1,27 @@
 """Mesh construction.  Functions, not module constants — importing this
 module never touches jax device state.
+
+Every mesh the program builds comes from :func:`make_mesh`, which gives all
+axes the ``Auto`` type: the sharding rules (``repro.dist.sharding``) and the
+activation constraints (``models.common.shard``) are written for GSPMD's
+automatic propagation, and ``jax.make_mesh`` now defaults to ``Explicit``
+axes, which ``with_sharding_constraint`` refuses.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_serve_mesh"]
+__all__ = ["make_mesh", "make_production_mesh", "make_local_mesh", "make_serve_mesh"]
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types over ``devices`` (default:
+    all local devices)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,12 +32,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
-    """Mesh over whatever devices exist locally (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Mesh over the first ``data * model`` local devices (tests / examples)."""
+    return make_mesh((data, model), ("data", "model"), jax.devices()[: data * model])
 
 
 def make_serve_mesh(spec: str):

@@ -39,6 +39,7 @@ from ..serve import (
     Scheduler,
     ServeConfig,
 )
+from .compile_cache import enable_compile_cache
 
 
 async def _serve_streaming(args, cfg, sched):
@@ -111,6 +112,7 @@ async def _serve_streaming(args, cfg, sched):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -308,7 +310,7 @@ def main():
                   f"hbm/req {st['hbm_bytes_per_active_request']/2**10:.1f}KiB")
         bad = sum(1 for c in done.values() if c.status.value not in ("OK", "FAILED_FALLBACK_OK"))
         if bad:
-            print(f"  {bad} requests did not deliver tokens")
+            raise SystemExit(f"{bad} requests did not deliver tokens")
         return
     prompts = np.ones((args.batch, args.prompt_len), np.int32)
     out = eng.generate(prompts, max_new=args.max_new)

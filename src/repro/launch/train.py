@@ -9,10 +9,9 @@ from the cluster env); here it sizes the mesh to the local devices.
 
 import argparse
 
-import jax
-
 from ..configs import get_config, get_smoke_config
 from ..train import TrainConfig, Trainer, TrainHParams
+from .mesh import make_local_mesh
 
 
 def main():
@@ -31,7 +30,7 @@ def main():
     args = ap.parse_args()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = make_local_mesh(args.data, args.model)
     tc = TrainConfig(
         steps=args.steps,
         global_batch=args.batch,

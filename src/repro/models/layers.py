@@ -440,7 +440,6 @@ def _maybe_seq_sharded_attention(q, k, v, mask, positions, cfg):
     dp = [a for a in ("pod", "data") if a in mesh.shape]
     if b % int(np.prod([mesh.shape[a] for a in dp])) != 0:
         return None
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     dp_spec = tuple(dp) if len(dp) > 1 else (dp[0] if dp else None)
@@ -452,7 +451,7 @@ def _maybe_seq_sharded_attention(q, k, v, mask, positions, cfg):
         valid = jnp.ones((s,), bool)
         return flash(q_l, k_l, v_l, qpos_l, kpos_l, valid)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_attn,
         mesh=mesh,
         in_specs=(
@@ -463,7 +462,7 @@ def _maybe_seq_sharded_attention(q, k, v, mask, positions, cfg):
             P(None),
         ),
         out_specs=P(dp_spec, "model", None, None, None),
-        check_rep=False,  # scan carries start as unvarying constants
+        check_vma=False,  # scan carries start as unvarying constants
     )
     return fn(q, k, v, positions, positions).astype(q.dtype)
 

@@ -36,6 +36,7 @@ from ..kernels.ops import (
     apply_row_packed_sharded,
     mesh_axis_size,
     pack_linear_rows,
+    pad_slots,
     pack_linear_rows_t,
     shard_linear_windows,
 )
@@ -64,27 +65,14 @@ ATTN_NAMES = ("wq", "wk", "wv", "wo")
 def _stack_packs(packs) -> Dict:
     """Stack per-layer RowPackedLinear into one (L, ...) device dict.
 
-    Jobs are padded to the max across layers so the stack is rectangular
-    (padded jobs are exact no-ops: value 0, position -1).  Quantized packs
-    pad the (possibly nibble-packed) value bytes with zeros — an idle
-    position never scatters, so the byte content there is ignored — and
-    stack the (T, K) scales unpadded."""
-    smax = max(p.positions.shape[2] for p in packs)
-    nib = 2 if packs[0].value_dtype == "int4" else 1
-    vmax = smax // nib
-
-    def pad(p: RowPackedLinear):
-        v = jnp.pad(p.values, ((0, 0), (0, 0), (0, vmax - p.values.shape[2])))
-        q = jnp.pad(
-            p.positions, ((0, 0), (0, 0), (0, smax - p.positions.shape[2])),
-            constant_values=-1,
-        )
-        return v, q
-
-    vs, qs = zip(*(pad(p) for p in packs))
+    Slots are padded to the max across layers so the stack is rectangular
+    (``kernels.ops.pad_slots``: idle slots, exact no-ops), and quantized
+    packs stack their (T, K) scales unpadded."""
+    smax = max(p.slots for p in packs)
+    padded = [pad_slots(p, smax) for p in packs]
     out = {
-        "values": jnp.stack(vs),
-        "positions": jnp.stack(qs),
+        "values": jnp.stack([p.values for p in padded]),
+        "positions": jnp.stack([p.positions for p in padded]),
         "k": packs[0].k,
         "c": packs[0].c,
         "m": packs[0].m,

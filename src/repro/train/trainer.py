@@ -28,6 +28,7 @@ from ..configs.base import ArchConfig
 from ..core.pruning import apply_masks, masks_tree, polynomial_sparsity, tree_sparsity
 from ..data.pipeline import SyntheticDataset
 from ..dist.sharding import act_rules, batch_shardings, params_shardings
+from ..launch.mesh import make_local_mesh
 from ..models import build_model
 from ..models.common import mesh_context
 from ..optim import AdamState, adamw_init
@@ -57,7 +58,7 @@ class TrainConfig:
 class Trainer:
     def __init__(self, cfg: ArchConfig, tc: TrainConfig, mesh=None):
         self.cfg, self.tc = cfg, tc
-        self.mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh or make_local_mesh(1, 1)
         self.rules = act_rules(self.mesh)
         self.model = build_model(cfg)
         self.p_shard = params_shardings(self.model.specs(), self.mesh)

@@ -16,6 +16,7 @@ import jax
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
 from repro.dist.sharding import param_sharding  # noqa: E402
+from repro.launch.mesh import make_local_mesh  # noqa: E402
 from repro.models.common import ParamSpec  # noqa: E402
 
 
@@ -45,9 +46,10 @@ def test_param_rules_multi_device():
         import jax
         from repro.configs import get_config
         from repro.dist.sharding import params_shardings
+        from repro.launch.mesh import make_local_mesh
         from repro.models import build_model
 
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_local_mesh(4, 4)
         for arch in ("qwen3_8b", "olmoe_1b_7b", "mamba2_2_7b"):
             cfg = get_config(arch)
             model = build_model(cfg)
@@ -74,8 +76,9 @@ def test_train_step_runs_sharded():
     code = textwrap.dedent("""
         import jax, numpy as np
         from repro.configs import get_smoke_config
+        from repro.launch.mesh import make_local_mesh
         from repro.train import TrainConfig, Trainer, TrainHParams
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_local_mesh(4, 4)
         cfg = get_smoke_config("llama3_2_1b")
         tc = TrainConfig(steps=3, global_batch=8, seq_len=32, prune_begin=100,
                          hp=TrainHParams(lr=1e-3, total_steps=3), log_every=100)
@@ -93,14 +96,14 @@ def test_sharded_matches_single_device():
     code = textwrap.dedent("""
         import jax, numpy as np
         from repro.configs import get_smoke_config
+        from repro.launch.mesh import make_local_mesh
         from repro.train import TrainConfig, Trainer, TrainHParams
         tc = TrainConfig(steps=2, global_batch=8, seq_len=16, prune_begin=100,
                          hp=TrainHParams(lr=1e-3, total_steps=2), log_every=100)
         cfg = get_smoke_config("qwen2_0_5b")
-        from jax.sharding import Mesh
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_local_mesh(4, 4)
         l_multi = Trainer(cfg, tc, mesh=mesh).train()["final_loss"]
-        mesh1 = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
+        mesh1 = make_local_mesh(1, 1)
         l_single = Trainer(cfg, tc, mesh=mesh1).train()["final_loss"]
         print("multi", l_multi, "single", l_single)
         assert abs(l_multi - l_single) < 2e-3, (l_multi, l_single)
@@ -110,7 +113,7 @@ def test_sharded_matches_single_device():
 
 def test_param_sharding_divisibility_fallback():
     """Non-divisible dims must fall back to replication, never error."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     spec = ParamSpec((7, 13), ("embed", "ff"))  # nothing divides
     s = param_sharding(spec, mesh)
     assert s.spec == jax.sharding.PartitionSpec(None, None)
@@ -119,7 +122,7 @@ def test_param_sharding_divisibility_fallback():
 def test_batch_sharding_non_divisible_batch():
     from repro.dist.sharding import batch_sharding
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(1, 1)
     s = batch_sharding(mesh, batch_size=1, ndim=2)  # long_500k case
     assert s.spec[0] in (None, "data")  # batch=1 on 1-dev mesh: either is valid
 
@@ -135,7 +138,7 @@ def _mesh24():
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 forced host devices")
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_local_mesh(2, 4)
 
 
 def test_param_sharding_odd_dims_replicate_on_real_mesh():
@@ -159,18 +162,15 @@ def test_window_sharding_fallback():
     """Packed-weight window axes (values AND the int8 positions metadata):
     divisible counts shard over `model`, odd counts replicate, a mesh without
     a model axis replicates — never an error."""
-    from jax.sharding import Mesh
-
     from repro.dist.sharding import window_sharding
+    from repro.launch.mesh import make_mesh
 
     mesh = _mesh24()
     P = jax.sharding.PartitionSpec
     assert window_sharding(mesh, 8, 3, axis=0).spec == P("model", None, None)
     assert window_sharding(mesh, 8, 4, axis=1).spec == P(None, "model", None, None)
     assert window_sharding(mesh, 7, 3, axis=0).spec == P(None, None, None)  # odd
-    import numpy as np
-
-    data_only = Mesh(np.array(jax.devices()[:2]).reshape(2), ("data",))
+    data_only = make_mesh((2,), ("data",), jax.devices()[:2])
     assert window_sharding(data_only, 8, 3).spec == P(None, None, None)
 
 

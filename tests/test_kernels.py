@@ -122,3 +122,42 @@ def test_byte_ratio_vs_sparsity_tracks_growth_model():
         w = _sparse(rng, 512, 512, sp, np.float32)
         ratios.append(pack_linear_rows(w, a=8).byte_ratio)
     assert ratios[0] > ratios[1] > ratios[2]
+
+
+# ---------------------------------------------------------------------------
+# k_blk candidates and interpret mode
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "k,slots,want",
+    [
+        (768, 48, [128]),  # vusa_edge d_model rows at 85 %: 256 rows overflow
+        (3072, 48, [128]),  # vusa_edge d_ff rows (w_down)
+        (768, 8, [128, 256, 384]),  # few slots: larger aligned blocks fit
+        (64, 48, [64]),  # K below one lane tile: the whole K
+    ],
+)
+def test_kblk_candidates_on_chip(monkeypatch, k, slots, want):
+    """On the TPU only lane-aligned blocks whose one-hot scratch fits the
+    VMEM budget are candidates, so the autotuners never time a block Mosaic
+    refuses; choose_k_blk takes the largest."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_VUSA_KBLK", raising=False)
+    assert ops._kblk_candidates(k, slots, 128) == want
+    assert ops.choose_k_blk(k, slots, 128) == want[-1]
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    import jax
+
+    from repro.kernels.ops import interpret_mode
+
+    assert interpret_mode() is True  # the test backend is the CPU
+    assert interpret_mode(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    with pytest.raises(ValueError, match="CPU backend only"):
+        interpret_mode(True)

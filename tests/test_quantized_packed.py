@@ -380,3 +380,29 @@ def test_quantized_fault_reaches_runtime_guard(vusa_pruned):
     for rid, c in done.items():
         assert c.status.value in ("OK", "FAILED_FALLBACK_OK"), (rid, c.status)
         assert len(c.tokens) == 6
+
+
+@pytest.mark.parametrize("dt", ["dense", "int8", "int4"])
+def test_pad_slots_is_exact(dt):
+    """Layer stacking pads every pack to the widest slot count.  An int4
+    pack keeps slots [0, S/2) in low nibbles and [S/2, S) in high ones, so
+    its padding must append idle slots to both halves: the padded pack
+    dequantizes to the same weights and computes the same product."""
+    from repro.kernels.ops import pad_slots
+
+    rng = np.random.default_rng(11)
+    w = _sparse(rng, 32, 64, 0.7)
+    p = pack_linear_rows(w, m=32, a=4, value_dtype=dt)
+    wide = pad_slots(p, p.slots + 8)
+    assert wide.slots == p.slots + 8
+    deq, deq_wide = (np.asarray(dequantize_linear_values(q)) for q in (p, wide))
+    pos, pos_wide = np.asarray(p.positions), np.asarray(wide.positions)
+
+    def dense(v, q):  # (T, K, S) slots -> {(t, k, lane): value}
+        return {(t, k, int(q[t, k, s])): v[t, k, s] for t, k, s in zip(*np.nonzero(q >= 0))}
+
+    assert dense(deq, pos) == dense(deq_wide, pos_wide)
+    x = jnp.asarray(rng.normal(size=(2, 32)), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(apply_row_packed(x, wide)), np.asarray(apply_row_packed(x, p))
+    )

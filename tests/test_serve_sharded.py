@@ -242,15 +242,14 @@ def test_sharded_applier_replicated_fallback():
     """A mesh whose model axis the window count cannot use still computes the
     right answer (shard_linear_windows pads on the fly) — and a mesh with no
     model axis at all degenerates to the plain path."""
-    from jax.sharding import Mesh
-
     from repro.kernels.ops import apply_row_packed_sharded, pack_linear_rows
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(2)
     w = _sparse(rng, 16, 33, 0.5)  # 2 windows of m=32 after padding
     x = jnp.asarray(rng.normal(size=(2, 16)), jnp.float32)
     p = pack_linear_rows(w, m=32, a=8)
-    data_only = Mesh(np.array(jax.devices()[:2]).reshape(2), ("data",))
+    data_only = make_mesh((2,), ("data",), jax.devices()[:2])
     got = np.asarray(apply_row_packed_sharded(x, p, data_only))
     np.testing.assert_allclose(got, np.asarray(x) @ w, rtol=1e-4, atol=1e-4)
     got3 = np.asarray(apply_row_packed_sharded(x, p, make_serve_mesh("1,4")))
