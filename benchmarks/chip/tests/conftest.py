@@ -1,0 +1,8 @@
+import os
+import sys
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE.parent))
+sys.path.insert(0, str(HERE.parents[2] / "src"))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
