@@ -406,11 +406,13 @@ def apply_row_packed(
     k_blk: int | None = None,
     reconstruct: str = "onehot",
     slot_chunk: int = DEFAULT_SLOT_CHUNK,
+    name: str = "vusa_packed_matmul",
 ) -> jax.Array:
     """y = x @ W for row-packed W.  x: (..., K) -> (..., C).
 
     ``k_blk=None`` consults the autotune cache (populated by
     ``autotune_row_packed``), falling back to the ``choose_k_blk`` heuristic.
+    ``name`` labels the kernel call (``vusa_packed_matmul``'s ``name``).
     """
     interp = interpret_mode(interpret)
     lead = x.shape[:-1]
@@ -438,6 +440,7 @@ def apply_row_packed(
         reconstruct=reconstruct,
         slot_chunk=slot_chunk,
         value_dtype=p.value_dtype,
+        name=name,
     )
     return y[..., : p.c].reshape(*lead, p.c).astype(x.dtype)
 
@@ -653,6 +656,7 @@ def apply_row_packed_sharded(
     axis_name: str = "model",
     *,
     interpret: bool | None = None,
+    name: str = "vusa_packed_matmul",
 ) -> jax.Array:
     """``apply_row_packed`` with the window axis sharded over ``axis_name``.
 
@@ -665,7 +669,7 @@ def apply_row_packed_sharded(
     free.  Degenerate mesh (None or size-1 axis) runs the plain kernel."""
     tp = mesh_axis_size(mesh, axis_name)
     if tp == 1:
-        return apply_row_packed(x, p, interpret=interpret)
+        return apply_row_packed(x, p, interpret=interpret, name=name)
     p = shard_linear_windows(p, tp)
     t = p.values.shape[0]
     t_local = t // tp
@@ -675,7 +679,8 @@ def apply_row_packed_sharded(
 
     def local(xf, values, positions, scales=None):
         y = apply_row_packed(
-            xf, _local_view(p, values, positions, t_local, scales), interpret=interpret
+            xf, _local_view(p, values, positions, t_local, scales), interpret=interpret,
+            name=name,
         )
         return jax.lax.all_gather(y, axis_name, axis=1, tiled=True)
 

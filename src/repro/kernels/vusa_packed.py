@@ -161,7 +161,9 @@ def _kernel(x_ref, val_ref, pos_ref, *rest, m: int, reconstruct: str, slot_chunk
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype"),
+    static_argnames=(
+        "interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype", "name",
+    ),
 )
 def vusa_packed_matmul(
     x: jax.Array,  # (B, K)
@@ -175,7 +177,10 @@ def vusa_packed_matmul(
     reconstruct: str = "onehot",
     slot_chunk: int = DEFAULT_SLOT_CHUNK,
     value_dtype: str = "dense",
+    name: str = "vusa_packed_matmul",
 ) -> jax.Array:
+    """``name`` labels the call in compiled programs and device traces; a
+    trace reader finds every call by its ``vusa_packed_matmul`` prefix."""
     b, k = x.shape
     t, kk, vslots = values.shape
     slots = positions.shape[2]
@@ -213,6 +218,7 @@ def vusa_packed_matmul(
         out_specs=pl.BlockSpec((b, m), lambda i, l: (0, i)),
         out_shape=jax.ShapeDtypeStruct((b, t * m), jnp.float32),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
@@ -386,4 +392,5 @@ def vusa_fused_mlp_matmul(
         out_specs=pl.BlockSpec((b, d_out), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d_out), jnp.float32),
         interpret=interpret,
+        name="vusa_fused_mlp_matmul",
     )(*operands)

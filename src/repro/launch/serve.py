@@ -107,8 +107,18 @@ async def _serve_streaming(args, cfg, sched):
               f"ttft p50/p99 {st['ttft_p50_s']*1e3:.0f}/{st['ttft_p99_s']*1e3:.0f}ms  "
               f"itl p50/p99 {st['itl_p50_s']*1e3:.0f}/{st['itl_p99_s']*1e3:.0f}ms  "
               f"journal records={st['journal_records']:.0f} syncs={st['journal_syncs']:.0f}")
+        print("  " + _host_line(st))
     for sig in (signal.SIGINT, signal.SIGTERM):
         loop.remove_signal_handler(sig)
+
+
+def _host_line(st) -> str:
+    """The run loop's host seconds per phase and the prefill padding share
+    (``Scheduler.stats()``, latest run epoch)."""
+    phases = "  ".join(f"{k} {st[k + '_s']:.3f}"
+                       for k in ("admit", "dispatch", "fetch", "consume", "hook"))
+    return (f"host s over {st['syncs']} syncs: {phases}  prefill pad "
+            f"{100 * st['prefill_pad_share']:.1f}% of {st['prefill_positions']} positions")
 
 
 def main():
@@ -301,6 +311,7 @@ def main():
             ("rejected", "shed", "timed_out", "cancelled", "fallback", "failed",
              "quarantined")
         ))
+        print("  " + _host_line(st))
         if args.page_size > 0:
             print(f"  arena {st['kv_pool_bytes']/2**20:.1f}MiB "
                   f"blocks live={st['blocks_live']:.0f} free={st['blocks_free']:.0f} "

@@ -188,11 +188,12 @@ def _lm_decode_layer(lp, x, cache_l, cfg, pos):
     h = rms_norm(x, lp["norm1"])
     y, new_cache = attention_decode(lp["attn"], h, cfg, {**cache_l, "pos": pos})
     x = x + y
-    h = rms_norm(x, lp["norm2"])
-    if cfg.family == "moe":
-        y, _ = moe(lp["ffn"], h, cfg)
-    else:
-        y = mlp(lp["ffn"], h)
+    with jax.named_scope("decode.mlp"):
+        h = rms_norm(x, lp["norm2"])
+        if cfg.family == "moe":
+            y, _ = moe(lp["ffn"], h, cfg)
+        else:
+            y = mlp(lp["ffn"], h)
     if "k_new" in new_cache:  # paged: pending row writes, not a full cache
         return x + y, {"k_new": new_cache["k_new"], "v_new": new_cache["v_new"]}
     return x + y, {"k": new_cache["k"], "v": new_cache["v"]}
@@ -242,9 +243,10 @@ def lm_decode_step(params, token, cache, cfg):
         return x, new_c
 
     x, new_kv = jax.lax.scan(body, x, (params["layers"], {"k": cache["k"], "v": cache["v"]}))
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+    with jax.named_scope("decode.head"):
+        x = rms_norm(x, params["final_norm"])
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
     if table is not None:
         return logits, {**new_kv, "table": table, "pos": pos + 1}
     return logits, {**new_kv, "pos": pos + 1}

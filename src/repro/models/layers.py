@@ -513,6 +513,7 @@ def attention(
     return jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(x.dtype))
 
 
+@jax.named_scope("decode.attention")
 def attention_decode(
     p: dict,
     x: jax.Array,  # (B, s, d) — s = 1 normal decode; s > 1 speculative verify

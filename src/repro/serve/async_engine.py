@@ -39,6 +39,7 @@ from typing import AsyncIterator, Dict, List, Optional, Tuple
 import numpy as np
 
 from .journal import Journal, JournalTap, recover_into
+from .metrics import span
 from .scheduler import Completion, Request, Scheduler, Status
 
 __all__ = ["AsyncEngine", "TokenStream"]
@@ -360,8 +361,13 @@ class AsyncEngine:
 
     def _on_sync(self, sched: Scheduler) -> None:
         self._drain_pending()
-        self._tap.on_sync(sched)
+        self._journal(sched)
         self._last_sync = sched._clock()
+
+    def _journal(self, sched: Scheduler) -> None:
+        """Journal and stream this sync's deltas (one fsync)."""
+        with span("serve.journal"):
+            self._tap.on_sync(sched)
 
     def _worker(self) -> None:
         while not self._stop:
@@ -380,7 +386,7 @@ class AsyncEngine:
                     # and this epoch's ITL series before the next epoch
                     # resets it
                     self._drain_pending()
-                    self._tap.on_sync(self.sched)
+                    self._journal(self.sched)
                     self._itl_all.extend(self.sched.itl_samples())
                     self._idle.set()
             else:

@@ -29,6 +29,7 @@ import numpy as np
 from ..configs.base import ArchConfig
 from ..models import build_model
 from .faults import FaultConfig
+from .metrics import span
 
 __all__ = ["ServeConfig", "Engine"]
 
@@ -306,26 +307,27 @@ class Engine:
                 )
             else:
                 logits, cache = self.model.decode_step(params, token, cache)
-        logits = logits[:, -1].astype(jnp.float32)
-        ok = jnp.isfinite(logits).all(axis=-1)
-        if self.mesh is not None:
-            # Pin the sampling computation replicated.  Under the default
-            # (non-partitionable) threefry lowering, random bits generated
-            # for a *sharded* (B, V) block differ from the single-device
-            # stream — GSPMD offsets each shard's counter — so a sharded
-            # categorical would emit different tokens than mesh=None for the
-            # same seed.  Replicating the tiny logits block first keeps the
-            # whole draw bit-identical at every mesh shape (DESIGN.md §8).
-            from jax.sharding import NamedSharding, PartitionSpec
+        with jax.named_scope("decode.sample"):
+            logits = logits[:, -1].astype(jnp.float32)
+            ok = jnp.isfinite(logits).all(axis=-1)
+            if self.mesh is not None:
+                # Pin the sampling computation replicated.  Under the default
+                # (non-partitionable) threefry lowering, random bits generated
+                # for a *sharded* (B, V) block differ from the single-device
+                # stream — GSPMD offsets each shard's counter — so a sharded
+                # categorical would emit different tokens than mesh=None for the
+                # same seed.  Replicating the tiny logits block first keeps the
+                # whole draw bit-identical at every mesh shape (DESIGN.md §8).
+                from jax.sharding import NamedSharding, PartitionSpec
 
-            logits = jax.lax.with_sharding_constraint(
-                logits, NamedSharding(self.mesh, PartitionSpec())
-            )
-        if self.sc.temperature > 0:
-            nxt = jax.random.categorical(key, logits / self.sc.temperature)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
-        return nxt.astype(jnp.int32)[:, None], cache, ok
+                logits = jax.lax.with_sharding_constraint(
+                    logits, NamedSharding(self.mesh, PartitionSpec())
+                )
+            if self.sc.temperature > 0:
+                nxt = jax.random.categorical(key, logits / self.sc.temperature)
+            else:
+                nxt = jnp.argmax(logits, axis=-1)
+            return nxt.astype(jnp.int32)[:, None], cache, ok
 
     def _decode_fn(self, params, token, cache, key):
         """Decode step on the engine's configured path: packed when a pack is
@@ -574,10 +576,11 @@ class Engine:
         ``families.lm_prefill_chunk``.  Donates ``arena``; returns
         ``(logits (1, V), arena')``."""
         self._validate_tokens(tokens)
-        return self._chunk(
-            self.params, jnp.asarray(tokens, jnp.int32), arena, table_row,
-            jnp.int32(start), jnp.int32(true_len), jnp.int32(write_from),
-        )
+        with span("serve.prefill", rows=1, bucket=tokens.shape[1]):
+            return self._chunk(
+                self.params, jnp.asarray(tokens, jnp.int32), arena, table_row,
+                jnp.int32(start), jnp.int32(true_len), jnp.int32(write_from),
+            )
 
     def bucket_len(self, n: int) -> int:
         """Smallest configured bucket >= n (the bucket set always covers
@@ -680,31 +683,32 @@ class Engine:
                 f"prompt length {prompts.shape[1]} exceeds max_len {self.sc.max_len}"
             )
         self._validate_tokens(prompts)
-        batch = {"tokens": self._shard_batch(jnp.asarray(prompts))}
-        if extras:
-            batch.update({k: self._shard_batch(jnp.asarray(v)) for k, v in extras.items()})
-        if self._prefill is not None:
-            logits, cache = self._prefill(self.params, batch)
-            nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1)[:, None].astype(jnp.int32)
-            # the recurrent paths below place their cache at init and keep
-            # that placement through the loop; only the prefill output needs
-            # an explicit move onto the serve shardings
-            cache = self.shard_cache(cache, prompts.shape[0])
-        elif self.sc.fused:
-            cache = self.shard_cache(
-                self.model.init_cache(prompts.shape[0], self.sc.max_len), prompts.shape[0]
-            )
-            nxt, cache, key = self._prime_loop(self.params, batch["tokens"], cache, key)
-        else:
-            # seed path: prime the state by stepping through the prompt
-            cache = self.shard_cache(
-                self.model.init_cache(prompts.shape[0], self.sc.max_len), prompts.shape[0]
-            )
-            nxt = jnp.asarray(prompts[:, :1])
-            for t in range(prompts.shape[1]):
-                key, sub = jax.random.split(key)
-                tok = jnp.asarray(prompts[:, t : t + 1])
-                nxt, cache, _ = self._decode(self.params, tok, cache, sub)
+        with span("serve.prefill", rows=prompts.shape[0], bucket=prompts.shape[1]):
+            batch = {"tokens": self._shard_batch(jnp.asarray(prompts))}
+            if extras:
+                batch.update({k: self._shard_batch(jnp.asarray(v)) for k, v in extras.items()})
+            if self._prefill is not None:
+                logits, cache = self._prefill(self.params, batch)
+                nxt = jnp.argmax(logits.astype(jnp.float32), axis=-1)[:, None].astype(jnp.int32)
+                # the recurrent paths below place their cache at init and keep
+                # that placement through the loop; only the prefill output needs
+                # an explicit move onto the serve shardings
+                cache = self.shard_cache(cache, prompts.shape[0])
+            elif self.sc.fused:
+                cache = self.shard_cache(
+                    self.model.init_cache(prompts.shape[0], self.sc.max_len), prompts.shape[0]
+                )
+                nxt, cache, key = self._prime_loop(self.params, batch["tokens"], cache, key)
+            else:
+                # seed path: prime the state by stepping through the prompt
+                cache = self.shard_cache(
+                    self.model.init_cache(prompts.shape[0], self.sc.max_len), prompts.shape[0]
+                )
+                nxt = jnp.asarray(prompts[:, :1])
+                for t in range(prompts.shape[1]):
+                    key, sub = jax.random.split(key)
+                    tok = jnp.asarray(prompts[:, t : t + 1])
+                    nxt, cache, _ = self._decode(self.params, tok, cache, sub)
         return nxt, cache, key
 
     def prime_many(self, prompts, lengths):
@@ -726,11 +730,12 @@ class Engine:
                 f"bucket length {prompts.shape[1]} exceeds max_len {self.sc.max_len}"
             )
         self._validate_tokens(prompts)
-        return self._prefill_masked(
-            self.params,
-            {"tokens": self._shard_batch(jnp.asarray(prompts))},
-            self._shard_batch(jnp.asarray(lengths, jnp.int32)),
-        )
+        with span("serve.prefill", rows=prompts.shape[0], bucket=prompts.shape[1]):
+            return self._prefill_masked(
+                self.params,
+                {"tokens": self._shard_batch(jnp.asarray(prompts))},
+                self._shard_batch(jnp.asarray(lengths, jnp.int32)),
+            )
 
     def decode_segment(self, token, cache, key, steps: int):
         """``steps`` fused decode steps in one dispatch: returns

@@ -530,11 +530,11 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
 
     from ..models.layers import attention_decode  # noqa: PLC0415
 
-    def papply(entry, vals, poss, x2, scales=None):
+    def papply(entry, vals, poss, x2, scales=None, name="vusa_packed_matmul"):
         lin = _as_linear(entry, vals, poss, scales)
         if mesh is not None:
-            return apply_row_packed_sharded(x2, lin, mesh)
-        return apply_row_packed(x2, lin)
+            return apply_row_packed_sharded(x2, lin, mesh, name=name)
+        return apply_row_packed(x2, lin, name=name)
 
     def arrays(group):  # scanned leaves only; meta stays static
         return {
@@ -572,51 +572,54 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
             lp["attn"], h, cfg, {**cache_l, "pos": pos}, wmm=wmm
         )
         x = x + y
-        h = rms_norm(x, lp["norm2"])
-        b, s, d = h.shape
-        hf = h.reshape(b * s, d)
-        if fused:
+        with jax.named_scope("decode.mlp"):
+            h = rms_norm(x, lp["norm2"])
+            b, s, d = h.shape
+            hf = h.reshape(b * s, d)
+            if fused:
 
-            def lin(name):
-                return _as_linear(
-                    mlp[name], mlp_l[name]["values"], mlp_l[name]["positions"],
-                    mlp_l[name].get("scales"),
-                )
+                def lin(name):
+                    return _as_linear(
+                        mlp[name], mlp_l[name]["values"], mlp_l[name]["positions"],
+                        mlp_l[name].get("scales"),
+                    )
 
-            if mesh is not None:
-                y2 = apply_fused_mlp_sharded(
-                    hf, lin("w_gate"), lin("w_up"), lin("w_down_t"), mesh
-                )
-            else:
-                y2 = apply_fused_mlp(hf, lin("w_gate"), lin("w_up"), lin("w_down_t"))
-        else:  # 3-dispatch baseline: gate/up/down round-trip the (B, ff)
+                if mesh is not None:
+                    y2 = apply_fused_mlp_sharded(
+                        hf, lin("w_gate"), lin("w_up"), lin("w_down_t"), mesh
+                    )
+                else:
+                    y2 = apply_fused_mlp(hf, lin("w_gate"), lin("w_up"), lin("w_down_t"))
+            else:  # 3-dispatch baseline: gate/up/down round-trip the (B, ff)
 
-            def pap(name, x2):
-                return papply(
-                    mlp[name], mlp_l[name]["values"], mlp_l[name]["positions"], x2,
-                    mlp_l[name].get("scales"),
-                )
+                def pap(name, x2):
+                    return papply(
+                        mlp[name], mlp_l[name]["values"], mlp_l[name]["positions"], x2,
+                        mlp_l[name].get("scales"),
+                    )
 
-            gate = jax.nn.silu(pap("w_gate", hf))
-            up = pap("w_up", hf)
-            y2 = pap("w_down", (gate * up).astype(hf.dtype))
-        x = x + y2.reshape(b, s, d).astype(x.dtype)
+                gate = jax.nn.silu(pap("w_gate", hf))
+                up = pap("w_up", hf)
+                y2 = pap("w_down", (gate * up).astype(hf.dtype))
+            x = x + y2.reshape(b, s, d).astype(x.dtype)
         if "k_new" in new_cache:
             return x, {"k_new": new_cache["k_new"], "v_new": new_cache["v_new"]}
         return x, {"k": new_cache["k"], "v": new_cache["v"]}
 
     x, new_kv = jax.lax.scan(body, x, xs)
-    x = rms_norm(x, params["final_norm"])
-    if packed.get("head") is not None:
-        b, s, d = x.shape
-        head = packed["head"]
-        logits = papply(
-            head, head["values"], head["positions"], x.reshape(b * s, d), head.get("scales")
-        )
-        logits = logits.reshape(b, s, -1)
-    else:
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+    with jax.named_scope("decode.head"):
+        x = rms_norm(x, params["final_norm"])
+        if packed.get("head") is not None:
+            b, s, d = x.shape
+            head = packed["head"]
+            logits = papply(
+                head, head["values"], head["positions"], x.reshape(b * s, d),
+                head.get("scales"), name="vusa_packed_matmul_head",
+            )
+            logits = logits.reshape(b, s, -1)
+        else:
+            head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
     if table is not None:
         return logits, {**new_kv, "table": table, "pos": pos + 1}
     return logits, {**new_kv, "pos": pos + token.shape[1]}

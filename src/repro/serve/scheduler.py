@@ -96,7 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .engine import Engine
-from .metrics import acceptance_rate, tok_per_s
+from .metrics import SpanTimes, acceptance_rate, tok_per_s
 
 __all__ = ["Request", "Completion", "Scheduler", "Status"]
 
@@ -359,6 +359,10 @@ class Scheduler:
             # speculative accounting (host-consumed view): drafts proposed in
             # rounds a slot consumed from, and how many of them were accepted
             spec_proposed=0, spec_accepted=0,
+            # segment syncs, and the prefill programs admission dispatched:
+            # real prompt tokens against the positions they computed
+            # (bucket length x padded batch rows)
+            syncs=0, prefill_dispatches=0, prefill_tokens=0, prefill_positions=0,
         )
         # streaming/watchdog state (DESIGN.md §12).  `_abort_status` is the
         # fail-fast flag another thread (the async engine's watchdog) sets:
@@ -380,8 +384,8 @@ class Scheduler:
         # run stats
         self._seg_steps = 0
         self._active_slot_steps = 0
-        self._decode_s = 0.0
-        self._admit_s = 0.0
+        # host seconds per phase of the run loop (serve.* spans)
+        self._spans = SpanTimes(self._clock)
         # cache observability (DESIGN.md §11): Σ used-KV bytes and Σ active
         # slots, sampled once per segment sync — their ratio is the
         # HBM-bytes-per-active-request gauge the paged bench gates on
@@ -415,7 +419,7 @@ class Scheduler:
             self._counters[k] = 0
         self._seg_steps = 0
         self._active_slot_steps = 0
-        self._decode_s = self._admit_s = 0.0
+        self._spans.reset()
         self._kv_used_acc = self._kv_active_acc = 0
         if self.paged:
             # the prefix registry itself persists across epochs (warm cache is
@@ -703,7 +707,8 @@ class Scheduler:
             token, kdata, rows, ok = jax.vmap(one, in_axes=(0, 0, paged_in_axes(pstate)))(
                 token, kdata, paged_view(pstate)
             )
-            pstate = paged_scatter_token(pstate, rows)
+            with jax.named_scope("decode.attention"):
+                pstate = paged_scatter_token(pstate, rows)
             return (token, kdata, pstate), (token[:, 0, 0], ok[:, 0])
 
         (token, kdata, pstate), (toks, okg) = jax.lax.scan(
@@ -782,7 +787,8 @@ class Scheduler:
             token, kdata, rows, emit, nem, okp = jax.vmap(
                 one, in_axes=(0, 0, paged_in_axes(pstate))
             )(token, kdata, paged_view(pstate))
-            pstate = paged_scatter_rows(pstate, rows, start, nem)
+            with jax.named_scope("decode.attention"):
+                pstate = paged_scatter_rows(pstate, rows, start, nem)
             return (token, kdata, pstate), (emit, nem, okp)
 
         (token, kdata, pstate), (toks, nems, okg) = jax.lax.scan(
@@ -915,15 +921,14 @@ class Scheduler:
         ``admission="sequential"`` baseline): B=1 prime + single-slot write.
         First-token EOS/budget checks are deferred to the segment sync, so
         no device->host transfer happens here."""
-        t0 = self._clock()
         key = jax.random.key(req.seed)
         nxt, cache, key = self.eng.prime(req.prompt[None], key)
+        self._note_prefill(len(req.prompt), len(req.prompt))
         self._cache, self._token, self._kdata = self._write(
             self._cache, self._token, self._kdata,
             jnp.int32(i), cache, nxt, jax.random.key_data(key),
         )
         self._bind_slot(i, rid, req, nxt, now)
-        self._admit_s += self._clock() - t0
 
     def _admit_batched(self, free: List[int], picked, now: float) -> None:
         """Coalesced bucketed admission: group this round's arrivals by
@@ -931,7 +936,6 @@ class Scheduler:
         prefill, scatter each into its slots in one donated write.  The
         batch dim is padded to a power of two so compile count stays
         O(len buckets x log2 slots), not O(distinct traffic shapes)."""
-        t0 = self._clock()
         groups: Dict[int, list] = {}
         for i, (rid, req) in zip(free, picked):
             groups.setdefault(self.eng.bucket_len(len(req.prompt)), []).append((i, rid, req))
@@ -946,13 +950,20 @@ class Scheduler:
                 idx[j] = i
             kds = self._kds_for([req.seed for _, _, req in group], nb)
             nxt, cache = self.eng.prime_many(tokens, lengths)
+            self._note_prefill(int(lengths[: len(group)].sum()), nb * blen)
             self._cache, self._token, self._kdata = self._write_many(
                 self._cache, self._token, self._kdata,
                 jnp.asarray(idx), cache, nxt, kds, jnp.asarray(lengths),
             )
             for j, (i, rid, req) in enumerate(group):
                 self._bind_slot(i, rid, req, nxt[j : j + 1], now)
-        self._admit_s += self._clock() - t0
+
+    def _note_prefill(self, tokens: int, positions: int) -> None:
+        """Count one prefill dispatch: ``tokens`` real prompt tokens over the
+        ``positions`` its program computes."""
+        self._counters["prefill_dispatches"] += 1
+        self._counters["prefill_tokens"] += tokens
+        self._counters["prefill_positions"] += positions
 
     # -- paged admission (DESIGN.md §11) --------------------------------------
 
@@ -967,7 +978,6 @@ class Scheduler:
         extension is where preemption lives.  Returns the ``(slot, rid,
         req)`` triples actually admitted (fault injection targets only
         those)."""
-        t0 = self._clock()
         admitted, whole = [], []
         pairs = list(zip(free, picked))
         for n_done, (i, (rid, req)) in enumerate(pairs):
@@ -978,7 +988,6 @@ class Scheduler:
             admitted.append((i, rid, req))
         if whole:
             self._prime_whole_paged(whole)
-        self._admit_s += self._clock() - t0
         return admitted
 
     def _plan_paged_one(self, i: int, rid: int, req: Request, now: float, whole) -> bool:
@@ -1073,6 +1082,7 @@ class Scheduler:
                 rows_arr[j] = self._rows[i]
             kds = self._kds_for([req.seed for _, _, req, _ in group], nb)
             nxt, cache = self.eng.prime_many(tokens, lengths)
+            self._note_prefill(int(lengths[: len(group)].sum()), nb * blen)
             primed = {name: cache[name] for name in self._arena_names}
             self._pstate = self._fill(self._pstate, jnp.asarray(pt), primed)
             self._pstate, self._token, self._kdata = self._bind(
@@ -1114,7 +1124,6 @@ class Scheduler:
         stream) and registers its prefix hashes."""
         if not self.paged:
             return
-        t0 = self._clock()
         for i, slot in enumerate(self._slot):
             job = slot.prefill
             if job is None or not slot.active:
@@ -1127,6 +1136,7 @@ class Scheduler:
                 toks, self._pstate["arena"], jnp.asarray(self._rows[i]),
                 s, n, job.write_from,
             )
+            self._note_prefill(n, job.chunk)
             self._pstate = {**self._pstate, "arena": arena}
             job.start = s + n
             if job.start >= job.L:
@@ -1135,7 +1145,6 @@ class Scheduler:
                     .astype(jnp.int32)
                 )
                 self._complete_prefill(i, job, first)
-        self._admit_s += self._clock() - t0
 
     def _complete_prefill(self, i: int, job: _PrefillJob, first) -> None:
         slot = self._slot[i]
@@ -1163,7 +1172,6 @@ class Scheduler:
         always makes progress."""
         if not self.paged:
             return
-        t0 = self._clock()
         for i in range(self.slots):
             slot = self._slot[i]
             if not slot.active or slot.prefill is not None:
@@ -1183,7 +1191,6 @@ class Scheduler:
             self._slot_private[i] += list(ids)
             self._slot_npages[i] = needed
             self._rebind_row(i)
-        self._admit_s += self._clock() - t0
 
     def _alloc_or_preempt(self, n: int, protect: int) -> list:
         got = self._alloc.alloc(n)
@@ -1277,7 +1284,6 @@ class Scheduler:
         f = self.eng.sc.faults
         if f is None:
             return
-        t0 = self._clock()
         for i, (rid, req) in zip(free, picked):
             if f.wants_stall(rid):
                 self._sleep(f.stall_s)
@@ -1295,7 +1301,6 @@ class Scheduler:
                 else:
                     self._fault_fired.add(rid)
                     self._cache = self._poison(self._cache, jnp.int32(i))
-        self._admit_s += self._clock() - t0
 
     def _stall_wait(self, secs: float) -> None:
         """Sleep ``secs`` (possibly inf — a hang) in small interruptible
@@ -1456,7 +1461,13 @@ class Scheduler:
         its terminal status; aggregate numbers via :meth:`stats`.
         ``on_sync`` (if given) fires after each segment sync — the hook
         tests use to cancel in-flight requests or advance an injected
-        clock at a deterministic point."""
+        clock at a deterministic point.
+
+        Each loop round is one ``serve.round`` span holding, in order,
+        ``serve.admit`` (pop, page planning, prefill and bind dispatches),
+        ``serve.dispatch`` (the segment program's call), ``serve.fetch``
+        (the blocking token fetch), ``serve.consume`` (token bookkeeping,
+        retirement, deadlines) and ``serve.hook`` (``on_sync``)."""
         self._maybe_reset()
         for r in requests or []:
             self.submit(r)
@@ -1465,6 +1476,7 @@ class Scheduler:
         def now() -> float:
             return self._clock() - t_start
 
+        span = self._spans.span
         self._run_now = now
         try:
             while self._queue or any(s.active for s in self._slot):
@@ -1473,186 +1485,194 @@ class Scheduler:
                     break
                 if self._draining and not any(s.active for s in self._slot):
                     break  # drained: queued requests survive for the next run
-                # admission: coalesce this round's arrived requests into free slots
-                t = now()
-                free = [i for i, s in enumerate(self._slot) if not s.active]
-                if free and self._queue and not self._draining:
-                    picked = self._pop_arrived(len(free), t)
-                    if picked:
-                        if self.paged:
-                            admitted = self._admit_paged(free[: len(picked)], picked, t)
-                            if admitted:
-                                self._inject_admission_faults(
-                                    [i for i, _, _ in admitted],
-                                    [(rid, req) for _, rid, req in admitted],
-                                )
-                        else:
-                            if self.admission == "batched" and self.eng.batched_prefill:
-                                self._admit_batched(free[: len(picked)], picked, t)
-                            else:
-                                for i, (rid, req) in zip(free, picked):
-                                    self._admit(i, rid, req, t)
-                            self._inject_admission_faults(free, picked)
-                if self.paged:
-                    # one prefill chunk per admitting slot, then make sure
-                    # every decoding slot's table covers this segment's rows
-                    self._step_prefills()
-                    self._extend_paged()
-                active_idx = [i for i, s in enumerate(self._slot) if s.active]
-                if not active_idx:
-                    if not self._queue:
-                        continue  # drained; loop condition exits
-                    # nothing in flight: sleep until the next request arrives
-                    # (the queue head, since the queue is arrival-sorted) —
-                    # chunked so drain()/abort() from another thread can
-                    # interrupt an arbitrarily long idle wait
-                    wait = self._queue[0][0] - now()
-                    while (
-                        wait > 0
-                        and self._abort_status is None
-                        and not self._draining
-                    ):
-                        self._sleep(min(wait, 0.02))
+                with span("serve.round"):
+                    with span("serve.admit"):
+                        self._admission_round(now())
+                    active_idx = [i for i, s in enumerate(self._slot) if s.active]
+                    if not active_idx:
+                        if not self._queue:
+                            continue  # drained; loop condition exits
+                        # nothing in flight: sleep until the next request
+                        # arrives (the queue head, since the queue is
+                        # arrival-sorted) — chunked so drain()/abort() from
+                        # another thread can interrupt an arbitrarily long
+                        # idle wait
                         wait = self._queue[0][0] - now()
-                    continue
-                # seeded decode stall/hang injection rides immediately before
-                # the dispatch; a watchdog abort fired during the stall exits
-                # here instead of dispatching the segment
-                self._inject_decode_stall(active_idx)
-                if self._abort_status is not None:
-                    self._abort_epilogue(now())
-                    break
-                # decode one segment and sync once: tokens + integrity flags
-                # come back in the same device_get — the guard costs no
-                # extra host transfer
-                t0 = self._clock()
-                if self.speculative:
-                    # each scan step is one draft/verify ROUND: grids come
-                    # back S-wide (S = draft_k + 1) with per-round accepted
-                    # counts — the host consumes tokens[r, i, :nem[r, i]]
-                    if self.paged:
-                        (
-                            self._token, self._kdata, self._pstate,
-                            toks, nems, okg,
-                        ) = self._seg_spec_paged(
-                            self.eng.params, self._token, self._kdata,
-                            self._pstate, self.segment,
-                            bool(self.eng.quarantined),
-                        )
-                    else:
-                        (
-                            self._token, self._kdata, self._cache,
-                            toks, nems, okg,
-                        ) = self._seg_spec(
-                            self.eng.params, self._token, self._kdata,
-                            self._cache, self.segment,
-                            bool(self.eng.quarantined),
-                        )
-                    # (segment, slots, S), (segment, slots), (segment, slots, S)
-                    toks_np, nem_np, ok_np = jax.device_get((toks, nems, okg))
-                else:
-                    if self.paged:
-                        self._token, self._kdata, self._pstate, toks, okg = self._seg_paged(
-                            self.eng.params, self._token, self._kdata, self._pstate,
-                            self.segment, bool(self.eng.quarantined),
-                        )
-                    else:
-                        self._token, self._kdata, self._cache, toks, okg = self._seg(
-                            self.eng.params, self._token, self._kdata, self._cache,
-                            self.segment, bool(self.eng.quarantined),
-                        )
-                    toks_np, ok_np = jax.device_get((toks, okg))  # (segment, slots) x2
-                    # present the non-speculative grids as degenerate S=1
-                    # rounds so one consumption loop serves both modes
-                    toks_np = toks_np[:, :, None]
-                    ok_np = ok_np[:, :, None]
-                    nem_np = np.ones(toks_np.shape[:2], np.int64)
-                self._decode_s += self._clock() - t0
-                self._seg_steps += self.segment
-                self._active_slot_steps += len(active_idx) * self.segment
-                if self.paged:
-                    # each slot advanced by its own accepted-token total
-                    # (uniformly ``segment`` when not speculative)
-                    self._pos = [
-                        p + int(nem_np[:, i].sum()) for i, p in enumerate(self._pos)
-                    ]
-                self._kv_active_acc += len(active_idx)
-                self._kv_used_acc += (
-                    self._alloc.live_blocks * self._block_bytes
-                    if self.paged
-                    else len(active_idx) * self._slot_bytes
-                )
-                t = now()
-                for i in active_idx:
-                    slot = self._slot[i]
-                    n_before = len(slot.tokens) if slot.tokens is not None else 0
-                    if slot.prefill is not None:
-                        # mid-chunked-prefill: no tokens yet; only deadlines
-                        # and cancellation apply at this sync
-                        if slot.rid in self._cancel:
-                            self._counters["cancelled"] += 1
-                            self._retire(i, t, Status.CANCELLED)
-                        elif t > slot.deadline:
-                            self._counters["timed_out"] += 1
-                            self._retire(i, t, Status.TIMEOUT)
-                        continue
-                    if slot.rid in self._cancel:
-                        self._counters["cancelled"] += 1
-                        self._retire(i, t, Status.CANCELLED)
-                        continue
-                    if slot.first is not None:
-                        # deferred first token: EOS/budget checked here, at the
-                        # segment sync, never in the admission path
-                        first = int(np.asarray(slot.first).reshape(-1)[0])
-                        slot.tokens.append(first)
-                        slot.first = None
-                        slot.ttft_s = t - slot.arrival_s
-                        if slot.remaining == 0 or (
-                            slot.eos_id is not None and first == slot.eos_id
+                        while (
+                            wait > 0
+                            and self._abort_status is None
+                            and not self._draining
                         ):
-                            self._note_emission(slot, n_before, t)
-                            self._retire(i, t)
-                            continue
-                    stop = False
-                    for step in range(self.segment):
-                        if stop or slot.remaining <= 0:
-                            break
-                        used = 0
-                        for j in range(int(nem_np[step, i])):
-                            if not ok_np[step, i, j]:
-                                # non-finite logits: every token from this
-                                # position on is garbage — truncate and fail
-                                self._fail_slot(i, t)
-                                stop = True
-                                break
-                            tok = toks_np[step, i, j]
-                            slot.tokens.append(int(tok))
-                            slot.remaining -= 1
-                            used += 1
-                            if (
-                                slot.eos_id is not None and tok == slot.eos_id
-                            ) or slot.remaining == 0:
-                                self._retire(i, t)
-                                stop = True
-                                break
-                        if self.speculative and used:
-                            # acceptance accounting per consumed round: the
-                            # round proposed draft_k tokens and used-1 of
-                            # them survived verification (the first emission
-                            # is the round's pending token, not a draft)
-                            self._counters["spec_proposed"] += self._draft_k
-                            self._counters["spec_accepted"] += used - 1
-                    self._note_emission(slot, n_before, t)
-                    slot = self._slot[i]  # may have retired/failed above
-                    if slot.active and t > slot.deadline:
-                        self._counters["timed_out"] += 1
-                        self._retire(i, t, Status.TIMEOUT)
-                if on_sync is not None:
-                    on_sync(self)
+                            self._sleep(min(wait, 0.02))
+                            wait = self._queue[0][0] - now()
+                        continue
+                    # seeded decode stall/hang injection rides immediately
+                    # before the dispatch; a watchdog abort fired during the
+                    # stall exits here instead of dispatching the segment
+                    self._inject_decode_stall(active_idx)
+                    if self._abort_status is not None:
+                        self._abort_epilogue(now())
+                        break
+                    # decode one segment and sync once: tokens + integrity
+                    # flags come back in the same device_get — the guard
+                    # costs no extra host transfer
+                    with span("serve.dispatch", slots=len(active_idx)):
+                        grids = self._dispatch_segment()
+                    with span("serve.fetch"):
+                        toks_np, nem_np, ok_np = self._fetch(grids)
+                    with span("serve.consume"):
+                        self._consume(active_idx, toks_np, nem_np, ok_np, now())
+                    if on_sync is not None:
+                        with span("serve.hook"):
+                            on_sync(self)
         finally:
             self._run_now = None
         self._ran = True
         return self._completions
+
+    def _admission_round(self, t: float) -> None:
+        """Coalesce this round's arrived requests into free slots; in paged
+        mode also advance every chunked prefill by one chunk and extend the
+        decoding slots' tables over this segment's rows."""
+        free = [i for i, s in enumerate(self._slot) if not s.active]
+        if free and self._queue and not self._draining:
+            picked = self._pop_arrived(len(free), t)
+            if picked:
+                if self.paged:
+                    admitted = self._admit_paged(free[: len(picked)], picked, t)
+                    if admitted:
+                        self._inject_admission_faults(
+                            [i for i, _, _ in admitted],
+                            [(rid, req) for _, rid, req in admitted],
+                        )
+                else:
+                    if self.admission == "batched" and self.eng.batched_prefill:
+                        self._admit_batched(free[: len(picked)], picked, t)
+                    else:
+                        for i, (rid, req) in zip(free, picked):
+                            self._admit(i, rid, req, t)
+                    self._inject_admission_faults(free, picked)
+        if self.paged:
+            # one prefill chunk per admitting slot, then make sure every
+            # decoding slot's table covers this segment's rows
+            self._step_prefills()
+            self._extend_paged()
+
+    def _dispatch_segment(self) -> tuple:
+        """Dispatch one decode segment of the whole pool; returns its device
+        grids, not yet fetched."""
+        args = (self.eng.params, self._token, self._kdata)
+        dense = bool(self.eng.quarantined)
+        if self.speculative:
+            # each scan step is one draft/verify ROUND: grids come back
+            # S-wide (S = draft_k + 1) with per-round accepted counts — the
+            # host consumes tokens[r, i, :nem[r, i]]
+            if self.paged:
+                self._token, self._kdata, self._pstate, toks, nems, okg = self._seg_spec_paged(
+                    *args, self._pstate, self.segment, dense
+                )
+            else:
+                self._token, self._kdata, self._cache, toks, nems, okg = self._seg_spec(
+                    *args, self._cache, self.segment, dense
+                )
+            return toks, nems, okg
+        if self.paged:
+            self._token, self._kdata, self._pstate, toks, okg = self._seg_paged(
+                *args, self._pstate, self.segment, dense
+            )
+        else:
+            self._token, self._kdata, self._cache, toks, okg = self._seg(
+                *args, self._cache, self.segment, dense
+            )
+        return toks, None, okg
+
+    def _fetch(self, grids: tuple) -> tuple:
+        """The segment's one sync: ``(tokens, accepted counts, flags)`` on
+        the host, shaped ``(segment, slots, S)``, ``(segment, slots)``,
+        ``(segment, slots, S)``; a plain segment reads as degenerate S=1
+        rounds, so one consumption loop serves both modes."""
+        toks, nems, okg = grids
+        if nems is not None:
+            return jax.device_get((toks, nems, okg))
+        toks_np, ok_np = jax.device_get((toks, okg))  # (segment, slots) x2
+        return toks_np[:, :, None], np.ones(toks_np.shape, np.int64), ok_np[:, :, None]
+
+    def _consume(self, active_idx: List[int], toks_np, nem_np, ok_np, t: float) -> None:
+        """Account one synced segment: hand each active slot its tokens,
+        then retire what finished, was cancelled or blew its deadline."""
+        self._counters["syncs"] += 1
+        self._seg_steps += self.segment
+        self._active_slot_steps += len(active_idx) * self.segment
+        if self.paged:
+            # each slot advanced by its own accepted-token total
+            # (uniformly ``segment`` when not speculative)
+            self._pos = [p + int(nem_np[:, i].sum()) for i, p in enumerate(self._pos)]
+        self._kv_active_acc += len(active_idx)
+        self._kv_used_acc += (
+            self._alloc.live_blocks * self._block_bytes
+            if self.paged
+            else len(active_idx) * self._slot_bytes
+        )
+        for i in active_idx:
+            slot = self._slot[i]
+            n_before = len(slot.tokens) if slot.tokens is not None else 0
+            if slot.prefill is not None:
+                # mid-chunked-prefill: no tokens yet; only deadlines and
+                # cancellation apply at this sync
+                if slot.rid in self._cancel:
+                    self._counters["cancelled"] += 1
+                    self._retire(i, t, Status.CANCELLED)
+                elif t > slot.deadline:
+                    self._counters["timed_out"] += 1
+                    self._retire(i, t, Status.TIMEOUT)
+                continue
+            if slot.rid in self._cancel:
+                self._counters["cancelled"] += 1
+                self._retire(i, t, Status.CANCELLED)
+                continue
+            if slot.first is not None:
+                # deferred first token: EOS/budget checked here, at the
+                # segment sync, never in the admission path
+                first = int(np.asarray(slot.first).reshape(-1)[0])
+                slot.tokens.append(first)
+                slot.first = None
+                slot.ttft_s = t - slot.arrival_s
+                if slot.remaining == 0 or (slot.eos_id is not None and first == slot.eos_id):
+                    self._note_emission(slot, n_before, t)
+                    self._retire(i, t)
+                    continue
+            stop = False
+            for step in range(self.segment):
+                if stop or slot.remaining <= 0:
+                    break
+                used = 0
+                for j in range(int(nem_np[step, i])):
+                    if not ok_np[step, i, j]:
+                        # non-finite logits: every token from this position
+                        # on is garbage — truncate and fail
+                        self._fail_slot(i, t)
+                        stop = True
+                        break
+                    tok = toks_np[step, i, j]
+                    slot.tokens.append(int(tok))
+                    slot.remaining -= 1
+                    used += 1
+                    if (slot.eos_id is not None and tok == slot.eos_id) or slot.remaining == 0:
+                        self._retire(i, t)
+                        stop = True
+                        break
+                if self.speculative and used:
+                    # acceptance accounting per consumed round: the round
+                    # proposed draft_k tokens and used-1 of them survived
+                    # verification (the first emission is the round's
+                    # pending token, not a draft)
+                    self._counters["spec_proposed"] += self._draft_k
+                    self._counters["spec_accepted"] += used - 1
+            self._note_emission(slot, n_before, t)
+            slot = self._slot[i]  # may have retired/failed above
+            if slot.active and t > slot.deadline:
+                self._counters["timed_out"] += 1
+                self._retire(i, t, Status.TIMEOUT)
 
     def stats(self) -> Dict[str, float]:
         """Aggregate serve metrics for the most recent :meth:`run` epoch.
@@ -1661,7 +1681,10 @@ class Scheduler:
         are excluded) and are NaN when none do: an empty run must not read
         as an infinitely fast one.  The counters account every terminal
         path; ``quarantined`` counts pack-quarantine transitions (0 or 1 per
-        engine lifetime)."""
+        engine lifetime).  ``admit_s``, ``dispatch_s``, ``fetch_s``,
+        ``consume_s`` and ``hook_s`` are the host seconds of the run loop's
+        ``serve.*`` spans (``decode_s`` = dispatch + fetch); ``syncs``
+        counts segment syncs and ``prefill_*`` the prefill dispatches."""
         done = sorted(self._completions.values(), key=lambda c: c.rid)
         lat = np.asarray([c.latency_s for c in done], np.float64)
         lat = lat[np.isfinite(lat)]
@@ -1670,7 +1693,12 @@ class Scheduler:
         itl = np.asarray(self._itl, np.float64)
         itl = itl[np.isfinite(itl)]
         decoded = sum(max(len(c.tokens) - 1, 0) for c in done)
-        busy = self._decode_s + self._admit_s
+        # .get: AsyncEngine reads stats from another thread mid-run
+        sec = {k: self._spans.seconds.get("serve." + k, 0.0)
+               for k in ("admit", "dispatch", "fetch", "consume", "hook")}
+        decode_s = sec["dispatch"] + sec["fetch"]
+        busy = decode_s + sec["admit"]
+        cnt = self._counters
 
         def pct(a, q):
             return float(np.percentile(a, q)) if a.size else float("nan")
@@ -1679,8 +1707,14 @@ class Scheduler:
             "requests": len(done),
             "decoded_tokens": decoded,
             "sustained_tok_per_s": decoded / max(busy, 1e-9),
-            "decode_s": self._decode_s,
-            "admit_s": self._admit_s,
+            "decode_s": decode_s,
+            # host seconds of the run loop's phases (serve.* spans)
+            **{k + "_s": v for k, v in sec.items()},
+            # the padding share of the prefill programs' positions
+            "prefill_pad_share": (
+                1.0 - cnt["prefill_tokens"] / cnt["prefill_positions"]
+                if cnt["prefill_positions"] else float("nan")
+            ),
             "latency_p50_s": pct(lat, 50),
             "latency_p95_s": pct(lat, 95),
             "latency_p99_s": pct(lat, 99),
@@ -1694,10 +1728,8 @@ class Scheduler:
             # unified accounting (DESIGN.md §13): accepted tokens over decode
             # wall time — the same definition Engine.generate reports, so
             # speculative and plain runs compare on one axis
-            "tok_per_s": tok_per_s(decoded, self._decode_s),
-            "acceptance_rate": acceptance_rate(
-                self._counters["spec_accepted"], self._counters["spec_proposed"]
-            ),
+            "tok_per_s": tok_per_s(decoded, decode_s),
+            "acceptance_rate": acceptance_rate(cnt["spec_accepted"], cnt["spec_proposed"]),
         }
         # cache observability (DESIGN.md §11) — always present, NaN where the
         # gauge doesn't apply (slot-pool mode, or an epoch with no traffic),

@@ -7,7 +7,8 @@ compile ``vusa_packed_matmul`` and ``vusa_fused_mlp_matmul`` at
 48 slots per row, m=128) for every value format, at the ``k_blk`` that
 ``choose_k_blk`` picks on the chip, alone and under the Scheduler's vmapped
 slot axis.  Nothing runs: a pass means the chip's compiler accepts the
-kernels and that they lower to a Pallas TPU call.
+kernels and that they lower to a Pallas TPU call named after the kernel,
+the name a device trace shows for each call.
 
 This is the only test file that describes the chip.  The topology is
 described inside a module fixture (never at import or collection time), so
@@ -15,6 +16,7 @@ only the worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,3 +106,30 @@ def test_kernel_compiles_for_v5e(
     fn = jax.vmap(call, in_axes=(0, None, None, None)) if slot_axis else call
     compiled = jax.jit(fn).lower(x, v, p, s).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel, name", [
+    ("packed", None), ("packed", "vusa_packed_matmul_head"), ("fused", None),
+], ids=["packed", "packed_head", "fused"])
+def test_kernel_calls_carry_their_names(one_chip, no_persistent_cache, chip_k_blk, kernel, name):
+    """Each call compiles to a TPU custom call whose instruction is named
+    ``vusa_packed_matmul``, ``vusa_packed_matmul_head`` (the LM head's
+    call) or ``vusa_fused_mlp_matmul``, under the Scheduler's slot vmap."""
+    t = D_FF // M
+    v, p, s = _pack_shapes(one_chip, t, D_MODEL, "int8")
+    kw = dict(m=M, k_blk=chip_k_blk, interpret=False, value_dtype="int8")
+    if kernel == "packed":
+        if name is not None:
+            kw["name"] = name
+
+        def call(x, v, p, s):
+            return vusa_packed_matmul(x, v, p, s, **kw)
+    else:
+        def call(x, v, p, s):
+            return vusa_fused_mlp_matmul(x, v, p, v, p, v, p, s, s, s, **kw)
+    want = name or {"packed": "vusa_packed_matmul", "fused": "vusa_fused_mlp_matmul"}[kernel]
+    x = jax.ShapeDtypeStruct((BATCH, 1, D_MODEL), jnp.bfloat16, sharding=one_chip)
+    fn = jax.vmap(call, in_axes=(0, None, None, None))
+    text = jax.jit(fn).lower(x, v, p, s).compile().as_text()
+    calls = re.findall(r"%([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert calls and all(re.fullmatch(rf"{want}(\.\d+)?", c) for c in calls), calls
