@@ -47,7 +47,9 @@ Grid: (output windows, K blocks); K innermost for output-block accumulation.
 VMEM per step: x (B, K_blk), vals (K_blk, J*A), pos (K_blk, J*A),
 one-hot scratch (K_blk, slot_chunk, M) for "onehot", reconstructed W
 (K_blk, M) fp32, acc (B, M) fp32.  ``k_blk`` is the knob that bounds the
-scratch — see ``repro.kernels.ops.choose_k_blk``.
+scratch — see ``repro.kernels.ops.choose_k_blk``.  A ``jax.vmap`` over
+``x`` alone (the Scheduler's slot axis) keeps this grid: the vmapped rows
+become more rows of one call (``fold_slot_vmap``).
 
 ``vusa_fused_mlp_matmul`` is the whole-MLP megakernel (DESIGN.md §7): one
 ``pallas_call`` whose grid walks the ff windows.  Each step reconstructs
@@ -62,6 +64,8 @@ scratch is live at a time.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -71,6 +75,10 @@ from jax.experimental import pallas as pl
 __all__ = [
     "vusa_packed_matmul",
     "vusa_fused_mlp_matmul",
+    "fold_slot_vmap",
+    "fold_tally",
+    "calls_repeat",
+    "FoldTally",
     "RECONSTRUCT_MODES",
     "DEFAULT_SLOT_CHUNK",
 ]
@@ -142,10 +150,30 @@ def _tile(raw, pos, m: int, reconstruct: str, slot_chunk: int, value_dtype: str)
     return w
 
 
+def _contract(a, b, b_dim: int, exact_rows: bool):
+    """``a`` (B, n) contracted with axis ``b_dim`` of the 2-D ``b``, in fp32.
+
+    ``exact_rows`` (interpret mode) spells it as a broadcast multiply-reduce,
+    whose summation order does not depend on B.  XLA:CPU's gemms pick their
+    order by shape, so a B-row call would differ in the last bit from B
+    one-row calls, and both the batched speculative verify (DESIGN.md §13)
+    and a slot vmap folded into rows rely on the kernels being row-bitwise.
+    Compiled, it is the MXU ``dot_general``."""
+    if not exact_rows:
+        return jax.lax.dot_general(
+            a, b, (((1,), (b_dim,)), ((), ())), preferred_element_type=jnp.float32
+        )
+    if b_dim == 0:
+        return jnp.sum(a[:, :, None] * b[None], axis=1)
+    return jnp.sum(a[:, None, :] * b[None], axis=2)
+
+
 def _kernel(x_ref, val_ref, pos_ref, *rest, m: int, reconstruct: str, slot_chunk: int,
-            value_dtype: str):
+            value_dtype: str, exact_rows: bool):
     """One (window, K block) step; ``rest`` is ``(scale_ref, y_ref)`` for a
-    quantized pack and ``(y_ref,)`` otherwise."""
+    quantized pack and ``(y_ref,)`` otherwise.  ``exact_rows``: as in
+    ``_contract``, so a call on a slot vmap's folded rows equals the
+    per-slot calls bit for bit in interpret mode."""
     scale_ref, y_ref = rest if len(rest) == 2 else (None, rest[0])
 
     @pl.when(pl.program_id(1) == 0)
@@ -156,7 +184,7 @@ def _kernel(x_ref, val_ref, pos_ref, *rest, m: int, reconstruct: str, slot_chunk
     if scale_ref is not None:
         x = x * scale_ref[0]  # (1, K_blk) row scales fold into x's columns
     w = _tile(val_ref[0], pos_ref[0], m, reconstruct, slot_chunk, value_dtype)
-    y_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    y_ref[...] += _contract(x, w, 0, exact_rows)
 
 
 @functools.partial(
@@ -165,7 +193,7 @@ def _kernel(x_ref, val_ref, pos_ref, *rest, m: int, reconstruct: str, slot_chunk
         "interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype", "name",
     ),
 )
-def vusa_packed_matmul(
+def _packed_matmul(
     x: jax.Array,  # (B, K)
     values: jax.Array,  # (T, K, J*A)  per window: A slots x J jobs per row
     positions: jax.Array,  # (T, K, J*A) int8 lane index per slot (-1 = idle)
@@ -179,8 +207,10 @@ def vusa_packed_matmul(
     value_dtype: str = "dense",
     name: str = "vusa_packed_matmul",
 ) -> jax.Array:
-    """``name`` labels the call in compiled programs and device traces; a
-    trace reader finds every call by its ``vusa_packed_matmul`` prefix."""
+    """y = x @ W for a row-packed W, fp32 (B, T*m).  ``name`` labels the
+    call in compiled programs and device traces; a trace reader finds every
+    call by its ``vusa_packed_matmul`` prefix.  A ``jax.vmap`` over ``x``
+    alone folds into the call's rows (``fold_slot_vmap``)."""
     b, k = x.shape
     t, kk, vslots = values.shape
     slots = positions.shape[2]
@@ -211,7 +241,7 @@ def vusa_packed_matmul(
     return pl.pallas_call(
         functools.partial(
             _kernel, m=m, reconstruct=reconstruct, slot_chunk=slot_chunk,
-            value_dtype=value_dtype,
+            value_dtype=value_dtype, exact_rows=interpret,
         ),
         grid=(t, k // k_blk),
         in_specs=in_specs,
@@ -225,24 +255,6 @@ def vusa_packed_matmul(
 # --------------------------------------------------------------------------
 # Fused packed-MLP megakernel (DESIGN.md §7)
 # --------------------------------------------------------------------------
-
-
-def _contract(a, b, b_dim: int, exact_rows: bool):
-    """``a`` (B, n) contracted with axis ``b_dim`` of the 2-D ``b``, in fp32.
-
-    ``exact_rows`` (interpret mode) spells it as a broadcast multiply-reduce,
-    whose summation order does not depend on B.  XLA:CPU's gemms pick their
-    order by shape, so a B-row call would differ in the last bit from B
-    one-row calls, and the batched speculative verify relies on the
-    fused MLP being row-bitwise (DESIGN.md §13).  Compiled, it is the MXU
-    ``dot_general``."""
-    if not exact_rows:
-        return jax.lax.dot_general(
-            a, b, (((1,), (b_dim,)), ((), ())), preferred_element_type=jnp.float32
-        )
-    if b_dim == 0:
-        return jnp.sum(a[:, :, None] * b[None], axis=1)
-    return jnp.sum(a[:, None, :] * b[None], axis=2)
 
 
 def _row_chunks(n: int, k_blk: int, body, carry):
@@ -311,7 +323,7 @@ def _fused_mlp_kernel(
     jax.jit,
     static_argnames=("interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype"),
 )
-def vusa_fused_mlp_matmul(
+def _fused_mlp_matmul(
     x: jax.Array,  # (B, K)
     gate_values: jax.Array,  # (T, K, Sg)   w_gate row-pack
     gate_positions: jax.Array,  # (T, K, Sg) int8
@@ -340,7 +352,8 @@ def vusa_fused_mlp_matmul(
     the full ``(B, D)`` output, which accumulates across the grid in fp32.
     Zero-padded ff lanes (C % m != 0) are exact no-ops: gate/up reconstruct
     to zero columns there (``silu(0) * 0 = 0``) and the transposed down pack
-    holds no slots pointing at them.  Returns (B, D) fp32.
+    holds no slots pointing at them.  Returns (B, D) fp32.  A ``jax.vmap``
+    over ``x`` alone folds into the call's rows (``fold_slot_vmap``).
     """
     b, k = x.shape
     t, kk, _ = gate_values.shape
@@ -394,3 +407,102 @@ def vusa_fused_mlp_matmul(
         interpret=interpret,
         name="vusa_fused_mlp_matmul",
     )(*operands)
+
+
+# --------------------------------------------------------------------------
+# Slot vmap -> kernel rows
+# --------------------------------------------------------------------------
+#
+# ``pallas_call``'s own batching rule turns a vmapped axis into an extra
+# outer grid axis, so a Scheduler that decodes n slots under ``jax.vmap``
+# would run n grid passes per call, each reconstructing the whole pack to
+# multiply one slot's rows.  The kernels cost almost the same for 1 row as
+# for 8 (a block pads to 8 sublanes; the reconstruction does not see B), so
+# when only ``x`` carries the vmapped axis the rule below reshapes it to
+# rows and makes one call.  Each row's math is unchanged.
+
+_TALLY: contextvars.ContextVar = contextvars.ContextVar("vusa_fold_tally", default=None)
+_REPEAT: contextvars.ContextVar = contextvars.ContextVar("vusa_call_repeat", default=1)
+
+
+class FoldTally:
+    """Packed-kernel calls that met a vmap while a program was traced:
+    ``folded`` went to the kernel's rows, ``fallback`` to ``pallas_call``'s
+    grid-axis rule (a batched pack).  Each call counts the times it runs
+    per step of the traced program (``calls_repeat``)."""
+
+    def __init__(self):
+        self._sites = {}
+
+    def record(self, site, repeat: int, folded: bool):
+        self._sites[site] = (repeat, folded)  # a site re-batched counts once
+
+    @property
+    def folded(self) -> int:
+        return sum(r for r, f in self._sites.values() if f)
+
+    @property
+    def fallback(self) -> int:
+        return sum(r for r, f in self._sites.values() if not f)
+
+
+@contextlib.contextmanager
+def fold_tally():
+    """Collect a :class:`FoldTally` of the vmapped kernel calls traced
+    inside the block (trace time only; a compiled program reruns nothing)."""
+    tally = FoldTally()
+    token = _TALLY.set(tally)
+    try:
+        yield tally
+    finally:
+        _TALLY.reset(token)
+
+
+@contextlib.contextmanager
+def calls_repeat(n: int):
+    """Kernel calls traced inside the block run ``n`` times per step: the
+    body of an ``n``-long scan (layers, draft steps).  Nests by product."""
+    token = _REPEAT.set(_REPEAT.get() * n)
+    try:
+        yield
+    finally:
+        _REPEAT.reset(token)
+
+
+def fold_slot_vmap(kernel):
+    """Give ``kernel(x, *pack, **static)`` a vmap rule of its own: when only
+    ``x`` (B, K) carries the vmapped axis, the (n, B, K) rows run as one
+    (n*B, K) call and the result is reshaped back; when any pack operand is
+    batched, it falls back to ``jax.vmap`` of the kernel (the grid axis).
+    Unbatched calls are the kernel's own program."""
+
+    @functools.wraps(kernel)
+    def call(x, *pack, **static):
+        run = functools.partial(kernel, **static)
+        repeat = _REPEAT.get()
+
+        @jax.custom_batching.custom_vmap
+        def folded(x, *pack):
+            return run(x, *pack)
+
+        @folded.def_vmap
+        def rule(axis_size, in_batched, x, *pack):
+            x_batched, *pack_batched = in_batched
+            fold = x_batched and not any(jax.tree.leaves(pack_batched))
+            tally = _TALLY.get()
+            if tally is not None:
+                tally.record(rule, repeat, fold)
+            if fold:
+                n, b, k = x.shape
+                y = folded(x.reshape(n * b, k), *pack)  # an outer vmap folds again
+                return y.reshape(n, b, *y.shape[1:]), True
+            axes = [0 if bt else None for bt in in_batched]
+            return jax.vmap(run, in_axes=axes)(x, *pack), True
+
+        return folded(x, *pack)
+
+    return call
+
+
+vusa_packed_matmul = fold_slot_vmap(_packed_matmul)
+vusa_fused_mlp_matmul = fold_slot_vmap(_fused_mlp_matmul)

@@ -389,6 +389,7 @@ class Engine:
           position i's logits equal the sequential step's (multi-token
           parity) and its draw consumes the same subkey.
         """
+        from ..kernels.vusa_packed import calls_repeat
         from .packed import lm_decode_step_packed
 
         k = self.sc.draft_k
@@ -406,9 +407,10 @@ class Engine:
                 ).astype(jnp.int32)
                 return (nxt[:, None], c), nxt
 
-            (_, cache), drafts = jax.lax.scan(
-                draft_body, (token, cache), None, length=k
-            )
+            with calls_repeat(k):
+                (_, cache), drafts = jax.lax.scan(
+                    draft_body, (token, cache), None, length=k
+                )
             seq = jnp.concatenate([token, jnp.moveaxis(drafts, 0, 1)], axis=1)
             cache = {**cache, "pos": pos0}  # rewind: verifier rewrites rows pos0..pos0+k
             if packed is not None:

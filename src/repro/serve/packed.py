@@ -40,6 +40,7 @@ from ..kernels.ops import (
     pack_linear_rows_t,
     shard_linear_windows,
 )
+from ..kernels.vusa_packed import calls_repeat
 from ..models import families as F
 from ..models.common import rms_norm
 
@@ -606,7 +607,8 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
             return x, {"k_new": new_cache["k_new"], "v_new": new_cache["v_new"]}
         return x, {"k": new_cache["k"], "v": new_cache["v"]}
 
-    x, new_kv = jax.lax.scan(body, x, xs)
+    with calls_repeat(cfg.n_layers):
+        x, new_kv = jax.lax.scan(body, x, xs)
     with jax.named_scope("decode.head"):
         x = rms_norm(x, params["final_norm"])
         if packed.get("head") is not None:

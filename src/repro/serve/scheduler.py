@@ -95,6 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.vusa_packed import fold_tally
 from .engine import Engine
 from .metrics import SpanTimes, acceptance_rate, tok_per_s
 
@@ -364,6 +365,10 @@ class Scheduler:
             # (bucket length x padded batch rows)
             syncs=0, prefill_dispatches=0, prefill_tokens=0, prefill_positions=0,
         )
+        # packed-kernel calls per decode step of the last traced segment
+        # program: folded into the slot rows, and left to the grid-axis
+        # fallback (``_vmap_slots``); set at trace time, not per run epoch
+        self._kernel_calls = (0, 0)
         # streaming/watchdog state (DESIGN.md §12).  `_abort_status` is the
         # fail-fast flag another thread (the async engine's watchdog) sets:
         # the run loop checks it at every sync and inside every injected
@@ -649,6 +654,15 @@ class Scheduler:
 
     # -- jitted segment body --------------------------------------------------
 
+    def _vmap_slots(self, one, *args, in_axes=0):
+        """``jax.vmap(one, in_axes)(*args)`` over the slot axis, recording
+        how its packed-kernel calls met the vmap (``stats()``'s
+        ``packed_calls_folded`` and ``packed_calls_fallback``)."""
+        with fold_tally() as tally:
+            out = jax.vmap(one, in_axes=in_axes)(*args)
+        self._kernel_calls = (tally.folded, tally.fallback)
+        return out
+
     def _segment_fn(self, params, token, kdata, cache, steps: int, dense: bool):
         """``steps`` decode steps of all slots; returns the emitted token grid
         and per-step integrity flags, both ``(steps, slots)``, plus the
@@ -673,7 +687,7 @@ class Scheduler:
                 nxt, c2, ok = decode(params, tok, c, sub)
                 return nxt, jax.random.key_data(key), c2, ok
 
-            token, kdata, cache, ok = jax.vmap(one)(token, kdata, cache)
+            token, kdata, cache, ok = self._vmap_slots(one, token, kdata, cache)
             return (token, kdata, cache), (token[:, 0, 0], ok[:, 0])
 
         (token, kdata, cache), (toks, okg) = jax.lax.scan(
@@ -704,8 +718,8 @@ class Scheduler:
                 rows = {n + "_new": c2[n + "_new"] for n in names}
                 return nxt, jax.random.key_data(key), rows, ok
 
-            token, kdata, rows, ok = jax.vmap(one, in_axes=(0, 0, paged_in_axes(pstate)))(
-                token, kdata, paged_view(pstate)
+            token, kdata, rows, ok = self._vmap_slots(
+                one, token, kdata, paged_view(pstate), in_axes=(0, 0, paged_in_axes(pstate))
             )
             with jax.named_scope("decode.attention"):
                 pstate = paged_scatter_token(pstate, rows)
@@ -735,7 +749,7 @@ class Scheduler:
                 pending, c2, kd2, emit, nem, okp = spec(params, tok, c, kd)
                 return pending, kd2, c2, emit, nem, okp
 
-            token, kdata, cache, emit, nem, okp = jax.vmap(one)(token, kdata, cache)
+            token, kdata, cache, emit, nem, okp = self._vmap_slots(one, token, kdata, cache)
             return (token, kdata, cache), (emit, nem, okp)
 
         (token, kdata, cache), (toks, nems, okg) = jax.lax.scan(
@@ -784,9 +798,9 @@ class Scheduler:
                 }
                 return pending, kd2, rows, emit, nem, okp
 
-            token, kdata, rows, emit, nem, okp = jax.vmap(
-                one, in_axes=(0, 0, paged_in_axes(pstate))
-            )(token, kdata, paged_view(pstate))
+            token, kdata, rows, emit, nem, okp = self._vmap_slots(
+                one, token, kdata, paged_view(pstate), in_axes=(0, 0, paged_in_axes(pstate))
+            )
             with jax.named_scope("decode.attention"):
                 pstate = paged_scatter_rows(pstate, rows, start, nem)
             return (token, kdata, pstate), (emit, nem, okp)
@@ -1684,7 +1698,11 @@ class Scheduler:
         engine lifetime).  ``admit_s``, ``dispatch_s``, ``fetch_s``,
         ``consume_s`` and ``hook_s`` are the host seconds of the run loop's
         ``serve.*`` spans (``decode_s`` = dispatch + fetch); ``syncs``
-        counts segment syncs and ``prefill_*`` the prefill dispatches."""
+        counts segment syncs and ``prefill_*`` the prefill dispatches.
+        ``packed_calls_folded`` and ``packed_calls_fallback`` count the
+        packed-kernel calls per decode step (per round, speculative) of the
+        last traced segment program that ran the slots as kernel rows, and
+        that took the grid-axis fallback."""
         done = sorted(self._completions.values(), key=lambda c: c.rid)
         lat = np.asarray([c.latency_s for c in done], np.float64)
         lat = lat[np.isfinite(lat)]
@@ -1771,4 +1789,5 @@ class Scheduler:
             else float("nan")
         )
         out.update(self._counters)
+        out["packed_calls_folded"], out["packed_calls_fallback"] = self._kernel_calls
         return out

@@ -8,7 +8,8 @@ compile ``vusa_packed_matmul`` and ``vusa_fused_mlp_matmul`` at
 ``choose_k_blk`` picks on the chip, alone and under the Scheduler's vmapped
 slot axis.  Nothing runs: a pass means the chip's compiler accepts the
 kernels and that they lower to a Pallas TPU call named after the kernel,
-the name a device trace shows for each call.
+the name a device trace shows for each call.  Under the slot vmap the
+program holds one such call, on all the slots' rows.
 
 This is the only test file that describes the chip.  The topology is
 described inside a module fixture (never at import or collection time), so
@@ -84,6 +85,19 @@ def _pack_shapes(sharding, t: int, rows: int, value_dtype: str):
     return values, positions, scales
 
 
+def _tpu_calls(text: str):
+    """``(instruction name, x operand shape)`` of each TPU custom call in a
+    compiled program's text."""
+    return [
+        (name, tuple(int(d) for d in shape.split(",")))
+        for name, shape in re.findall(
+            r"%([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\", "
+            r"operand_layout_constraints=\{\w+\[([\d,]+)\]",
+            text,
+        )
+    ]
+
+
 @pytest.mark.parametrize("slot_axis", [False, True], ids=["engine", "scheduler"])
 @pytest.mark.parametrize("value_dtype", ["dense", "int8", "int4"])
 @pytest.mark.parametrize("kernel", ["packed", "fused"])
@@ -99,13 +113,15 @@ def test_kernel_compiles_for_v5e(
     else:
         def call(x, v, p, s):
             return vusa_fused_mlp_matmul(x, v, p, v, p, v, p, s, s, s, **kw)
-    # the Scheduler decodes every slot at batch 1 under jax.vmap, which turns
-    # the slot axis into an extra grid axis of the same kernel
+    # the Scheduler decodes every slot at batch 1 under jax.vmap; the
+    # kernels' vmap rule folds the slot axis into the call's rows
     x_shape = (BATCH, 1, D_MODEL) if slot_axis else (BATCH, D_MODEL)
     x = jax.ShapeDtypeStruct(x_shape, jnp.bfloat16, sharding=one_chip)
     fn = jax.vmap(call, in_axes=(0, None, None, None)) if slot_axis else call
-    compiled = jax.jit(fn).lower(x, v, p, s).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(x, v, p, s).compile().as_text()
+    # one call on all BATCH rows, with the slot axis or without
+    calls = _tpu_calls(text)
+    assert [shape for _, shape in calls] == [(BATCH, D_MODEL)], calls
 
 
 @pytest.mark.parametrize("kernel, name", [
@@ -131,5 +147,8 @@ def test_kernel_calls_carry_their_names(one_chip, no_persistent_cache, chip_k_bl
     x = jax.ShapeDtypeStruct((BATCH, 1, D_MODEL), jnp.bfloat16, sharding=one_chip)
     fn = jax.vmap(call, in_axes=(0, None, None, None))
     text = jax.jit(fn).lower(x, v, p, s).compile().as_text()
-    calls = re.findall(r"%([\w.]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-    assert calls and all(re.fullmatch(rf"{want}(\.\d+)?", c) for c in calls), calls
+    calls = _tpu_calls(text)
+    assert len(calls) == 1, calls
+    (call_name, x_shape), = calls
+    assert re.fullmatch(rf"{want}(\.\d+)?", call_name), calls
+    assert x_shape == (BATCH, D_MODEL), calls
