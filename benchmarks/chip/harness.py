@@ -4,20 +4,23 @@ reduce.
 A cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a
 configuration file (``configs/<name>.json``: the model's sizes as run, the
 ``repro.configs`` module they must equal but for the keys it lists under
-``reduced``, sparsity and serve settings), a traffic mix
+``reduced``, its family module, sparsity and serve settings), a traffic mix
 (``traffic/<name>.json``, read by ``traffic.py``) and its limits
 (``limits/<cell>.json``).  Per-layer metrics are read by
-``metrics/<metric>.py``.  Nothing here names a cell, a mix or a metric: a
-new one is new files and a new entry.
+``metrics/<metric>.py``.  The family module (``references/<name>.py``,
+the configuration's ``reference``) is the plain reference and all that is
+known of the model's family: its program keys, weight layout, kernel calls
+and FLOPs.  Nothing here names a cell, a mix, a metric, a family or a
+kernel: a new one is new files and a new entry.
 
 One run, in one process:
 
 1. Set-up (``setup_s``): weights from the seed on the device
-   (``weights.py``), the ``Engine`` and ``Scheduler`` the configuration
-   states, and a warm-up: ``Engine.prime_many`` at every prefill bucket x
-   batch bucket the mix's prompt lengths can reach, and as few
-   ``Scheduler`` rounds as put every batch bucket through admission and
-   run the decode segment.
+   (``weights.py``, in the family's layout), the ``Engine`` and
+   ``Scheduler`` the configuration states, and a warm-up:
+   ``Engine.prime_many`` at every prefill bucket x batch bucket the mix's
+   prompt lengths can reach, and as few ``Scheduler`` rounds as put
+   every batch bucket through admission and run the decode segment.
 2. The window: the mix's requests are submitted and ``Scheduler.run``
    serves them.  An ``on_sync`` hook stamps each request's tokens as they
    reach the host.  The window closes at the first segment sync at or past
@@ -42,6 +45,7 @@ import shutil
 import sys
 import tempfile
 import time
+from functools import partial
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -62,7 +66,9 @@ import xplane  # noqa: E402
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_AT = 0.4  # the traced part of the window starts at this share of it
 TRACE_S = 4.0  # and lasts this long at most (a quarter of the window at most)
-CACHE_MAX_BYTES = 1 << 30  # larger executables are not written to the compile cache
+# the compile cache's size: a larger executable is not written, and the entries used
+# longest ago are deleted once the cache holds more
+CACHE_MAX_BYTES = 1 << 30
 
 
 class NoAccelerator(RuntimeError):
@@ -107,38 +113,44 @@ def log(*a):
 
 # -- configuration --------------------------------------------------------
 
-PROGRAM_KEYS = {  # configuration key -> ArchConfig field it must equal
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "kv_heads",
-    "head_dim": "hd", "intermediate_size": "d_ff", "vocab_size": "vocab",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-}
+def family(config: dict):
+    """The configuration's family module, ``references/<reference>.py``."""
+    return _load("references", config["reference"])
 
 
-def program_config(config: dict, smoke: bool):
+def program_config(config: dict, smoke: bool, fam):
     """(ArchConfig, model sizes).  The program's configuration module must
-    state the file's sizes, but for the keys the file lists under
-    ``reduced``, which are set to the file's; ``smoke`` takes the module's
-    small preset instead (CPU rehearsal only)."""
+    be one the family module ``fam`` computes (``check_program``) and state
+    the file's sizes (``PROGRAM_KEYS``), but for the keys the file lists
+    under ``reduced``, which are set to the file's; ``smoke`` takes the
+    module's small preset's sizes instead (CPU rehearsal only)."""
     from repro.configs import get_config, get_smoke_config
 
     mod = config["program_config"]
     arch = (get_smoke_config if smoke else get_config)(mod)
-    if arch.family != "dense" or arch.qkv_bias or arch.qk_norm:
-        raise ValueError(f"{mod}: the dense reference covers no qkv bias or qk norm")
+    fam.check_program(arch)
+    keys = fam.PROGRAM_KEYS
     if smoke:
-        model = {k: getattr(arch, f) for k, f in PROGRAM_KEYS.items()}
-        model["rms_norm_eps"] = config["model"]["rms_norm_eps"]
-        model["torch_dtype"] = config["model"]["torch_dtype"]
-        return arch, model
+        return arch, {**config["model"], **{k: getattr(arch, f) for k, f in keys.items()}}
     model = config["model"]
-    cut = {f: model[k] for k, f in PROGRAM_KEYS.items() if k in config["reduced"]}
+    cut = {f: model[k] for k, f in keys.items() if k in config["reduced"]}
     arch = dataclasses.replace(arch, **cut)
-    bad = {k: (model[k], getattr(arch, f)) for k, f in PROGRAM_KEYS.items()
+    bad = {k: (model[k], getattr(arch, f)) for k, f in keys.items()
            if model[k] != getattr(arch, f)}
     if bad:
         raise ValueError(f"repro.configs.{mod} differs from {config['name']}: {bad}")
     return arch, model
+
+
+def counts(fam, model: dict, nnz: dict, serve: dict) -> dict:
+    """What the family module ``fam`` counts of the served work: ``calls``,
+    the packed-kernel calls of one decode step by kernel name (at one row
+    per slot, in the configuration's packed values), and ``decode_flops``
+    (of a token at a context length) and ``prefill_flops`` (of a prompt of
+    a length), the model FLOPs."""
+    return {"calls": fam.packed_calls(model, nnz, serve["slots"], serve["packed_values"]),
+            "decode_flops": partial(fam.decode_flops, model),
+            "prefill_flops": partial(fam.prefill_flops, model)}
 
 
 # -- the window -----------------------------------------------------------
@@ -263,7 +275,8 @@ class Bench:
                 f"{len(self.devices)} {self.dev.platform} device(s)")
         from repro.serve import Engine, Scheduler, ServeConfig
 
-        self.arch, self.model = program_config(cell.config, smoke)
+        self.family = family(cell.config)
+        self.arch, self.model = program_config(cell.config, smoke, self.family)
         self.serve = serve = {**cell.config["serve"], **(overrides or {})}
         self.sparsity = float(cell.config["sparsity"])
         log(f"{cell.name} seed {seed}: {self.dev.device_kind} x{len(self.devices)}, "
@@ -272,9 +285,11 @@ class Bench:
             f"serve {json.dumps(serve)}")
         t = time.monotonic()
         seed32 = int(np.random.SeedSequence(seed).generate_state(1)[0])
-        self.weights = jax.block_until_ready(
-            weights_mod.make_weights(self.model, self.sparsity, seed32))
-        self.nnz = weights_mod.nonzeros(self.weights)
+        layout = self.family.layout(self.model)
+        self.weights = jax.block_until_ready(weights_mod.make_weights(
+            layout, self.sparsity, seed32, self.model["torch_dtype"], self.model["vocab_size"]))
+        self.nnz = weights_mod.nonzeros(self.weights, layout)
+        self.counts = counts(self.family, self.model, self.nnz, serve)
         self.t_weights = time.monotonic() - t
         t = time.monotonic()
         self.eng = Engine(self.arch, self.weights, ServeConfig(
@@ -409,7 +424,7 @@ def serve_window(b: Bench, mix: dict, seed: int, seconds: float, trace: bool,
     if trace_dir:
         files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
         if files:
-            summary = xplane.summarize(str(files[-1]))
+            summary = xplane.summarize(str(files[-1]), list(b.counts["calls"]))
             if keep_trace:
                 out = Path(keep_trace)
                 out.mkdir(parents=True, exist_ok=True)
@@ -473,15 +488,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start=None
         window_s=win.seconds, stats=win.stats, records=win.records, prefills=b.prefills,
         trace=win.summary, trace_t=win.trace_t, model=b.model, serve=b.serve,
         peaks=work.peaks(b.dev.device_kind) if b.dev.platform != "cpu" else None,
-        calls=work.packed_calls(b.model, b.nnz, b.serve["slots"], b.serve["packed_values"]),
-        tokens_in=win.tracker.tokens_in,
+        tokens_in=win.tracker.tokens_in, **b.counts,
     )
     b.free()
 
     t = time.monotonic()
     picked = check.sample(served, seed, int(cell.limits["sample_tokens"]))
-    reference = _load("references", cell.config["reference"])
-    got = check.served_gap(reference, b.weights, b.model, picked, b.serve["max_len"])
+    got = check.served_gap(b.family, b.weights, b.model, picked, b.serve["max_len"])
     log(f"  reference check of {len(picked)} requests took {time.monotonic() - t:.2f}s; "
         f"argmax share {got['argmax_share']:.4f}")
     checks = {}

@@ -6,8 +6,16 @@ of the program under test: no import of it, no cache, no kernels, no
 batching.  ``logits(weights, tokens, model)`` runs one whole sequence
 causally and returns the logits at every position, in float32 with
 ``highest`` matmul precision (on the TPU a float32 matmul otherwise runs in
-bf16 passes).  Weights are read in the layout ``weights.py`` documents; the
+bf16 passes).  Weights are read in the tree ``layout`` describes; the
 layers run one at a time under a scan, so only one layer is ever expanded.
+
+The module is also the benchmark's whole description of the family: the
+harness knows it only through ``PROGRAM_KEYS`` and ``check_program``
+(which program configurations it stands for), ``layout`` (the weight tree,
+for ``weights.py``), ``packed_calls`` (the packed-kernel calls of a decode
+step by kernel name, for the rooflines and for finding the kernels in a
+trace) and ``decode_flops`` / ``prefill_flops`` (model FLOPs, for
+``step_mfu``).
 """
 
 from __future__ import annotations
@@ -16,6 +24,101 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+import work
+from weights import Leaf, pad_vocab
+
+PROGRAM_KEYS = {  # configuration key -> ArchConfig field it must equal
+    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "kv_heads",
+    "head_dim": "hd", "intermediate_size": "d_ff", "vocab_size": "vocab",
+    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
+}
+
+
+def check_program(arch) -> None:
+    """Refuse a program configuration this reference does not compute."""
+    if arch.family != "dense" or arch.qkv_bias or arch.qk_norm:
+        raise ValueError(f"{arch.name}: the dense reference covers no qkv bias or qk norm")
+
+
+def layout(m: dict) -> dict:
+    """{path: Leaf} of the weight tree the served program takes
+    (``Engine(cfg, params)``) and ``logits`` reads: ``embed (V, d)``,
+    ``lm_head (d, V)``, ``final_norm (d,)`` and per-layer stacks
+    ``layers/attn/{wq, wk, wv} (L, d, H, hd)``, ``wo (L, H, hd, d)``,
+    ``layers/ffn/{w_gate, w_up} (L, d, f)``, ``w_down (L, f, d)``,
+    ``norm1``/``norm2 (L, d)``.  ``V`` is the vocabulary padded to a
+    multiple of 256, as the program lays it out."""
+    L, d, f = m["num_hidden_layers"], m["hidden_size"], m["intermediate_size"]
+    h, kv, hd = m["num_attention_heads"], m["num_key_value_heads"], m["head_dim"]
+    v = pad_vocab(m["vocab_size"])
+
+    def stack(shape, fan_in=None, init="pruned"):
+        return Leaf((L, *shape), fan_in, init, stacks=1)
+
+    return {
+        "embed": Leaf((v, d), None, "normal", vocab_axis=0),
+        "final_norm": Leaf((d,), None, "zeros"),
+        "lm_head": Leaf((d, v), d, "pruned", vocab_axis=1),
+        "layers/norm1": stack((d,), init="zeros"),
+        "layers/norm2": stack((d,), init="zeros"),
+        "layers/attn/wq": stack((d, h, hd), d),
+        "layers/attn/wk": stack((d, kv, hd), d),
+        "layers/attn/wv": stack((d, kv, hd), d),
+        "layers/attn/wo": stack((h, hd, d), h * hd),
+        "layers/ffn/w_gate": stack((d, f), d),
+        "layers/ffn/w_up": stack((d, f), d),
+        "layers/ffn/w_down": stack((f, d), f),
+    }
+
+
+def packed_calls(model: dict, nnz: dict, rows: int, values: str) -> dict:
+    """The calls of one decode step, per kernel: ``vusa_packed_matmul`` runs
+    wq, wk, wv, wo of every layer and the head; ``vusa_fused_mlp_matmul``
+    runs each layer's whole MLP.  ``rows`` rows per call (one per slot);
+    the pack's bytes are counted once per call, however many rows it
+    serves."""
+    L, d, f = model["num_hidden_layers"], model["hidden_size"], model["intermediate_size"]
+    h, kv, hd = model["num_attention_heads"], model["num_key_value_heads"], model["head_dim"]
+    packed, fused = [], []
+    for layer in range(L):
+        for name, k, n in (("wq", d, h * hd), ("wk", d, kv * hd), ("wv", d, kv * hd),
+                           ("wo", h * hd, d)):
+            packed.append(work.linear(rows, k, n, float(nnz[f"layers/attn/{name}"][layer]),
+                                      values))
+        z = sum(float(nnz[f"layers/ffn/{n}"][layer]) for n in ("w_gate", "w_up", "w_down"))
+        fused.append(work.fused_mlp(rows, d, f, z, values))
+    packed.append(work.linear(rows, d, model["vocab_size"], float(nnz["lm_head"]), values))
+    return {"vusa_packed_matmul": packed, "vusa_fused_mlp_matmul": fused}
+
+
+def dense_params(model: dict) -> tuple:
+    """(matmul parameters of the layers, of the head), dense-equivalent."""
+    L, d, f = model["num_hidden_layers"], model["hidden_size"], model["intermediate_size"]
+    h, kv, hd = model["num_attention_heads"], model["num_key_value_heads"], model["head_dim"]
+    body = L * (d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f)
+    return body, d * model["vocab_size"]
+
+
+def attn_flops(model: dict, ctx: float) -> float:
+    """QK^T and AV of one token against ``ctx`` positions, all layers."""
+    return 4.0 * model["num_hidden_layers"] * model["num_attention_heads"] \
+        * model["head_dim"] * ctx
+
+
+def decode_flops(model: dict, ctx: int) -> float:
+    """One decoded token at context ``ctx``: 2 per matmul parameter, and
+    attention over the context."""
+    body, head = dense_params(model)
+    return 2.0 * (body + head) + attn_flops(model, ctx)
+
+
+def prefill_flops(model: dict, n: int) -> float:
+    """A prompt of ``n`` tokens: every token through the layers, causal
+    attention, and the head at the last position only."""
+    body, head = dense_params(model)
+    return 2.0 * body * n + 2.0 * head + attn_flops(model, n * (n + 1) / 2)
 
 
 def rms_norm(x, scale, eps):

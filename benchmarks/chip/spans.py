@@ -17,7 +17,10 @@ its name.  Operations that only wait on others (``while``,
 ``conditional``, ``call``, asynchronous ``*-start``/``*-done``) span whole
 loops and are left out of the scope sums.
 
-    python benchmarks/chip/spans.py <file.xplane.pb> [--segment 8]
+    python benchmarks/chip/spans.py <file.xplane.pb> --kernels <kernel> ... [--segment 8]
+
+``--kernels`` names the packed kernels whose device time each scope
+splits off: the keys of the family module's ``packed_calls``.
 """
 
 from __future__ import annotations
@@ -121,8 +124,10 @@ def _innermost(a: int, b: int, spans: list) -> dict:
     return out
 
 
-def summarize(trace) -> SpanSummary:
-    """``trace``: a path to an ``.xplane.pb`` or a ``ProfileData``."""
+def summarize(trace, kernels) -> SpanSummary:
+    """``trace``: a path to an ``.xplane.pb`` or a ``ProfileData``;
+    ``kernels``: the packed kernels' names, as ``xplane.summarize`` takes
+    them."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(trace) if isinstance(trace, str) else trace
@@ -161,7 +166,7 @@ def summarize(trace) -> SpanSummary:
             host[name][0] += 1
             host[name][1] += (e - s) / 1e9
     scopes = defaultdict(lambda: [0, 0.0])
-    kernels = defaultdict(float)
+    in_kernels = defaultdict(float)
     gaps = defaultdict(float)
     for mine in ops:
         if not mine:
@@ -178,8 +183,8 @@ def summarize(trace) -> SpanSummary:
             if scope:
                 scopes[scope][0] += 1
                 scopes[scope][1] += d / 1e9
-                if xplane.kernel_of(name):
-                    kernels[scope] += d / 1e9
+                if xplane.kernel_of(name, kernels):
+                    in_kernels[scope] += d / 1e9
         u = xplane._union(ivs)
         edges = [t0] + [x for iv in u for x in iv] + [t1]
         for a, b in zip(edges[0::2], edges[1::2]):
@@ -191,7 +196,7 @@ def summarize(trace) -> SpanSummary:
     out.idle_s = sum(out.gaps.values())
     out.host = dict(host)
     out.scopes = dict(scopes)
-    out.scope_kernels = dict(kernels)
+    out.scope_kernels = dict(in_kernels)
     out.segments = sum(1 for name, s in modules if "segment" in name and t0 <= s < t1)
     return out
 
@@ -212,7 +217,8 @@ def report(s: SpanSummary, steps_per_segment: int) -> dict:
 if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("trace", nargs="+")
+    ap.add_argument("--kernels", nargs="+", required=True, help="the packed kernels' names")
     ap.add_argument("--segment", type=int, default=8, help="decode steps per segment program")
     args = ap.parse_args()
     for path in args.trace:
-        print(json.dumps({"trace": path, **report(summarize(path), args.segment)}))
+        print(json.dumps({"trace": path, **report(summarize(path, args.kernels), args.segment)}))

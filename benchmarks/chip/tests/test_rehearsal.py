@@ -59,8 +59,8 @@ def test_traced_run_reports_per_layer_metrics_it_can_read():
     cell = smoke_cell(CELLS[0])
     r = run(cell, trace=True)
     assert r["correct"], r["checks"]
-    # on the CPU there is no device plane and no peak: only the counters answer
-    assert set(r["metrics"]) == {"slot_occupancy"}
+    # on the CPU there is no device plane and no peak: only the program counters answer
+    assert set(r["metrics"]) == {"slot_occupancy", "sync_host_ms", "prefill_pad_share"}
 
 
 def test_int4_control_is_not_correct():

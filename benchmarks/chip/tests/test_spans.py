@@ -11,15 +11,17 @@ operation stats added.  Times in ms, from the trace's start:
 """
 
 import importlib.util
+from functools import partial
 from pathlib import Path
 from types import SimpleNamespace
 
 import pytest
 from jax.profiler import ProfileData
 
+import harness
 import spans
 import xplane
-from test_xplane import DEV, HOST, TEXT, event, meta
+from test_xplane import DEV, HOST, KERNELS, TEXT, event, meta
 
 SEG = "jit(_segment_paged_fn)/while/body"
 HOST2 = {**HOST, 3: "serve.round", 4: "serve.dispatch", 5: "serve.fetch", 6: "serve.consume",
@@ -62,7 +64,7 @@ def traces():
 
 @pytest.fixture(scope="module")
 def summary(traces):
-    return spans.summarize(traces[1])
+    return spans.summarize(traces[1], KERNELS)
 
 
 def test_idle_gaps_are_named_after_the_innermost_span(summary):
@@ -157,12 +159,15 @@ def test_existing_metrics_read_the_same_with_the_spans_added(traces):
     model = {"num_hidden_layers": 2, "hidden_size": 256, "num_attention_heads": 4,
              "num_key_value_heads": 2, "head_dim": 64, "intermediate_size": 512,
              "vocab_size": 1000}
+    dense = harness.family({"reference": "dense_lm"})
 
     def ctx(trace):
         return SimpleNamespace(
-            trace=xplane.summarize(trace), stats={"slot_occupancy": 0.75}, serve={"segment": 8},
-            peaks={"bf16_flop_per_s": 197e12, "hbm_byte_per_s": 819e9},
+            trace=xplane.summarize(trace, KERNELS), stats={"slot_occupancy": 0.75},
+            serve={"segment": 8}, peaks={"bf16_flop_per_s": 197e12, "hbm_byte_per_s": 819e9},
             calls={"vusa_packed_matmul": [call], "vusa_fused_mlp_matmul": [call]},
+            decode_flops=partial(dense.decode_flops, model),
+            prefill_flops=partial(dense.prefill_flops, model),
             window_s=10 * MS, prefills=[(0.0, 100)], model=model,
             records=[{"prompt": 100, "n_in": 3}])
 

@@ -14,6 +14,7 @@ from jax.profiler import ProfileData
 import xplane
 
 MS = 10**9  # picoseconds
+KERNELS = ("vusa_packed_matmul", "vusa_fused_mlp_matmul")  # the dense family's
 
 
 def event(mid, start_ms, dur_ms):
@@ -44,7 +45,7 @@ planes {{ name: "/device:TPU:0"
 
 @pytest.fixture(scope="module")
 def summary():
-    return xplane.summarize(ProfileData.from_text_proto(TEXT))
+    return xplane.summarize(ProfileData.from_text_proto(TEXT), KERNELS)
 
 
 def test_busy_and_window(summary):
@@ -75,12 +76,20 @@ def test_op_names_drop_their_hlo_text():
     assert xplane.op_name("fusion.2") == "fusion.2"
 
 
-@pytest.mark.parametrize("name, kernel", [
-    ("%vusa_fused_mlp_matmul.6 = f32[8,1,2048] custom-call(...)", "vusa_fused_mlp_matmul"),
-    ("%vusa_packed_matmul.24 = f32[8,1,2048] custom-call(...)", "vusa_packed_matmul"),
-    ("%vmap_jit_vusa_packed_matmul__.6 = f32[8,1,92672] custom-call(...)",
+@pytest.mark.parametrize("name, kernels, kernel", [
+    ("%vusa_fused_mlp_matmul.6 = f32[8,1,2048] custom-call(...)", KERNELS,
+     "vusa_fused_mlp_matmul"),
+    ("%vusa_packed_matmul.24 = f32[8,1,2048] custom-call(...)", KERNELS, "vusa_packed_matmul"),
+    ("%vusa_packed_matmul_head.6 = f32[8,1,92672] custom-call(...)", KERNELS,
      "vusa_packed_matmul"),
-    ("%convert_convert_fusion.5 = bf16[8,1,2048] fusion(...)", None),
+    ("%vmap_jit_vusa_packed_matmul__.6 = f32[8,1,92672] custom-call(...)", KERNELS,
+     "vusa_packed_matmul"),
+    ("%convert_convert_fusion.5 = bf16[8,1,2048] fusion(...)", KERNELS, None),
+    ("%vusa_packed_matmul_experts.3 = f32[2,1408] custom-call(...)",
+     ("vusa_packed_matmul", "vusa_packed_matmul_experts"), "vusa_packed_matmul_experts"),
+    ("%vusa_packed_matmul_head.6 = f32[8,1,92672] custom-call(...)",
+     ("vusa_packed_matmul_experts", "vusa_packed_matmul"), "vusa_packed_matmul"),
+    ("%vusa_packed_matmul.24 = f32[8,1,2048] custom-call(...)", (), None),
 ])
-def test_only_the_pallas_calls_are_kernels(name, kernel):
-    assert xplane.kernel_of(name) == kernel
+def test_only_the_pallas_calls_are_kernels(name, kernels, kernel):
+    assert xplane.kernel_of(name, kernels) == kernel

@@ -1,9 +1,11 @@
-"""Operations and bytes: the peaks of each chip, the least time of each
-packed-kernel call, and the model FLOPs of the served tokens.
+"""Operations and bytes: the peaks of each chip, and the least time of a
+packed-kernel call from its FLOPs and bytes.
 
-Counts come from the configuration and from the non-zeros of the weights
-the benchmark made, never from the program: a later change to the kernels
-cannot change what their work is said to be.
+A family module (``references/<family>.py``) lists the calls of one
+decode step from the configuration and the non-zeros of the weights the
+benchmark made, through the byte and FLOP rules here, never from the
+program: a later change to the kernels cannot change what their work is
+said to be.
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ from pathlib import Path
 HERE = Path(__file__).resolve().parent
 VALUE_BYTES = {"bf16": 2.0, "int8": 1.0, "int4": 0.5}
 ACT_BYTES = 2  # bf16 activations into a kernel
-OUT_BYTES = 4  # both kernels write float32
+OUT_BYTES = 4  # the packed kernels write float32
 LANES = 128  # window width of a pack; one float32 scale per (window, row)
 
 
@@ -36,7 +38,11 @@ class Call:
         return max(self.flops / pk["bf16_flop_per_s"], self.bytes / pk["hbm_byte_per_s"])
 
 
-def _linear(rows: int, k: int, n: int, nnz: float, values: str) -> Call:
+def linear(rows: int, k: int, n: int, nnz: float, values: str) -> Call:
+    """One packed ``(k, n)`` matrix with ``nnz`` non-zeros applied to
+    ``rows`` rows: 2 FLOPs per row and non-zero; bytes for the values, a
+    position byte each, the scales (none for bf16 values), the rows in and
+    the float32 rows out.  The pack is read once, however many rows."""
     scales = 0 if values == "bf16" else math.ceil(n / LANES) * k * 4
     return Call(
         flops=2.0 * rows * nnz,
@@ -45,49 +51,11 @@ def _linear(rows: int, k: int, n: int, nnz: float, values: str) -> Call:
     )
 
 
-def packed_calls(model: dict, nnz: dict, rows: int, values: str) -> dict:
-    """The calls of one decode step, per kernel: ``vusa_packed_matmul`` runs
-    wq, wk, wv, wo of every layer and the head; ``vusa_fused_mlp_matmul``
-    runs each layer's whole MLP.  ``rows`` rows per call (one per slot);
-    the pack's bytes are counted once per call, however many rows it
-    serves."""
-    L, d, f = model["num_hidden_layers"], model["hidden_size"], model["intermediate_size"]
-    h, kv, hd = model["num_attention_heads"], model["num_key_value_heads"], model["head_dim"]
-    packed, fused = [], []
-    for layer in range(L):
-        for name, k, n in (("wq", d, h * hd), ("wk", d, kv * hd), ("wv", d, kv * hd),
-                           ("wo", h * hd, d)):
-            packed.append(_linear(rows, k, n, float(nnz[f"layers/attn/{name}"][layer]), values))
-        z = sum(float(nnz[f"layers/ffn/{n}"][layer]) for n in ("w_gate", "w_up", "w_down"))
-        scales = 0 if values == "bf16" else 3 * math.ceil(f / LANES) * d * 4
-        fused.append(Call(flops=2.0 * rows * z,
-                          bytes=z * (VALUE_BYTES[values] + 1) + scales
-                          + rows * d * ACT_BYTES + rows * d * OUT_BYTES))
-    packed.append(_linear(rows, d, model["vocab_size"], float(nnz["lm_head"]), values))
-    return {"vusa_packed_matmul": packed, "vusa_fused_mlp_matmul": fused}
-
-
-def dense_params(model: dict) -> tuple:
-    """(matmul parameters of the layers, of the head), dense-equivalent."""
-    L, d, f = model["num_hidden_layers"], model["hidden_size"], model["intermediate_size"]
-    h, kv, hd = model["num_attention_heads"], model["num_key_value_heads"], model["head_dim"]
-    body = L * (d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f)
-    return body, d * model["vocab_size"]
-
-
-def attn_flops(model: dict, ctx: float) -> float:
-    """QK^T and AV of one token against ``ctx`` positions, all layers."""
-    return 4.0 * model["num_hidden_layers"] * model["num_attention_heads"] \
-        * model["head_dim"] * ctx
-
-
-def decode_flops(model: dict, ctx: int) -> float:
-    body, head = dense_params(model)
-    return 2.0 * (body + head) + attn_flops(model, ctx)
-
-
-def prefill_flops(model: dict, n: int) -> float:
-    """A prompt of ``n`` tokens: every token through the layers, causal
-    attention, and the head at the last position only."""
-    body, head = dense_params(model)
-    return 2.0 * body * n + 2.0 * head + attn_flops(model, n * (n + 1) / 2)
+def fused_mlp(rows: int, d: int, f: int, nnz: float, values: str) -> Call:
+    """One fused gated MLP (``(d, f)`` gate and up, ``(f, d)`` down, ``nnz``
+    non-zeros in the three) on ``rows`` rows: the intermediate never leaves
+    the chip, so only ``d``-wide rows go in and out."""
+    scales = 0 if values == "bf16" else 3 * math.ceil(f / LANES) * d * 4
+    return Call(flops=2.0 * rows * nnz,
+                bytes=nnz * (VALUE_BYTES[values] + 1) + scales
+                + rows * d * ACT_BYTES + rows * d * OUT_BYTES)

@@ -1,10 +1,11 @@
 """Reduction of a JAX profiler trace (``.xplane.pb``) to the numbers the
 per-layer metrics read.
 
-``summarize(path)`` reads the file with
-``jax.profiler.ProfileData`` and keeps, for the device planes, every
-operation and every whole program ("XLA Modules") that ran, and for the host,
-the spans the benchmark annotates (``jax.profiler.TraceAnnotation``).  The
+``summarize(path, kernels)`` reads the file with ``jax.profiler.ProfileData``
+and keeps, for the device planes, every operation and every whole program
+("XLA Modules") that ran, with the time of each kernel named in ``kernels``
+(the keys of the family module's ``packed_calls``), and for the host, the
+spans the benchmark annotates (``jax.profiler.TraceAnnotation``).  The
 window is the benchmark's own host span ``WINDOW_SPAN`` when the trace holds
 it, else the extent of the device events.
 
@@ -12,7 +13,7 @@ Busy time is the union of the device's operation intervals inside the
 window, averaged over the device planes; idle gaps are the holes in that
 union, each named after the host span that overlaps it most.
 
-    python benchmarks/chip/xplane.py <file.xplane.pb>   # print a summary
+    python benchmarks/chip/xplane.py <file.xplane.pb> [kernel ...]   # print a summary
 """
 
 from __future__ import annotations
@@ -63,18 +64,17 @@ def _stats(ev) -> dict:
         return {}
 
 
-def kernel_of(name: str):
-    """The packed kernel an operation event is, or None.  A Pallas call is
-    an operation named after its kernel function (``vusa_fused_mlp_matmul.6``,
-    ``vusa_packed_matmul.24``, under the slot vmap
-    ``vmap_jit_vusa_packed_matmul__.6``); the small XLA operations around
-    it carry the kernel's name only in their metadata and are not it."""
+def kernel_of(name: str, kernels) -> str | None:
+    """The kernel of ``kernels`` an operation event is, or None.  A Pallas
+    call is an operation named after its kernel function with a suffix the
+    compiler adds (``<kernel>.6``, ``<kernel>_head.6`` for a call site
+    named apart, ``vmap_jit_<kernel>__.6`` under a vmap that keeps the
+    kernel's grid); the small XLA operations around it carry the kernel's
+    name only in their metadata and are not it.  The longest name that
+    the operation's name holds wins, so a kernel whose name extends
+    another's is told apart from it."""
     short = op_name(name)
-    if "vusa_fused_mlp_matmul" in short:
-        return "vusa_fused_mlp_matmul"
-    if "vusa_packed_matmul" in short:
-        return "vusa_packed_matmul"
-    return None
+    return next((k for k in sorted(kernels, key=len, reverse=True) if k in short), None)
 
 
 def op_name(name: str) -> str:
@@ -87,8 +87,9 @@ def _is_device(plane_name: str) -> bool:
     return plane_name.startswith("/device:") and "CPU" not in plane_name
 
 
-def summarize(trace) -> Summary:
-    """``trace``: a path to an ``.xplane.pb`` or a ``ProfileData``."""
+def summarize(trace, kernels) -> Summary:
+    """``trace``: a path to an ``.xplane.pb`` or a ``ProfileData``;
+    ``kernels``: the kernel names whose calls ``Summary.kernels`` sums."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(trace) if isinstance(trace, str) else trace
@@ -122,7 +123,7 @@ def summarize(trace) -> Summary:
     span = max(t1 - t0, 1)
     modules = defaultdict(lambda: [0, 0.0])
     ops = defaultdict(lambda: [0, 0.0])
-    kernels = defaultdict(lambda: [0, 0.0])
+    per_kernel = defaultdict(lambda: [0, 0.0])
     busy = 0.0
     gaps = defaultdict(float)
     n_dev = 0
@@ -137,10 +138,10 @@ def summarize(trace) -> Summary:
             ivs.append((max(s, t0), min(s + d, t1)))
             ops[op_name(name)][0] += 1
             ops[op_name(name)][1] += d / 1e9
-            k = kernel_of(name)
+            k = kernel_of(name, kernels)
             if k:
-                kernels[k][0] += 1
-                kernels[k][1] += d / 1e9
+                per_kernel[k][0] += 1
+                per_kernel[k][1] += d / 1e9
         for name, s, d, _ in p["modules"]:
             if t0 <= s < t1:
                 modules[name][0] += 1
@@ -165,7 +166,7 @@ def summarize(trace) -> Summary:
         devices=n_dev,
         modules=dict(modules),
         ops=dict(ops),
-        kernels=dict(kernels),
+        kernels=dict(per_kernel),
         gaps=sorted(((k, v / max(n_dev, 1)) for k, v in gaps.items()), key=lambda kv: -kv[1]),
     )
 
@@ -201,7 +202,7 @@ def describe_file(path: str) -> dict:
 
 
 if __name__ == "__main__":
-    s = summarize(sys.argv[1])
+    s = summarize(sys.argv[1], sys.argv[2:])
     print(json.dumps({"window_s": s.window_s, "busy_s": s.busy_s, "devices": s.devices,
                       "modules": s.modules, "kernels": s.kernels,
                       **breakdown(s)}, indent=1))
