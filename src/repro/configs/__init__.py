@@ -13,7 +13,7 @@ ARCH_IDS = [
     "internlm2_1_8b",
     "qwen3_8b",
     "olmoe_1b_7b",
-    "moonshot_v1_16b_a3b",
+    "moonlight_16b_a3b",
     "mamba2_2_7b",
     "whisper_tiny",
     "paligemma_3b",

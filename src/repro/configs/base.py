@@ -38,6 +38,28 @@ class ArchConfig:
     n_experts: int = 0
     top_k: int = 0
     moe_cf: float = 1.25  # capacity factor; >= n_experts/top_k == dropless
+    # routing: "softmax" = top-k of a softmax, capacity-bounded dispatch
+    # (``layers.moe``); "sigmoid" = DeepSeek-V3 ``noaux_tc``: experts chosen
+    # by top-k of sigmoid scores plus a learned correction bias, weighted by
+    # the scores alone, dropless, over the held experts (``layers.moe_held``)
+    score_func: str = "softmax"
+    norm_topk_prob: bool = True  # renormalise the chosen experts' weights
+    routed_scale: float = 1.0  # routed_scaling_factor on the routed sum
+    n_shared_experts: int = 0  # always-on experts, run as one MLP of n x d_ff
+    # expert parallelism: ``ep_size`` chips share each expert layer and this
+    # one holds the first n_experts / ep_size experts (rank 0)
+    ep_size: int = 1
+    # leading dense layers (first_k_dense_replace) and their SwiGLU width;
+    # in a moe model d_ff is the expert width
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # latent attention (MLA, DeepSeek-V2/V3): kv_lora_rank > 0 replaces every
+    # layer's attention.  Scores use qk_nope + qk_rope dims per head, values
+    # v_head_dim; the cache holds kv_lora_rank + qk_rope numbers a token.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -70,16 +92,63 @@ class ArchConfig:
     def padded_vocab(self) -> int:
         return pad_vocab(self.vocab)
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Per-head score width of latent attention (no-rope + rope parts)."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def held_moe(self) -> bool:
+        """Expert layers of held experts (``layers.moe_held``): sigmoid
+        routing over all experts, this chip computing its own."""
+        return self.family == "moe" and self.score_func == "sigmoid"
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this chip computes: the first n_experts / ep_size."""
+        return self.n_experts // self.ep_size
+
+    @property
+    def moe_dropless(self) -> bool:
+        """No token can ever be dropped: the sigmoid layer is dropless by
+        construction, the capacity layer when its factor covers every
+        assignment.  Co-batched rows then never interact."""
+        return self.score_func == "sigmoid" or self.moe_cf >= self.n_experts / self.top_k
+
+    def _attn_params(self) -> int:
+        d, nh = self.d_model, self.n_heads
+        if self.mla:
+            r, dr = self.kv_lora_rank, self.qk_rope_head_dim
+            return (d * nh * self.qk_head_dim + d * (r + dr) + r
+                    + r * nh * (self.qk_nope_head_dim + self.v_head_dim)
+                    + nh * self.v_head_dim * d)
+        hd = self.hd
+        return d * (nh * hd) + 2 * d * (self.kv_heads * hd) + (nh * hd) * d
+
+    def _moe_layer_params(self, experts: int) -> int:
+        """One expert layer with ``experts`` routed experts computed: those,
+        the shared experts and, for the sigmoid router, its weights and
+        correction bias."""
+        d = self.d_model
+        ffn = 3 * d * self.d_ff * (experts + self.n_shared_experts)
+        if self.score_func == "sigmoid":
+            ffn += (d + 1) * self.n_experts
+        return self._attn_params() + ffn
+
     def param_count(self) -> int:
         """Approximate parameter count (used for 6ND model-FLOPs)."""
         d, v, L = self.d_model, self.padded_vocab, self.n_layers
-        hd = self.hd
         emb = v * d * (1 if self.tie_embeddings else 2)
-        attn = d * (self.n_heads * hd) + 2 * d * (self.kv_heads * hd) + (self.n_heads * hd) * d
+        attn = self._attn_params()
         if self.family == "moe":
-            ffn = 3 * d * self.d_ff * self.n_experts
-        else:
-            ffn = 3 * d * self.d_ff
+            ld = self.n_dense_layers
+            dense = ld * (attn + 3 * d * self.dense_d_ff)
+            return emb + dense + (L - ld) * self._moe_layer_params(self.n_experts)
+        ffn = 3 * d * self.d_ff
         if self.family == "ssm":
             din = self.expand * d
             # in_proj(z,x,B,C,dt) + out_proj + conv
@@ -102,12 +171,10 @@ class ArchConfig:
         """Params touched per token (MoE: top_k of n_experts FFNs)."""
         if self.family != "moe":
             return self.param_count()
-        d, L = self.d_model, self.n_layers
-        hd = self.hd
+        d, L, ld = self.d_model, self.n_layers, self.n_dense_layers
         emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
-        attn = d * (self.n_heads * hd) + 2 * d * (self.kv_heads * hd) + (self.n_heads * hd) * d
-        ffn = 3 * d * self.d_ff * self.top_k
-        return emb + L * (attn + ffn)
+        dense = ld * (self._attn_params() + 3 * d * self.dense_d_ff)
+        return emb + dense + (L - ld) * self._moe_layer_params(self.top_k)
 
     def _pattern(self) -> Tuple[str, ...]:
         if not self.block_pattern:

@@ -39,6 +39,8 @@ __all__ = [
     "autotune_row_packed",
     "apply_fused_mlp",
     "apply_fused_mlp_ref",
+    "apply_fused_moe",
+    "apply_fused_moe_ref",
     "autotune_fused_mlp",
     "shard_linear_windows",
     "mesh_axis_size",
@@ -148,10 +150,11 @@ from ..core.packing import (  # noqa: E402
     pack_rows_t,
     quantize_rows,
 )
-from .ref import vusa_fused_mlp_ref, vusa_packed_ref  # noqa: E402
+from .ref import vusa_fused_mlp_ref, vusa_fused_moe_ref, vusa_packed_ref  # noqa: E402
 from .vusa_packed import (  # noqa: E402
     DEFAULT_SLOT_CHUNK,
     vusa_fused_mlp_matmul,
+    vusa_fused_moe_matmul,
     vusa_packed_matmul,
 )
 
@@ -179,7 +182,7 @@ class RowPackedLinear:
     @property
     def slots(self) -> int:
         """Logical slot count — positions are never nibble-packed."""
-        return self.positions.shape[2]
+        return self.positions.shape[-1]
 
     @property
     def byte_ratio(self) -> float:
@@ -595,6 +598,51 @@ def apply_fused_mlp_ref(
     _check_fused_packs(xf.shape[-1], gate, up, down_t)
     y = vusa_fused_mlp_ref(
         xf, dequantize_linear_values(gate), gate.positions,
+        dequantize_linear_values(up), up.positions,
+        dequantize_linear_values(down_t), down_t.positions, m=gate.m,
+    )
+    return y.reshape(*lead, down_t.k).astype(x.dtype)
+
+
+def apply_fused_moe(
+    x: jax.Array,
+    weights: jax.Array,
+    gate: RowPackedLinear,
+    up: RowPackedLinear,
+    down_t: RowPackedLinear,
+    *,
+    interpret: bool | None = None,
+    k_blk: int | None = None,
+    reconstruct: str = "onehot",
+    slot_chunk: int = DEFAULT_SLOT_CHUNK,
+) -> jax.Array:
+    """Held experts through the grouped kernel: ``gate``/``up``/``down_t``
+    stack one fused-MLP pack per expert on a leading axis (values (E, T, K,
+    S)); ``weights`` (..., E) weight each row's expert outputs, 0 where the
+    expert was not chosen.  x: (..., K) -> (..., D)."""
+    interp = interpret_mode(interpret)
+    lead = x.shape[:-1]
+    xf = x.reshape(-1, x.shape[-1])
+    wf = weights.reshape(-1, weights.shape[-1])
+    if k_blk is None:
+        k_blk = choose_k_blk(xf.shape[-1], max(gate.slots, up.slots, down_t.slots), gate.m)
+    y = vusa_fused_moe_matmul(
+        xf, wf, gate.values, gate.positions, up.values, up.positions,
+        down_t.values, down_t.positions, gate.scales, up.scales, down_t.scales,
+        m=gate.m, k_blk=max(int(k_blk), 1), interpret=interp, reconstruct=reconstruct,
+        slot_chunk=slot_chunk, value_dtype=gate.value_dtype,
+    )
+    return y.reshape(*lead, down_t.k).astype(x.dtype)
+
+
+def apply_fused_moe_ref(
+    x: jax.Array, weights: jax.Array, gate: RowPackedLinear, up: RowPackedLinear,
+    down_t: RowPackedLinear,
+) -> jax.Array:
+    lead = x.shape[:-1]
+    y = vusa_fused_moe_ref(
+        x.reshape(-1, x.shape[-1]), weights.reshape(-1, weights.shape[-1]),
+        dequantize_linear_values(gate), gate.positions,
         dequantize_linear_values(up), up.positions,
         dequantize_linear_values(down_t), down_t.positions, m=gate.m,
     )

@@ -10,7 +10,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["dense_matmul_ref", "vusa_spmm_ref", "vusa_packed_ref", "vusa_fused_mlp_ref"]
+__all__ = [
+    "dense_matmul_ref", "vusa_spmm_ref", "vusa_packed_ref", "vusa_fused_mlp_ref",
+    "vusa_fused_moe_ref",
+]
 
 
 def dense_matmul_ref(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
@@ -75,3 +78,26 @@ def vusa_fused_mlp_ref(
     xf = x.astype(jnp.float32)
     h = jax.nn.silu(xf @ wg) * (xf @ wu)  # (B, T*m)
     return h @ wdt.T
+
+
+def vusa_fused_moe_ref(
+    x: jnp.ndarray,
+    weights: jnp.ndarray,
+    gate_values: jnp.ndarray,
+    gate_positions: jnp.ndarray,
+    up_values: jnp.ndarray,
+    up_positions: jnp.ndarray,
+    down_values: jnp.ndarray,
+    down_positions: jnp.ndarray,
+    m: int = 128,
+) -> jnp.ndarray:
+    """Grouped-expert oracle, pure jnp: ``sum_e weights[:, e] *
+    vusa_fused_mlp_ref(x, expert e's packs)`` over the leading expert axis of
+    the ``vusa_fused_moe_matmul`` operands.  Returns (B, D) fp32."""
+    y = 0.0
+    for e in range(gate_values.shape[0]):
+        y = y + weights[:, e : e + 1].astype(jnp.float32) * vusa_fused_mlp_ref(
+            x, gate_values[e], gate_positions[e], up_values[e], up_positions[e],
+            down_values[e], down_positions[e], m,
+        )
+    return y

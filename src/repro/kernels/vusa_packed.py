@@ -60,6 +60,11 @@ straight into the ``(B, d_model)`` output — the ``(B, ff)`` intermediate
 never touches HBM and the per-layer dispatch count drops from three to one.
 Its chunk loops over ``k_blk`` rows are ``fori_loop``s, so only one chunk's
 scratch is live at a time.
+
+``vusa_fused_moe_matmul`` runs an expert layer's held experts, each a fused
+MLP in that layout, in one call whose grid walks (expert, ff window); each
+row's hidden state is scaled by its weight for the expert, and all experts
+accumulate into one output.
 """
 
 from __future__ import annotations
@@ -75,6 +80,7 @@ from jax.experimental import pallas as pl
 __all__ = [
     "vusa_packed_matmul",
     "vusa_fused_mlp_matmul",
+    "vusa_fused_moe_matmul",
     "fold_slot_vmap",
     "fold_tally",
     "calls_repeat",
@@ -272,23 +278,29 @@ def _row_chunks(n: int, k_blk: int, body, carry):
 
 
 def _fused_mlp_kernel(
-    x_ref, gv_ref, gp_ref, uv_ref, up_ref, dv_ref, dp_ref, *rest,
-    m: int, k_blk: int, reconstruct: str, slot_chunk: int, value_dtype: str,
-    exact_rows: bool,
+    x_ref, *refs, m: int, k_blk: int, reconstruct: str, slot_chunk: int,
+    value_dtype: str, exact_rows: bool, gated: bool = False, grid_rank: int = 1,
 ):
     """One ff window of the fused MLP: gate/up reconstruct + matmul,
     ``silu(gate) * up`` in VMEM, then the window's ``w_down`` rows
     (transposed pack: ``dv``/``dp`` are (1, D, Sd) over the same window)
-    accumulate into the full (B, D) output block.  ``rest`` is ``(gs, us,
-    ds, y_ref)`` — (1, 1, rows) scale blocks — for quantized packs and
-    ``(y_ref,)`` otherwise."""
+    accumulate into the full (B, D) output block.  ``refs`` is ``([w_ref,]
+    gv, gp, uv, up, dv, dp, [gs, us, ds,] y_ref)``: the scale blocks (1, 1,
+    rows) for quantized packs; ``w_ref`` (1, B, 1), with ``gated``, is each
+    row's gate weight for this expert, scaling its hidden state.  The output
+    accumulates over the whole ``grid_rank``-axis grid."""
+    w_ref, refs = (refs[0], refs[1:]) if gated else (None, refs)
+    gv_ref, gp_ref, uv_ref, up_ref, dv_ref, dp_ref, *rest = refs
     *scales, y_ref = rest
     gs_ref, us_ref, ds_ref = scales or (None, None, None)
     tile = functools.partial(
         _tile, m=m, reconstruct=reconstruct, slot_chunk=slot_chunk, value_dtype=value_dtype
     )
+    first = pl.program_id(0) == 0
+    for axis in range(1, grid_rank):
+        first = jnp.logical_and(first, pl.program_id(axis) == 0)
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(first)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
@@ -305,6 +317,8 @@ def _fused_mlp_kernel(
     zeros = jnp.zeros((b, m), jnp.float32)
     gate, up = _row_chunks(x_ref.shape[1], k_blk, gate_up, (zeros, zeros))
     h = jax.nn.silu(gate) * up  # (B, m) — the (B, ff) intermediate, one window of it
+    if w_ref is not None:
+        h = h * w_ref[0]  # (B, 1): the expert's weight per row, 0 where not chosen
 
     def down(r0, width, carry):
         rows = pl.ds(r0, width)
@@ -410,6 +424,105 @@ def _fused_mlp_matmul(
 
 
 # --------------------------------------------------------------------------
+# Grouped packed experts (the held experts of an expert layer)
+# --------------------------------------------------------------------------
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype"),
+)
+def _fused_moe_matmul(
+    x: jax.Array,  # (B, K)
+    weights: jax.Array,  # (B, E) each row's gate weight per expert, 0 = not chosen
+    gate_values: jax.Array,  # (E, T, K, Sg)   per expert, as _fused_mlp_matmul's
+    gate_positions: jax.Array,  # (E, T, K, Sg) int8
+    up_values: jax.Array,  # (E, T, K, Su)
+    up_positions: jax.Array,  # (E, T, K, Su) int8
+    down_values: jax.Array,  # (E, T, D, Sd)   w_down.T packs (ff windowed)
+    down_positions: jax.Array,  # (E, T, D, Sd) int8
+    gate_scales: jax.Array | None = None,  # (E, T, K) fp32, quantized packs only
+    up_scales: jax.Array | None = None,  # (E, T, K) fp32
+    down_scales: jax.Array | None = None,  # (E, T, D) fp32
+    *,
+    m: int = 128,
+    k_blk: int = 256,
+    interpret: bool = False,
+    reconstruct: str = "onehot",
+    slot_chunk: int = DEFAULT_SLOT_CHUNK,
+    value_dtype: str = "dense",
+) -> jax.Array:
+    """``sum_e weights[:, e] * mlp_e(x)`` over E stacked SwiGLU experts in
+    one ``pallas_call``.  Each expert's packs are laid out as
+    ``_fused_mlp_matmul``'s (gate and up over the ff windows, down
+    transposed), stacked on a leading expert axis and padded to a common
+    slot count (idle slots are exact no-ops).  The grid walks (expert, ff
+    window); each step is a fused-MLP window whose hidden state is scaled
+    by the rows' weights for that expert before its down projection, and
+    every step accumulates into one ``(B, D)`` fp32 output.  Every row runs
+    through every expert: a row an expert was not chosen for has weight 0.
+    A ``jax.vmap`` over ``x`` and ``weights`` folds into the call's rows
+    (``fold_slot_vmap``)."""
+    b, k = x.shape
+    e, t, kk, _ = gate_values.shape
+    d_out = down_values.shape[2]
+    assert kk == k and up_values.shape[:3] == (e, t, k), (gate_values.shape, up_values.shape, k)
+    assert down_values.shape[:2] == (e, t) and weights.shape == (b, e), (
+        down_values.shape, weights.shape,
+    )
+    assert m <= 128, m
+    assert reconstruct in RECONSTRUCT_MODES, reconstruct
+    k_blk = max(1, min(k_blk, max(k, d_out)))
+    nib = 2 if value_dtype == "int4" else 1
+    vg, vu, vd = gate_values.shape[3], up_values.shape[3], down_values.shape[3]
+    sg, su, sd = gate_positions.shape[3], up_positions.shape[3], down_positions.shape[3]
+    assert (vg * nib, vu * nib, vd * nib) == (sg, su, sd), (value_dtype, (vg, vu, vd), (sg, su, sd))
+
+    def window(rows, slots):  # expert e's ff window i: one (rows, slots) pack block
+        return pl.BlockSpec((1, rows, slots), lambda j, i: (j * t + i, 0, 0))
+
+    def flat(a):  # (E, T, ...) -> (E*T, ...): windows in grid order
+        return a.reshape(e * t, *a.shape[2:])
+
+    in_specs = [
+        pl.BlockSpec((b, k), lambda j, i: (0, 0)),
+        pl.BlockSpec((1, b, 1), lambda j, i: (j, 0, 0)),
+        window(k, vg), window(k, sg), window(k, vu), window(k, su),
+        window(d_out, vd), window(d_out, sd),
+    ]
+    operands = [
+        x, weights.T.astype(jnp.float32)[:, :, None],
+        flat(gate_values), flat(gate_positions), flat(up_values), flat(up_positions),
+        flat(down_values), flat(down_positions),
+    ]
+    if value_dtype == "dense":
+        assert gate_scales is None and up_scales is None and down_scales is None
+    else:
+        assert gate_scales.shape == (e, t, k) and up_scales.shape == (e, t, k), (
+            gate_scales.shape, up_scales.shape,
+        )
+        assert down_scales.shape == (e, t, d_out), (down_scales.shape, e, t, d_out)
+        in_specs += [window(1, k), window(1, k), window(1, d_out)]
+        operands += [
+            gate_scales.reshape(e * t, 1, k), up_scales.reshape(e * t, 1, k),
+            down_scales.reshape(e * t, 1, d_out),
+        ]
+    return pl.pallas_call(
+        functools.partial(
+            _fused_mlp_kernel, m=m, k_blk=k_blk, reconstruct=reconstruct,
+            slot_chunk=slot_chunk, value_dtype=value_dtype, exact_rows=interpret,
+            gated=True, grid_rank=2,
+        ),
+        grid=(e, t),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((b, d_out), lambda j, i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, d_out), jnp.float32),
+        interpret=interpret,
+        name="vusa_fused_moe_matmul",
+    )(*operands)
+
+
+# --------------------------------------------------------------------------
 # Slot vmap -> kernel rows
 # --------------------------------------------------------------------------
 #
@@ -469,40 +582,46 @@ def calls_repeat(n: int):
         _REPEAT.reset(token)
 
 
-def fold_slot_vmap(kernel):
-    """Give ``kernel(x, *pack, **static)`` a vmap rule of its own: when only
-    ``x`` (B, K) carries the vmapped axis, the (n, B, K) rows run as one
-    (n*B, K) call and the result is reshaped back; when any pack operand is
-    batched, it falls back to ``jax.vmap`` of the kernel (the grid axis).
-    Unbatched calls are the kernel's own program."""
+def fold_slot_vmap(kernel, rows: int = 1):
+    """Give ``kernel(*row_args, *pack, **static)`` a vmap rule of its own:
+    its first ``rows`` operands are per-row ((B, ...): ``x``, and the
+    grouped kernel's weights).  When only those carry the vmapped axis, the
+    (n, B, ...) rows run as one (n*B, ...) call (an unbatched row operand
+    is broadcast first) and the result is reshaped back; when any pack
+    operand is batched, it falls back to ``jax.vmap`` of the kernel (the
+    grid axis).  Unbatched calls are the kernel's own program."""
 
     @functools.wraps(kernel)
-    def call(x, *pack, **static):
+    def call(*args, **static):
         run = functools.partial(kernel, **static)
         repeat = _REPEAT.get()
 
         @jax.custom_batching.custom_vmap
-        def folded(x, *pack):
-            return run(x, *pack)
+        def folded(*args):
+            return run(*args)
 
         @folded.def_vmap
-        def rule(axis_size, in_batched, x, *pack):
-            x_batched, *pack_batched = in_batched
-            fold = x_batched and not any(jax.tree.leaves(pack_batched))
+        def rule(axis_size, in_batched, *args):
+            row_batched, pack_batched = in_batched[:rows], in_batched[rows:]
+            fold = any(row_batched) and not any(jax.tree.leaves(pack_batched))
             tally = _TALLY.get()
             if tally is not None:
                 tally.record(rule, repeat, fold)
             if fold:
-                n, b, k = x.shape
-                y = folded(x.reshape(n * b, k), *pack)  # an outer vmap folds again
+                lead = [a if bt else jnp.broadcast_to(a, (axis_size, *a.shape))
+                        for a, bt in zip(args[:rows], row_batched)]
+                n, b = lead[0].shape[:2]
+                # an outer vmap folds again
+                y = folded(*[a.reshape(n * b, *a.shape[2:]) for a in lead], *args[rows:])
                 return y.reshape(n, b, *y.shape[1:]), True
             axes = [0 if bt else None for bt in in_batched]
-            return jax.vmap(run, in_axes=axes)(x, *pack), True
+            return jax.vmap(run, in_axes=axes)(*args), True
 
-        return folded(x, *pack)
+        return folded(*args)
 
     return call
 
 
 vusa_packed_matmul = fold_slot_vmap(_packed_matmul)
 vusa_fused_mlp_matmul = fold_slot_vmap(_fused_mlp_matmul)
+vusa_fused_moe_matmul = fold_slot_vmap(_fused_moe_matmul, rows=2)

@@ -17,6 +17,7 @@ Layer parameters carry a leading "layers" axis and run under ``lax.scan``
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 
 import jax
@@ -26,15 +27,24 @@ from . import ssm as ssm_mod
 from .common import ParamSpec, rms_norm, shard
 from .layers import (
     MaskSpec,
+    _project_qkv,
     attention,
     attention_decode,
     attention_specs,
     cross_attention,
     encode_cross_kv,
+    held_experts,
+    mla_attention,
+    mla_chunk,
+    mla_decode,
+    mla_latent,
+    mla_specs,
     mlp,
     mlp_specs,
     moe,
+    moe_held,
     moe_specs,
+    route,
 )
 
 # --------------------------------------------------------------------------
@@ -78,24 +88,45 @@ def _xent(logits: jax.Array, labels: jax.Array, vocab: int) -> jax.Array:
 # --------------------------------------------------------------------------
 
 
-def _lm_layer_specs(cfg) -> dict:
+def _lm_stacks(cfg):
+    """The layer stacks of a decoder LM, in order: ``(params key, layers,
+    cache-leaf prefix, dense)``.  A model with leading dense layers
+    (``n_dense_layers``) runs them as their own stack, with a SwiGLU of
+    ``dense_d_ff``, before the main stack; the main stack alone is the case
+    with no dense prefix."""
+    stacks = []
+    if cfg.n_dense_layers:
+        stacks.append(("dense_layers", cfg.n_dense_layers, "dense_", True))
+    stacks.append(("layers", cfg.n_layers - cfg.n_dense_layers, "", False))
+    return stacks
+
+
+def lm_attn_leaves(cfg) -> tuple:
+    """A layer's cache leaves: the latent row under latent attention, else
+    per-head keys and values."""
+    return ("latent",) if cfg.mla else ("k", "v")
+
+
+def _lm_layer_specs(cfg, dense: bool = False) -> dict:
     d = cfg.d_model
     specs = {
         "norm1": ParamSpec((d,), ("embed",), init="zeros"),
-        "attn": attention_specs(cfg),
+        "attn": mla_specs(cfg) if cfg.mla else attention_specs(cfg),
         "norm2": ParamSpec((d,), ("embed",), init="zeros"),
     }
-    specs["ffn"] = moe_specs(cfg) if cfg.family == "moe" else mlp_specs(cfg)
+    if dense:
+        specs["ffn"] = mlp_specs(cfg, cfg.dense_d_ff)
+    else:
+        specs["ffn"] = moe_specs(cfg) if cfg.family == "moe" else mlp_specs(cfg)
     return specs
 
 
 def lm_specs(cfg) -> dict:
     d, v = cfg.d_model, cfg.padded_vocab
-    specs = {
-        "embed": ParamSpec((v, d), ("vocab", "embed"), scale=1.0),
-        "layers": _stack_specs(_lm_layer_specs(cfg), cfg.n_layers),
-        "final_norm": ParamSpec((d,), ("embed",), init="zeros"),
-    }
+    specs = {"embed": ParamSpec((v, d), ("vocab", "embed"), scale=1.0)}
+    for key, n, _, dense in _lm_stacks(cfg):
+        specs[key] = _stack_specs(_lm_layer_specs(cfg, dense), n)
+    specs["final_norm"] = ParamSpec((d,), ("embed",), init="zeros")
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((d, v), ("embed", "vocab"))
     if cfg.family == "vlm":
@@ -103,29 +134,38 @@ def lm_specs(cfg) -> dict:
     return specs
 
 
-def _lm_layer(lp, x, cfg, mask: MaskSpec, positions, kv_valid=None):
+def _lm_ffn(p, h, cfg, dense: bool):
+    """The layer's feed-forward: (y, aux loss)."""
+    if dense or cfg.family != "moe":
+        return mlp(p, h), 0.0
+    if cfg.held_moe:
+        return moe_held(p, h, cfg)
+    return moe(p, h, cfg)
+
+
+def _lm_layer(lp, x, cfg, mask: MaskSpec, positions, kv_valid=None, dense=False):
     h = rms_norm(x, lp["norm1"])
-    x = x + attention(lp["attn"], h, cfg, mask, positions, kv_valid=kv_valid)
+    attend = mla_attention if cfg.mla else attention
+    x = x + attend(lp["attn"], h, cfg, mask, positions, kv_valid=kv_valid)
     x = shard(x, "batch", None, "embed")
     h = rms_norm(x, lp["norm2"])
-    if cfg.family == "moe":
-        y, aux = moe(lp["ffn"], h, cfg)
-    else:
-        y, aux = mlp(lp["ffn"], h), 0.0
+    y, aux = _lm_ffn(lp["ffn"], h, cfg, dense)
     return x + y, aux
 
 
 def _lm_backbone(params, x, cfg, mask: MaskSpec, positions):
-    layer = partial(_lm_layer, cfg=cfg, mask=mask, positions=positions)
-    if cfg.remat:
-        layer = jax.checkpoint(layer)
+    aux = 0.0
+    for key, _, _, dense in _lm_stacks(cfg):
+        layer = partial(_lm_layer, cfg=cfg, mask=mask, positions=positions, dense=dense)
+        if cfg.remat:
+            layer = jax.checkpoint(layer)
 
-    def body(carry, lp):
-        x, aux = carry
-        x, a = layer(lp, x)
-        return (x, aux + a), None
+        def body(carry, lp, layer=layer):
+            x, aux = carry
+            x, a = layer(lp, x)
+            return (x, aux + a), None
 
-    (x, aux), _ = jax.lax.scan(body, (x, 0.0), params["layers"])
+        (x, aux), _ = jax.lax.scan(body, (x, aux), params[key])
     return rms_norm(x, params["final_norm"]), aux
 
 
@@ -169,13 +209,22 @@ def lm_loss(params, batch, cfg):
 
 
 def lm_cache_specs(cfg, batch: int, max_len: int):
-    kvh, hd = cfg.kv_heads, cfg.hd
-    kv = jax.ShapeDtypeStruct((cfg.n_layers, batch, max_len, kvh, hd), _act_dtype(cfg))
-    return {
-        "k": kv,
-        "v": kv,
-        "pos": jax.ShapeDtypeStruct((), jnp.int32),
+    """One leaf per layer stack and attention leaf, stacked over the stack's
+    layers: ``k``/``v`` (L, B, T, kvh, hd), or under latent attention
+    ``latent`` (L, B, T, kv_lora_rank + qk_rope) — a token's whole cached
+    state; the leading dense stack's leaves carry the prefix ``dense_``."""
+    adt = _act_dtype(cfg)
+    if cfg.mla:
+        row = (cfg.kv_lora_rank + cfg.qk_rope_head_dim,)
+    else:
+        row = (cfg.kv_heads, cfg.hd)
+    out = {
+        pre + name: jax.ShapeDtypeStruct((n, batch, max_len) + row, adt)
+        for _, n, pre, _ in _lm_stacks(cfg)
+        for name in lm_attn_leaves(cfg)
     }
+    out["pos"] = jax.ShapeDtypeStruct((), jnp.int32)
+    return out
 
 
 def lm_init_cache(cfg, batch: int, max_len: int):
@@ -184,19 +233,70 @@ def lm_init_cache(cfg, batch: int, max_len: int):
     )
 
 
-def _lm_decode_layer(lp, x, cache_l, cfg, pos):
-    h = rms_norm(x, lp["norm1"])
-    y, new_cache = attention_decode(lp["attn"], h, cfg, {**cache_l, "pos": pos})
-    x = x + y
+def _lm_decode_ffn(lp, x, cfg, dense: bool):
+    """Feed-forward of one decode step: ``(x + y, expert rows or None)``.
+    The held-expert layer's routing runs under ``decode.router``, its
+    experts and their combine under ``decode.experts``, the shared experts
+    under ``decode.mlp``."""
+    if dense or not cfg.held_moe:
+        with jax.named_scope("decode.mlp"):
+            h = rms_norm(x, lp["norm2"])
+            y, _ = _lm_ffn(lp["ffn"], h, cfg, dense)
+        return x + y, None
+    p = lp["ffn"]
     with jax.named_scope("decode.mlp"):
         h = rms_norm(x, lp["norm2"])
-        if cfg.family == "moe":
-            y, _ = moe(lp["ffn"], h, cfg)
-        else:
-            y = mlp(lp["ffn"], h)
-    if "k_new" in new_cache:  # paged: pending row writes, not a full cache
-        return x + y, {"k_new": new_cache["k_new"], "v_new": new_cache["v_new"]}
-    return x + y, {"k": new_cache["k"], "v": new_cache["v"]}
+        b, s, d = h.shape
+        hf = h.reshape(b * s, d)
+    with jax.named_scope("decode.router"):
+        gates, rows = route(p, hf, cfg)
+    with jax.named_scope("decode.experts"):
+        x = x + held_experts(p, hf, gates).astype(x.dtype).reshape(b, s, d)
+    if "shared" in p:
+        with jax.named_scope("decode.mlp"):
+            x = x + mlp(p["shared"], h)
+    return x, rows
+
+
+def _lm_decode_layer(lp, x, cache_l, cfg, pos, dense=False):
+    h = rms_norm(x, lp["norm1"])
+    attend = mla_decode if cfg.mla else attention_decode
+    y, new_cache = attend(lp["attn"], h, cfg, {**cache_l, "pos": pos})
+    x, rows = _lm_decode_ffn(lp, x + y, cfg, dense)
+    out = lm_step_rows(new_cache, cfg)
+    if rows is not None:
+        out["expert_rows"] = rows
+    return x, out
+
+
+def lm_step_rows(new_cache, cfg) -> dict:
+    """A layer's cache output of a decode step, by leaf: the pending rows
+    (``<leaf>_new``) of a paged cache, else the updated leaves."""
+    names = lm_attn_leaves(cfg)
+    if names[0] + "_new" in new_cache:  # paged: pending row writes, not a full cache
+        return {n + "_new": new_cache[n + "_new"] for n in names}
+    return {n: new_cache[n] for n in names}
+
+
+def lm_scan_stacks(x, cache, cfg, body, stack_inputs, repeat=None):
+    """Run ``body(x, (params, cache leaves, *extra)) -> (x, outputs)`` over
+    each layer stack in turn (``stack_inputs(key, dense)`` gives the stack's
+    params and extra scanned inputs) and gather the outputs under the
+    cache's leaf names.  Per-layer ``expert_rows`` are summed into one
+    count.  ``repeat(n)`` is entered around each stack's scan."""
+    out, rows = {}, None
+    for key, n, pre, dense in _lm_stacks(cfg):
+        params_s, *extra = stack_inputs(key, dense)
+        leaves = {name: cache[pre + name] for name in lm_attn_leaves(cfg)}
+        with repeat(n) if repeat is not None else contextlib.nullcontext():
+            x, new = jax.lax.scan(partial(body, dense=dense), x, (params_s, leaves, *extra))
+        r = new.pop("expert_rows", None)
+        if r is not None:
+            rows = r.sum() if rows is None else rows + r.sum()
+        out.update({pre + k: v for k, v in new.items()})
+    if rows is not None:
+        out["expert_rows"] = rows
+    return x, out
 
 
 def lm_decode_step(params, token, cache, cfg):
@@ -220,7 +320,11 @@ def lm_decode_step(params, token, cache, cfg):
     The layer scan then slices the arena per layer exactly as it slices the
     dense cache, and the returned tree carries the pending KV rows
     (``k_new``/``v_new``, stacked (L, 1, 1, ...)) for the caller to scatter
-    into the shared arena — the step itself never writes arena state."""
+    into the shared arena — the step itself never writes arena state.  The
+    leaves are those of :func:`lm_cache_specs` (``latent`` under latent
+    attention, ``dense_``-prefixed for a leading dense stack).  A model with
+    held experts adds ``expert_rows``: the (row, held expert) pairs its
+    routing chose in this step."""
     if token.shape[1] > 1:
         assert "table" not in cache, (
             "multi-token decode needs a contiguous cache; gather the paged "
@@ -235,14 +339,13 @@ def lm_decode_step(params, token, cache, cfg):
     pos = cache["pos"]
     table = cache.get("table")
 
-    def body(x, layer_in):
+    def body(x, layer_in, dense):
         lp, cache_l = layer_in
         if table is not None:
             cache_l = {**cache_l, "table": table}
-        x, new_c = _lm_decode_layer(lp, x, cache_l, cfg, pos)
-        return x, new_c
+        return _lm_decode_layer(lp, x, cache_l, cfg, pos, dense)
 
-    x, new_kv = jax.lax.scan(body, x, (params["layers"], {"k": cache["k"], "v": cache["v"]}))
+    x, new_kv = lm_scan_stacks(x, cache, cfg, body, lambda key, dense: (params[key],))
     with jax.named_scope("decode.head"):
         x = rms_norm(x, params["final_norm"])
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -250,6 +353,14 @@ def lm_decode_step(params, token, cache, cfg):
     if table is not None:
         return logits, {**new_kv, "table": table, "pos": pos + 1}
     return logits, {**new_kv, "pos": pos + 1}
+
+
+def _lm_cache_rows(p, h, cfg, positions) -> dict:
+    """A layer's cache rows for the prompt ``h``, by leaf."""
+    if cfg.mla:
+        return {"latent": mla_latent(p, h, cfg, positions)}
+    _, k, v = _project_qkv(p, h, cfg, positions)
+    return {"k": k, "v": v}
 
 
 def lm_prefill(params, batch, cfg, max_len: int, lengths=None):
@@ -272,9 +383,9 @@ def lm_prefill(params, batch, cfg, max_len: int, lengths=None):
 
     Caveat (moe): capacity-bounded dispatch couples rows — padding and
     co-batched tokens consume shared expert capacity — so bit-exactness
-    additionally requires a dropless capacity factor
-    (``moe_cf >= n_experts / top_k``); the serve engine only enables
-    batched admission for moe under that condition."""
+    additionally requires a dropless layer (``ArchConfig.moe_dropless``);
+    the serve engine only enables batched admission for moe under that
+    condition."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     cache = lm_init_cache(cfg, b, max_len)
@@ -284,23 +395,22 @@ def lm_prefill(params, batch, cfg, max_len: int, lengths=None):
     if lengths is not None:
         kv_valid = jnp.arange(x.shape[1])[None, :] < (lengths[:, None] + patch_off)
 
-    from .layers import _project_qkv  # noqa: PLC0415
+    rows = {}
+    for key, _, pre, dense in _lm_stacks(cfg):
 
-    def body(carry, lp):
-        x, ks, vs = carry
-        h = rms_norm(x, lp["norm1"])
-        _, k, v = _project_qkv(lp["attn"], h, cfg, positions)
-        x, _ = _lm_layer(lp, x, cfg, mask, positions, kv_valid)
-        return (x, ks, vs), (k, v)
+        def body(x, lp, dense=dense):
+            h = rms_norm(x, lp["norm1"])
+            out = _lm_cache_rows(lp["attn"], h, cfg, positions)
+            x, _ = _lm_layer(lp, x, cfg, mask, positions, kv_valid, dense)
+            return x, out
 
-    (xf, _, _), (ks, vs) = jax.lax.scan(body, (x, 0, 0), params["layers"])
-    xf = rms_norm(xf, params["final_norm"])
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], ks.astype(cache["k"].dtype), (0, 0, 0, 0, 0)
-    )
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], vs.astype(cache["v"].dtype), (0, 0, 0, 0, 0)
-    )
+        x, out = jax.lax.scan(body, x, params[key])
+        rows.update({pre + name: r for name, r in out.items()})
+    xf = rms_norm(x, params["final_norm"])
+    for name, r in rows.items():
+        cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], r.astype(cache[name].dtype), (0,) * r.ndim
+        )
     cache["pos"] = jnp.int32(x.shape[1])
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     if lengths is None:
@@ -320,12 +430,13 @@ def lm_prefill_chunk(params, tokens, cfg, arena, table_row, start, true_len,
     absolute position ``start`` (traced — one compiled program per static C);
     ``true_len`` counts real tokens (the final chunk is right-padded to C).
     Each layer attends the chunk causally over everything resident in
-    ``table_row``'s blocks plus itself, then the chunk's KV rows scatter into
-    ``arena`` at block-table addresses.  Rows below ``write_from`` are
-    *not* written — prefix-shared pages are already resident and must stay
-    read-only (setting ``write_from = start + true_len`` turns the call into
-    a pure re-peek, e.g. recovering the first-token logits after a
-    fully-matched prefix hit without touching shared blocks).
+    ``table_row``'s blocks plus itself, then the chunk's rows scatter into
+    every leaf of ``arena`` at block-table addresses.  Rows below
+    ``write_from`` are *not* written — prefix-shared pages are already
+    resident and must stay read-only (setting ``write_from = start +
+    true_len`` turns the call into a pure re-peek, e.g. recovering the
+    first-token logits after a fully-matched prefix hit without touching
+    shared blocks).
 
     Returns ``(logits (1, V), arena')`` — logits at the chunk's last real
     token, meaningful on the final chunk only."""
@@ -333,30 +444,27 @@ def lm_prefill_chunk(params, tokens, cfg, arena, table_row, start, true_len,
 
     x = _embed_tokens(params, tokens, cfg)
     c = x.shape[1]
+    attend = mla_chunk if cfg.mla else attention_chunk
 
-    def body(x, layer_in):
-        lp, ak, av = layer_in
+    def body(x, layer_in, dense):
+        lp, arena_l = layer_in
         h = rms_norm(x, lp["norm1"])
-        y, k_c, v_c = attention_chunk(
-            lp["attn"], h, cfg, ak, av, table_row, start, true_len
-        )
+        y, rows = attend(lp["attn"], h, cfg, arena_l, table_row, start, true_len)
         x = x + y
         h = rms_norm(x, lp["norm2"])
-        if cfg.family == "moe":
-            y, _ = moe(lp["ffn"], h, cfg)
-        else:
-            y = mlp(lp["ffn"], h)
-        return x + y, (k_c, v_c)
+        y, _ = _lm_ffn(lp["ffn"], h, cfg, dense)
+        return x + y, rows
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], arena["k"], arena["v"]))
-    page, n_blocks = arena["k"].shape[2], arena["k"].shape[1]
-    rows = start + jnp.arange(c)  # absolute positions of chunk tokens
-    writable = (jnp.arange(c) < true_len) & (rows >= write_from)
-    pg = jnp.clip(rows // page, 0, table_row.shape[0] - 1)
+    x, rows = lm_scan_stacks(x, arena, cfg, body, lambda key, dense: (params[key],))
+    some = next(iter(arena.values()))
+    page, n_blocks = some.shape[2], some.shape[1]
+    pos = start + jnp.arange(c)  # absolute positions of chunk tokens
+    writable = (jnp.arange(c) < true_len) & (pos >= write_from)
+    pg = jnp.clip(pos // page, 0, table_row.shape[0] - 1)
     blk = jnp.where(writable, table_row[pg], n_blocks)  # sentinel -> dropped
-    off = rows % page
+    off = pos % page
     new_arena = {}
-    for name, stacked in (("k", ks), ("v", vs)):
+    for name, stacked in rows.items():
         a = arena[name]
         new_arena[name] = a.at[:, blk, off].set(
             stacked[:, 0].astype(a.dtype), mode="drop"

@@ -45,8 +45,8 @@ def attention_specs(cfg, cross: bool = False) -> dict:
     return specs
 
 
-def mlp_specs(cfg) -> dict:
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_specs(cfg, f: Optional[int] = None) -> dict:
+    d, f = cfg.d_model, f or cfg.d_ff
     return {
         "w_gate": ParamSpec((d, f), ("embed", "ff")),
         "w_up": ParamSpec((d, f), ("embed", "ff")),
@@ -55,12 +55,37 @@ def mlp_specs(cfg) -> dict:
 
 
 def moe_specs(cfg) -> dict:
+    """Softmax routing: every expert.  Sigmoid routing (``moe_held``): the
+    router over all experts, its correction bias, the held experts and the
+    shared experts as one MLP."""
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
-    return {
+    held = cfg.held_experts if cfg.score_func == "sigmoid" else e
+    specs = {
         "router": ParamSpec((d, e), ("embed", None)),
-        "w_gate": ParamSpec((e, d, f), ("experts", "embed", "ff")),
-        "w_up": ParamSpec((e, d, f), ("experts", "embed", "ff")),
-        "w_down": ParamSpec((e, f, d), ("experts", "ff", "embed")),
+        "w_gate": ParamSpec((held, d, f), ("experts", "embed", "ff")),
+        "w_up": ParamSpec((held, d, f), ("experts", "embed", "ff")),
+        "w_down": ParamSpec((held, f, d), ("experts", "ff", "embed")),
+    }
+    if cfg.score_func == "sigmoid":
+        specs["router_bias"] = ParamSpec((e,), (None,), init="zeros")
+    if cfg.n_shared_experts:
+        specs["shared"] = mlp_specs(cfg, cfg.n_shared_experts * f)
+    return specs
+
+
+def mla_specs(cfg) -> dict:
+    """Latent attention: ``wq`` (d, H, nope + rope), ``wkv_a`` (d, latent +
+    rope) giving the cached row, ``kv_norm`` on its latent part, ``wkv_b``
+    (latent, H, nope + v) expanding keys and values, ``wo`` (H, v, d)."""
+    d, nh, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    return {
+        "wq": ParamSpec((d, nh, cfg.qk_head_dim), ("embed", "heads", None)),
+        "wkv_a": ParamSpec((d, r + cfg.qk_rope_head_dim), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), init="zeros"),
+        "wkv_b": ParamSpec(
+            (r, nh, cfg.qk_nope_head_dim + cfg.v_head_dim), (None, "heads", None)
+        ),
+        "wo": ParamSpec((nh, cfg.v_head_dim, d), ("heads", None, "embed")),
     }
 
 
@@ -120,7 +145,7 @@ def _flash_attend(
     or per-row ``(B, Sk)`` (ragged true lengths under bucketed prefill,
     DESIGN.md §6) — invalid keys get exactly-zero probability either way."""
     b, sq, kvh, g, hd = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]  # dv != hd under latent attention
     q_chunk = min(q_chunk, sq)
     kv_chunk = min(kv_chunk, sk)
     scale = hd ** -0.5
@@ -146,7 +171,7 @@ def _flash_attend(
 
     qs = q.reshape(b, sq_full // q_chunk, q_chunk, kvh, g, hd)
     ks = k.reshape(b, sk_full // kv_chunk, kv_chunk, kvh, hd)
-    vs = v.reshape(b, sk_full // kv_chunk, kv_chunk, kvh, hd)
+    vs = v.reshape(b, sk_full // kv_chunk, kv_chunk, kvh, dv)
     qps = q_pos.reshape(sq_full // q_chunk, q_chunk)
     kps = k_pos.reshape(sk_full // kv_chunk, kv_chunk)
     if per_row_valid:
@@ -189,16 +214,16 @@ def _flash_attend(
 
         m0 = jnp.full((b, kvh, g, qi.shape[1]), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kvh, g, qi.shape[1]), jnp.float32)
-        a0 = jnp.zeros((b, kvh, g, qi.shape[1], hd), jnp.float32)
+        a0 = jnp.zeros((b, kvh, g, qi.shape[1], dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0), (ks.swapaxes(0, 1), vs.swapaxes(0, 1), kps, valid)
         )
-        out = acc / jnp.maximum(l, 1e-30)[..., None]  # (b, kvh, g, qc, hd)
-        return None, out.transpose(0, 3, 1, 2, 4)  # (b, qc, kvh, g, hd)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]  # (b, kvh, g, qc, dv)
+        return None, out.transpose(0, 3, 1, 2, 4)  # (b, qc, kvh, g, dv)
 
     _, outs = jax.lax.scan(q_step, None, (qs.swapaxes(0, 1), qps))
-    # outs: (nq, b, qc, kvh, g, hd)
-    out = outs.swapaxes(0, 1).reshape(b, sq_full, kvh, g, hd)
+    # outs: (nq, b, qc, kvh, g, dv)
+    out = outs.swapaxes(0, 1).reshape(b, sq_full, kvh, g, dv)
     return out[:, :sq].astype(q.dtype)
 
 
@@ -638,12 +663,11 @@ def attention_chunk(
     p: dict,
     x: jax.Array,  # (1, C, d) — one prefill chunk of a single request
     cfg,
-    arena_k: jax.Array,  # (n_blocks, page, kvh, hd)
-    arena_v: jax.Array,
+    arena: dict,  # {"k": (n_blocks, page, kvh, hd), "v": ...} — this layer's blocks
     table: jax.Array,  # (n_pages,) int32 — the request's block table
     start: jax.Array,  # scalar int32: absolute position of the chunk's first token
     true_len: jax.Array,  # scalar int32: real (non-padding) tokens in the chunk
-) -> tuple[jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, dict]:
     """Chunked-prefill attention (Sarathi-style, DESIGN.md §11): one chunk of
     a long prompt attends causally over everything already resident in the
     request's block table plus itself.  ``start`` is traced, so one compiled
@@ -652,12 +676,14 @@ def attention_chunk(
     The chunk's K/V splice into the gathered table view by *row index*
     (padding rows past ``s_max`` drop) rather than a dynamic slice — a
     clamped slice near the cache end would silently shift the write window.
-    Returns ``(y, k_chunk, v_chunk)``; the caller scatters the chunk rows
-    into the arena (masking padding and prefix-shared rows)."""
+    Returns ``(y, {"k": k_chunk, "v": v_chunk})``, the chunk's rows by
+    cache leaf; the caller scatters them into the arena (masking padding and
+    prefix-shared rows)."""
     b, c, d = x.shape
     nh, kvh, hd = cfg.n_heads, cfg.kv_heads, cfg.hd
     positions = start + jnp.arange(c)
     q, k_new, v_new = _project_qkv(p, x, cfg, positions)
+    arena_k, arena_v = arena["k"], arena["v"]
     s_max = table.shape[0] * arena_k.shape[1]
     k_all = arena_k[table].reshape(1, s_max, *arena_k.shape[2:])
     v_all = arena_v[table].reshape(1, s_max, *arena_v.shape[2:])
@@ -672,7 +698,135 @@ def attention_chunk(
     )
     out = out.reshape(b, c, nh, hd)
     y = jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(x.dtype))
-    return y, k_new, v_new
+    return y, {"k": k_new, "v": v_new}
+
+
+# --------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3)
+#
+# A token's cache row is ``wkv_a``'s output: the normalised latent ``c_kv``
+# (kv_lora_rank wide) and one roped key part ``k_pe`` shared by every head.
+# Train and prefill expand it through ``wkv_b`` into per-head keys
+# [k_nope, k_pe] and values and run the flash path; decode absorbs ``wkv_b``
+# into the query and the output instead, so it reads the latent rows as they
+# are cached and never forms keys or values for the context.
+# --------------------------------------------------------------------------
+
+
+def _mla_query(p, x, cfg, positions, wmm=None):
+    """(q_nope (B, S, H, nope), q_pe (B, S, H, rope) roped)."""
+    b, s, _ = x.shape
+    nh, dn = cfg.n_heads, cfg.qk_nope_head_dim
+    if wmm is None:
+        q = jnp.einsum("bsd,dnh->bsnh", x, p["wq"].astype(x.dtype))
+    else:
+        q = wmm("wq", x).reshape(b, s, nh, cfg.qk_head_dim).astype(x.dtype)
+    sin, cos = rope(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    return q[..., :dn], apply_rope(q[..., dn:], sin, cos)
+
+
+def mla_latent(p, x, cfg, positions, wmm=None):
+    """The cache rows of ``x`` (B, S, d): (B, S, latent + rope) holding the
+    normalised ``c_kv`` and the roped shared ``k_pe``."""
+    r = cfg.kv_lora_rank
+    kv = x @ p["wkv_a"].astype(x.dtype) if wmm is None else wmm("wkv_a", x).astype(x.dtype)
+    sin, cos = rope(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    k_pe = apply_rope(kv[..., None, r:], sin, cos)[..., 0, :]
+    return jnp.concatenate([rms_norm(kv[..., :r], p["kv_norm"]), k_pe], axis=-1)
+
+
+def _mla_expand(p, latent, cfg):
+    """Latent rows (B, S, latent + rope) -> per-head keys (B, S, H, nope +
+    rope) and values (B, S, H, v)."""
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = jnp.einsum("bsr,rnh->bsnh", latent[..., :r], p["wkv_b"].astype(latent.dtype))
+    k_pe = jnp.broadcast_to(
+        latent[:, :, None, r:], kv.shape[:3] + (cfg.qk_rope_head_dim,)
+    )
+    return jnp.concatenate([kv[..., :dn], k_pe], axis=-1), kv[..., dn:]
+
+
+def _mla_attend(p, x, cfg, q_pos, latent, k_pos, valid, mask):
+    """Decompressed attention of ``x``'s queries over ``latent`` rows."""
+    b, s, _ = x.shape
+    nh = cfg.n_heads
+    q_nope, q_pe = _mla_query(p, x, cfg, q_pos)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1).reshape(b, s, nh, 1, cfg.qk_head_dim)
+    k, v = _mla_expand(p, latent, cfg)
+    out = _flash_attend(
+        q, k, v, mask, q_pos, k_pos, kv_valid=valid, kv_chunk=min(512, k.shape[1])
+    )
+    out = out.reshape(b, s, nh, cfg.v_head_dim)
+    return jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(x.dtype))
+
+
+def mla_attention(p, x, cfg, mask: MaskSpec, positions=None, kv_valid=None):
+    """Full-sequence latent attention (train / prefill), keys and values
+    decompressed; ``kv_valid`` as in :func:`attention`."""
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    latent = mla_latent(p, x, cfg, positions)
+    return _mla_attend(p, x, cfg, positions, latent, positions, kv_valid, mask)
+
+
+def mla_chunk(p, x, cfg, arena: dict, table, start, true_len):
+    """Chunked-prefill latent attention: :func:`attention_chunk` for a
+    ``{"latent": (n_blocks, page, latent + rope)}`` arena; the gathered rows
+    are decompressed like a prefill's.  Returns ``(y, {"latent": rows})``."""
+    c = x.shape[1]
+    positions = start + jnp.arange(c)
+    new = mla_latent(p, x, cfg, positions)
+    a = arena["latent"]
+    s_max = table.shape[0] * a.shape[1]
+    latent = a[table].reshape(1, s_max, a.shape[-1])
+    latent = latent.at[:, positions].set(new.astype(a.dtype), mode="drop")
+    rows = jnp.arange(s_max)
+    y = _mla_attend(
+        p, x, cfg, positions, latent, rows, rows < start + true_len, MaskSpec("causal")
+    )
+    return y, {"latent": new}
+
+
+@jax.named_scope("decode.attention")
+def mla_decode(p, x, cfg, cache: dict, wmm=None):
+    """One-token latent attention against the latent cache, absorbed: the
+    scores are ``(q_nope W_UK) . c_kv + q_pe . k_pe`` over the cached rows,
+    the output ``(p . c_kv) W_UV`` then ``wo``, with ``W_UK``/``W_UV`` the
+    key and value halves of ``wkv_b`` (a bf16 einsum, not packed).
+
+    ``cache`` is ``{"latent": (B, S_max, w), "pos"}`` or paged, ``{"latent":
+    (n_blocks, page, w), "table", "pos"}``, the same contract as
+    :func:`attention_decode`: the paged form returns the new row as a
+    pending write ``latent_new``."""
+    b, s, _ = x.shape
+    assert s == 1, "latent attention decodes one token per step"
+    r, dn, nh = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.n_heads
+    pos = cache["pos"]
+    paged = "table" in cache
+    a = cache["latent"]
+    lat = a[cache["table"]].reshape(1, -1, a.shape[-1]) if paged else a
+    q_nope, q_pe = _mla_query(p, x, cfg, pos[None], wmm)
+    new = mla_latent(p, x, cfg, pos[None], wmm).astype(a.dtype)
+    lat = jax.lax.dynamic_update_slice(lat, new, (0, pos, 0))
+    w_kv = p["wkv_b"].astype(x.dtype)
+    q_lat = jnp.einsum("bsnh,rnh->bsnr", q_nope, w_kv[..., :dn])
+    sc = jnp.einsum("bsnr,btr->bnst", q_lat, lat[..., :r], preferred_element_type=jnp.float32)
+    sc = sc + jnp.einsum(
+        "bsnh,bth->bnst", q_pe, lat[..., r:], preferred_element_type=jnp.float32
+    )
+    sc = sc * cfg.qk_head_dim ** -0.5
+    valid = jnp.arange(lat.shape[1]) <= pos
+    sc = jnp.where(valid, sc, _NEG_INF)
+    prob = jax.nn.softmax(sc, axis=-1)
+    o_lat = jnp.einsum("bnst,btr->bsnr", prob, lat[..., :r].astype(jnp.float32))
+    out = jnp.einsum("bsnr,rnh->bsnh", o_lat.astype(x.dtype), w_kv[..., dn:])
+    if wmm is None:
+        y = jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(x.dtype))
+    else:
+        y = wmm("wo", out.reshape(b, 1, nh * cfg.v_head_dim)).astype(x.dtype)
+    if paged:
+        return y, {"latent_new": new, "pos": pos + 1}
+    return y, {"latent": lat, "pos": pos + 1}
 
 
 def cross_attention(
@@ -792,3 +946,52 @@ def moe(p: dict, x: jax.Array, cfg, capacity_factor: float | None = None):
     w = jnp.where(dropped, 0.0, gate_w.reshape(-1)).astype(x.dtype)
     y = (y * w[:, None]).reshape(t, k, d).sum(axis=1)
     return y.reshape(b, s, d), aux
+
+
+def route(p: dict, x: jax.Array, cfg):
+    """Sigmoid routing (DeepSeek-V3 ``noaux_tc``, one group) of rows ``x``
+    (T, d), in float32 over all ``n_experts``: experts chosen by top-k of
+    ``s + router_bias``, each weighted by its score ``s`` alone, normalised
+    over the chosen (``norm_topk_prob``), times ``routed_scale``.  Returns
+    ``(gates (T, held), rows)``: each held expert's weight per row (0 where
+    it was not chosen) and the number of (row, held expert) pairs chosen."""
+    s = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"].astype(jnp.float32))
+    _, ids = jax.lax.top_k(s + p["router_bias"].astype(jnp.float32), cfg.top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    w = w * cfg.routed_scale
+    mine = ids[..., None] == jnp.arange(cfg.held_experts)  # (T, k, held)
+    gates = jnp.sum(jnp.where(mine, w[..., None], 0.0), axis=-2)
+    return gates, jnp.sum(mine, dtype=jnp.int32)
+
+
+def held_experts(p: dict, x: jax.Array, gates: jax.Array) -> jax.Array:
+    """Sum over the held experts of each expert's SwiGLU output on ``x``
+    (T, d) times its gate (T, held), in float32.  Dropless: every row runs
+    through every held expert, a zero gate leaving it out."""
+
+    def one(y, e):
+        wg, wu, wd, g = e
+        h = jax.nn.silu(x @ wg.astype(x.dtype)) * (x @ wu.astype(x.dtype))
+        return y + (h @ wd.astype(x.dtype)).astype(jnp.float32) * g[:, None], None
+
+    y0 = jnp.zeros(x.shape, jnp.float32)
+    y, _ = jax.lax.scan(one, y0, (p["w_gate"], p["w_up"], p["w_down"], gates.T))
+    return y
+
+
+def moe_held(p: dict, x: jax.Array, cfg):
+    """Expert layer of an expert-parallel deployment, on the chip that holds
+    the first ``n_experts / ep_size`` experts: route over all experts
+    (:func:`route`), add the held experts' part for the rows routed to them
+    and the shared experts once.  The result is this chip's partial sum; the
+    other chips' experts, and the exchange that would add their parts, are
+    not here.  Returns ``(y, 0.0)``: the routing has no auxiliary loss."""
+    b, s, d = x.shape
+    xf = x.reshape(b * s, d)
+    gates, _ = route(p, xf, cfg)
+    y = held_experts(p, xf, gates).astype(x.dtype).reshape(b, s, d)
+    if "shared" in p:
+        y = y + mlp(p["shared"], x)
+    return y, 0.0

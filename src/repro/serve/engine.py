@@ -31,7 +31,15 @@ from ..models import build_model
 from .faults import FaultConfig
 from .metrics import span
 
-__all__ = ["ServeConfig", "Engine"]
+__all__ = ["ServeConfig", "Engine", "pop_expert_rows"]
+
+
+def pop_expert_rows(cache):
+    """Split a decode step's cache from the ``expert_rows`` it may carry:
+    the (row, held expert) pairs the step's routing chose (0 for a model
+    without held experts).  A cache that goes round a loop leaves it out."""
+    cache = dict(cache)
+    return cache, cache.pop("expert_rows", jnp.int32(0))
 
 
 @dataclasses.dataclass
@@ -181,13 +189,13 @@ class Engine:
         # capacity-bounded MoE dispatch couples co-batched rows (padding and
         # neighbour tokens consume shared expert capacity, changing which
         # tokens drop), so batching is only bit-exact when no token can ever
-        # drop (moe_cf >= n_experts/top_k).  encdec consumes frames, and vlm
-        # needs per-request patch extras prime_many has no way to carry (and
-        # whose patch-prefix KV rows the token-length slot ``pos`` would
-        # disown).  Everything else falls back to per-request admission.
-        batchable = cfg.family == "dense" or (
-            cfg.family == "moe" and cfg.moe_cf >= cfg.n_experts / cfg.top_k
-        )
+        # drop (``ArchConfig.moe_dropless``: moe_cf >= n_experts/top_k, or
+        # the held-expert layer, dropless by construction).  encdec consumes
+        # frames, and vlm needs per-request patch extras prime_many has no
+        # way to carry (and whose patch-prefix KV rows the token-length slot
+        # ``pos`` would disown).  Everything else falls back to per-request
+        # admission.
+        batchable = cfg.family == "dense" or (cfg.family == "moe" and cfg.moe_dropless)
         self._prefill_masked = jax.jit(self._prefill_masked_fn) if batchable else None
         # chunked-prefill entry for the paged pool (DESIGN.md §11): donates
         # the arena; jax.jit re-specializes per static chunk length, so one
@@ -297,7 +305,9 @@ class Engine:
         ``(next_token (B, 1), cache, ok (B,))`` where ``ok`` is the per-row
         integrity guard — ``isfinite`` over the fp32 logits (DESIGN.md §9).
         Computed on device and carried through the fused scan, it costs no
-        extra host sync: the scheduler fetches it with the segment tokens."""
+        extra host sync: the scheduler fetches it with the segment tokens.
+        A model of held experts' cache carries the step's ``expert_rows``
+        (``pop_expert_rows``), fetched the same way."""
         with self._mesh_ctx():
             if packed is not None:
                 from .packed import lm_decode_step_packed
@@ -354,6 +364,7 @@ class Engine:
             token, cache, key = carry
             key, sub = jax.random.split(key)
             token, cache, ok = self._decode_fn(params, token, cache, sub)
+            cache, _ = pop_expert_rows(cache)
             return (token, cache, key), (token[:, 0], ok)
 
         (token, cache, key), (toks, okg) = jax.lax.scan(
@@ -506,6 +517,7 @@ class Engine:
             _, cache, key = carry
             key, sub = jax.random.split(key)
             nxt, cache, _ = self._decode_fn(params, tok[:, None], cache, sub)
+            cache, _ = pop_expert_rows(cache)
             return (nxt, cache, key), None
 
         init = (prompts[:, :1], cache, key)

@@ -1,11 +1,13 @@
-"""VUSA-packed decode path for the dense LM family (DESIGN.md §7).
+"""VUSA-packed decode path for the decoder LMs (DESIGN.md §7, §14).
 
 ``pack_lm_weights`` packs the decode-step weights into the row-wise VUSA
 format: per-layer MLP matrices (``w_gate``/``w_up`` plain, ``w_down``
 *transposed* so the fused megakernel can window its reduction dim), and —
 with ``scope="all"`` — the attention projections ``wq/wk/wv/wo`` and the
 untied LM head.  One static sparse format serves every GEMM of the decode
-step, the paper's application-independence claim on the serving path.
+step, the paper's application-independence claim on the serving path.  A
+model of held experts adds their per-expert packs for the grouped kernel,
+its shared experts and leading dense layers taking the fused MLP's layout.
 
 ``lm_decode_step_packed`` is a twin of ``families.lm_decode_step`` whose
 packed matmuls run through the Pallas kernels: the MLP through the fused
@@ -32,6 +34,7 @@ from ..kernels.ops import (
     RowPackedLinear,
     apply_fused_mlp,
     apply_fused_mlp_sharded,
+    apply_fused_moe,
     apply_row_packed,
     apply_row_packed_sharded,
     mesh_axis_size,
@@ -56,6 +59,7 @@ __all__ = [
 ]
 
 ATTN_NAMES = ("wq", "wk", "wv", "wo")
+MLA_NAMES = ("wq", "wkv_a", "wo")  # latent attention's packed projections
 
 
 # --------------------------------------------------------------------------
@@ -140,32 +144,9 @@ def pack_lm_mlps(cfg: ArchConfig, params, m: int = 128, a: int = 16) -> Dict:
     }
 
 
-def pack_lm_weights(
-    cfg: ArchConfig,
-    params,
-    m: int = 128,
-    a: int = 16,
-    scope: str = "all",
-    fused_mlp: bool = True,
-    shards: int = 1,
-    value_dtype: str = "dense",
-) -> Dict:
-    """Pack the dense-family decode-step weights; returns a structured dict.
-
-    ``scope="mlp"`` packs only the per-layer MLP trio; ``scope="all"`` adds
-    the attention projections (head dims flattened to 2-D) and the untied
-    LM head (tied embeddings stay a gather + transpose-einsum — there is no
-    separate weight to pack).  ``fused_mlp`` selects the megakernel operand
-    layout (``w_down`` packed transposed via ``pack_linear_rows_t``) vs the
-    3-dispatch baseline layout (``w_down`` packed plain).  ``shards`` pads
-    every window axis to a multiple (no-op windows, exact) so the packs can
-    be split over a TP mesh axis of that size — place them with
-    :func:`shard_packed` (DESIGN.md §8).  ``value_dtype="int8"``/``"int4"``
-    quantizes every pack's value slots with per-(window, row) fp32 scales
-    (DESIGN.md §10); ``"dense"`` keeps the native float dtype."""
-    assert cfg.family == "dense", "packed decode path targets the dense family"
-    assert scope in ("mlp", "all"), scope
-    ffn = params["layers"]["ffn"]
+def _pack_mlp(ffn, m, a, shards, value_dtype, fused_mlp: bool) -> Dict:
+    """The SwiGLU trio of a layer stack: ``w_down`` packed transposed for
+    the fused megakernel, plain for the 3-dispatch baseline."""
     mlp: Dict = {
         name: _stack_layers(np.asarray(ffn[name]), m, a, shards=shards, value_dtype=value_dtype)
         for name in ("w_gate", "w_up")
@@ -179,25 +160,98 @@ def pack_lm_weights(
         mlp["w_down"] = _stack_layers(
             np.asarray(ffn["w_down"]), m, a, shards=shards, value_dtype=value_dtype
         )
+    return mlp
+
+
+def _pack_attn(cfg, attn_p, m, a, shards, value_dtype) -> Dict:
+    """A layer stack's attention projections, head dims flattened to 2-D;
+    latent attention packs ``wq``, ``wkv_a`` and ``wo`` (``wkv_b`` stays a
+    bf16 einsum of the absorbed decode)."""
+    attn: Dict = {}
+    for name in MLA_NAMES if cfg.mla else ATTN_NAMES:
+        w = np.asarray(attn_p[name])  # (L, d, ...) or wo (L, nh, hd, d)
+        flat = (
+            w.reshape(w.shape[0], -1, w.shape[-1])  # wo: (L, nh*hd, d)
+            if name == "wo"
+            else w.reshape(w.shape[0], w.shape[1], -1)  # (L, d, n)
+        )
+        attn[name] = _stack_layers(flat, m, a, shards=shards, value_dtype=value_dtype)
+    return attn
+
+
+def _pack_experts(ffn, m, a, shards, value_dtype) -> Dict:
+    """Every layer's held experts as fused-MLP packs stacked (L, E, ...) and
+    padded to one slot count: the grouped kernel's operands."""
+    out: Dict = {}
+    for name, src, fn in (("w_gate", "w_gate", pack_linear_rows),
+                          ("w_up", "w_up", pack_linear_rows),
+                          ("w_down_t", "w_down", pack_linear_rows_t)):
+        w = np.asarray(ffn[src])  # (L, E, ...)
+        st = _stack_packs([
+            shard_linear_windows(fn(w[i, e], m=m, a=a, value_dtype=value_dtype), shards)
+            for i in range(w.shape[0]) for e in range(w.shape[1])
+        ])
+        for leaf in ("values", "positions", "scales"):
+            if leaf in st:
+                st[leaf] = st[leaf].reshape(w.shape[:2] + st[leaf].shape[1:])
+        out[name] = st
+    return out
+
+
+def pack_lm_weights(
+    cfg: ArchConfig,
+    params,
+    m: int = 128,
+    a: int = 16,
+    scope: str = "all",
+    fused_mlp: bool = True,
+    shards: int = 1,
+    value_dtype: str = "dense",
+) -> Dict:
+    """Pack the decode-step weights of a decoder LM; returns a structured dict.
+
+    ``scope="mlp"`` packs only the feed-forward weights; ``scope="all"``
+    adds the attention projections (head dims flattened to 2-D) and the
+    untied LM head (tied embeddings stay a gather + transpose-einsum — there
+    is no separate weight to pack).  ``fused_mlp`` selects the megakernel
+    operand layout (``w_down`` packed transposed via ``pack_linear_rows_t``)
+    vs the 3-dispatch baseline layout (``w_down`` packed plain).  ``shards``
+    pads every window axis to a multiple (no-op windows, exact) so the packs
+    can be split over a TP mesh axis of that size — place them with
+    :func:`shard_packed` (DESIGN.md §8).  ``value_dtype="int8"``/``"int4"``
+    quantizes every pack's value slots with per-(window, row) fp32 scales
+    (DESIGN.md §10); ``"dense"`` keeps the native float dtype.
+
+    ``mlp`` and ``attn`` are the main layer stack's.  A model of held
+    experts (sigmoid routing) packs, besides, ``experts``: the grouped
+    kernel's per-expert packs, stacked (L, E, ...); its ``mlp`` is then the
+    shared experts, and its leading dense layers pack as ``dense_layers``
+    (``{"mlp", "attn"}``, the same layouts)."""
+    held = cfg.held_moe
+    assert cfg.family == "dense" or held, (
+        "packed decode serves the dense family and held-expert models"
+    )
+    assert scope in ("mlp", "all"), scope
+    assert fused_mlp or not held, "held experts run through the fused kernels"
+    geo = (m, a, shards, value_dtype)
+    ffn = params["layers"]["ffn"]
     out: Dict = {
-        "mlp": mlp,
+        "mlp": _pack_mlp(ffn["shared"] if held else ffn, *geo, fused_mlp),
         "attn": None,
         "head": None,
         "scope": scope,
         "fused_mlp": fused_mlp,
     }
+    if held:
+        out["experts"] = _pack_experts(ffn, *geo)
+    if cfg.n_dense_layers:
+        dense = params["dense_layers"]
+        out["dense_layers"] = {
+            "mlp": _pack_mlp(dense["ffn"], *geo, fused_mlp),
+            "attn": _pack_attn(cfg, dense["attn"], *geo) if scope == "all" else None,
+        }
     if scope == "all":
-        attn_p = params["layers"]["attn"]
-        attn: Dict = {}
-        for name in ATTN_NAMES:
-            w = np.asarray(attn_p[name])  # (L, d, nh, hd) or (L, nh, hd, d)
-            flat = (
-                w.reshape(w.shape[0], -1, w.shape[-1])  # wo: (L, nh*hd, d)
-                if name == "wo"
-                else w.reshape(w.shape[0], w.shape[1], -1)  # q/k/v: (L, d, nh*hd)
-            )
-            attn[name] = _stack_layers(flat, m, a, shards=shards, value_dtype=value_dtype)
-        out["attn"] = attn
+        out["attn"] = _pack_attn(cfg, params["layers"]["attn"], *geo)
         if not cfg.tie_embeddings:
             out["head"] = _pack_one(
                 shard_linear_windows(
@@ -241,6 +295,13 @@ def shard_packed(packed: Dict, mesh) -> Dict:
     out["mlp"] = {name: place(e, 1) for name, e in packed["mlp"].items()}
     if packed.get("attn"):
         out["attn"] = {name: place(e, 1) for name, e in packed["attn"].items()}
+    if packed.get("experts"):
+        out["experts"] = {name: place(e, 2) for name, e in packed["experts"].items()}
+    if packed.get("dense_layers"):
+        out["dense_layers"] = {
+            group: {name: place(e, 1) for name, e in entries.items()} if entries else None
+            for group, entries in packed["dense_layers"].items()
+        }
     if packed.get("head") is not None:
         out["head"] = place(packed["head"], 0)
     return out
@@ -254,6 +315,11 @@ def _flat_entries(packed: Dict) -> Dict[str, Dict]:
         flat.update(packed["mlp"])
         if packed.get("attn"):
             flat.update(packed["attn"])
+        for name, e in (packed.get("experts") or {}).items():
+            flat["experts/" + name] = e
+        for group in (packed.get("dense_layers") or {}).values():
+            for name, e in (group or {}).items():
+                flat["dense_layers/" + name] = e
         if packed.get("head") is not None:
             flat["lm_head"] = packed["head"]
     else:
@@ -334,8 +400,11 @@ def validate_packed(packed: Dict) -> None:
                 raise ValueError(f"{name}: non-positive dequant scale at {i}")
         if q.dtype != jnp.int8:
             raise ValueError(f"{name}: positions dtype must be int8, got {q.dtype}")
-        if v.ndim not in (3, 4):
-            raise ValueError(f"{name}: expected (T, K, S) or (L, T, K, S), got {tuple(v.shape)}")
+        if v.ndim not in (3, 4, 5):
+            raise ValueError(
+                f"{name}: expected (T, K, S), (L, T, K, S) or (L, E, T, K, S), "
+                f"got {tuple(v.shape)}"
+            )
         if m < 1 or a < 1 or m > 128:
             raise ValueError(f"{name}: window m={m} / slots a={a} out of range (int8 lanes)")
         if v.shape[-2] != k:
@@ -379,7 +448,7 @@ def packed_byte_ratios(packed: Dict, value_bytes: Optional[int] = None) -> Dict[
     tot_packed = tot_dense = 0
     for name, e in flat.items():
         v = e["values"]
-        n_layers = v.shape[0] if v.ndim == 4 else 1
+        n_layers = int(np.prod(v.shape[:-3]))  # matrices stacked: layers (x experts)
         if e.get("value_dtype", "dense") == "dense":
             vb = v.dtype.itemsize if value_bytes is None else value_bytes
             pb = v.size * (vb + 1)  # values + int8 positions
@@ -471,7 +540,12 @@ def qdq_lm_params(
 
 
 def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
-    """Decode step with VUSA-packed weights (dense family only).  ``token``
+    """Decode step with VUSA-packed weights: the dense family, and models of
+    held experts (``pack_lm_weights``), whose layer stacks run in turn with
+    the same step as the dense family's plus the expert layer: routing
+    (``decode.router``), the grouped kernel (``decode.experts``) and the
+    shared experts on the fused kernel (``decode.mlp``); the step's cache
+    output then carries ``expert_rows`` (``lm_decode_step``).  ``token``
     is (B, 1) for normal decode or (B, s) for a speculative multi-token
     verify (contiguous cache only).  A *fully* packed step (scope="all"
     with an untied, packed LM head) runs the s-row verify genuinely
@@ -496,7 +570,9 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
     (column windows: gate/up/qkv/o/head).  A mesh whose ``model`` axis is
     absent or size 1 is the degenerate case — identical program to
     ``mesh=None`` (DESIGN.md §8)."""
-    assert cfg.family == "dense", "packed decode path targets the dense family"
+    assert cfg.family == "dense" or cfg.held_moe, (
+        "packed decode serves the dense family and held-expert models"
+    )
     if token.shape[1] > 1:
         assert "table" not in cache, (
             "multi-token decode needs a contiguous cache; gather the paged "
@@ -517,19 +593,17 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
             return jnp.concatenate(logits, axis=1), cache
     if "mlp" not in packed:  # legacy flat layout
         packed = {"mlp": packed, "attn": None, "head": None, "fused_mlp": False}
-    mlp = packed["mlp"]
-    attn = packed["attn"]
-    fused = packed.get("fused_mlp", "w_down_t" in mlp)
+    fused = packed.get("fused_mlp", "w_down_t" in packed["mlp"])
     if mesh_axis_size(mesh, "model") == 1:
         mesh = None  # degenerate: plain single-device appliers
 
     x = F._embed_tokens(params, token, cfg)
     pos = cache["pos"]
     # paged per-slot view (DESIGN.md §11): same contract as lm_decode_step —
-    # arena leaves scan per layer, the step returns pending k_new/v_new rows
+    # arena leaves scan per layer, the step returns pending rows (<leaf>_new)
     table = cache.get("table")
 
-    from ..models.layers import attention_decode  # noqa: PLC0415
+    from ..models.layers import attention_decode, mla_decode, route  # noqa: PLC0415
 
     def papply(entry, vals, poss, x2, scales=None, name="vusa_packed_matmul"):
         lin = _as_linear(entry, vals, poss, scales)
@@ -544,18 +618,23 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
                 for leaf in ("values", "positions", "scales")
                 if leaf in e
             }
-            for n, e in group.items()
+            for n, e in (group or {}).items()
         }
 
-    xs = (
-        params["layers"],
-        {"k": cache["k"], "v": cache["v"]},
-        arrays(mlp),
-        arrays(attn) if attn is not None else {},
-    )
+    def lin(group, leaves, name):
+        return _as_linear(
+            group[name], leaves[name]["values"], leaves[name]["positions"],
+            leaves[name].get("scales"),
+        )
 
-    def body(x, layer_in):
-        lp, cache_l, mlp_l, attn_l = layer_in
+    def groups(key):  # a layer stack's packs
+        if key == "layers":
+            return packed["mlp"], packed["attn"], packed.get("experts")
+        return packed[key]["mlp"], packed[key]["attn"], None
+
+    def body(x, layer_in, dense):
+        lp, cache_l, mlp_l, attn_l, exp_l = layer_in
+        mlp, attn, experts = groups("dense_layers" if dense else "layers")
         if table is not None:
             cache_l = {**cache_l, "table": table}
         h = rms_norm(x, lp["norm1"])
@@ -569,28 +648,30 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
             if attn is not None
             else None
         )
-        y, new_cache = attention_decode(
-            lp["attn"], h, cfg, {**cache_l, "pos": pos}, wmm=wmm
-        )
+        attend = mla_decode if cfg.mla else attention_decode
+        y, new_cache = attend(lp["attn"], h, cfg, {**cache_l, "pos": pos}, wmm=wmm)
         x = x + y
+        out = F.lm_step_rows(new_cache, cfg)
         with jax.named_scope("decode.mlp"):
             h = rms_norm(x, lp["norm2"])
             b, s, d = h.shape
             hf = h.reshape(b * s, d)
+        if experts is not None:
+            with jax.named_scope("decode.router"):
+                gates, out["expert_rows"] = route(lp["ffn"], hf, cfg)
+            with jax.named_scope("decode.experts"):
+                y2 = apply_fused_moe(
+                    hf, gates, lin(experts, exp_l, "w_gate"), lin(experts, exp_l, "w_up"),
+                    lin(experts, exp_l, "w_down_t"),
+                )
+                x = x + y2.reshape(b, s, d).astype(x.dtype)
+        with jax.named_scope("decode.mlp"):
             if fused:
-
-                def lin(name):
-                    return _as_linear(
-                        mlp[name], mlp_l[name]["values"], mlp_l[name]["positions"],
-                        mlp_l[name].get("scales"),
-                    )
-
+                g, u, dn = (lin(mlp, mlp_l, n) for n in ("w_gate", "w_up", "w_down_t"))
                 if mesh is not None:
-                    y2 = apply_fused_mlp_sharded(
-                        hf, lin("w_gate"), lin("w_up"), lin("w_down_t"), mesh
-                    )
+                    y2 = apply_fused_mlp_sharded(hf, g, u, dn, mesh)
                 else:
-                    y2 = apply_fused_mlp(hf, lin("w_gate"), lin("w_up"), lin("w_down_t"))
+                    y2 = apply_fused_mlp(hf, g, u, dn)
             else:  # 3-dispatch baseline: gate/up/down round-trip the (B, ff)
 
                 def pap(name, x2):
@@ -603,12 +684,13 @@ def lm_decode_step_packed(params, packed, token, cache, cfg, mesh=None):
                 up = pap("w_up", hf)
                 y2 = pap("w_down", (gate * up).astype(hf.dtype))
             x = x + y2.reshape(b, s, d).astype(x.dtype)
-        if "k_new" in new_cache:
-            return x, {"k_new": new_cache["k_new"], "v_new": new_cache["v_new"]}
-        return x, {"k": new_cache["k"], "v": new_cache["v"]}
+        return x, out
 
-    with calls_repeat(cfg.n_layers):
-        x, new_kv = jax.lax.scan(body, x, xs)
+    def stack_inputs(key, dense):
+        mlp, attn, experts = groups(key)
+        return params[key], arrays(mlp), arrays(attn), arrays(experts)
+
+    x, new_kv = F.lm_scan_stacks(x, cache, cfg, body, stack_inputs, repeat=calls_repeat)
     with jax.named_scope("decode.head"):
         x = rms_norm(x, params["final_norm"])
         if packed.get("head") is not None:

@@ -96,7 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.vusa_packed import fold_tally
-from .engine import Engine
+from .engine import Engine, pop_expert_rows
 from .metrics import SpanTimes, acceptance_rate, tok_per_s
 
 __all__ = ["Request", "Completion", "Scheduler", "Status"]
@@ -364,6 +364,9 @@ class Scheduler:
             # real prompt tokens against the positions they computed
             # (bucket length x padded batch rows)
             syncs=0, prefill_dispatches=0, prefill_tokens=0, prefill_positions=0,
+            # (row, held expert) pairs the plain segments' routing chose, over
+            # the slots that held a request (paged pool) or every slot
+            expert_rows=0,
         )
         # packed-kernel calls per decode step of the last traced segment
         # program: folded into the slot rows, and left to the grid-axis
@@ -685,15 +688,16 @@ class Scheduler:
                 key = jax.random.wrap_key_data(kd)
                 key, sub = jax.random.split(key)
                 nxt, c2, ok = decode(params, tok, c, sub)
-                return nxt, jax.random.key_data(key), c2, ok
+                c2, rows = pop_expert_rows(c2)
+                return nxt, jax.random.key_data(key), c2, ok, rows
 
-            token, kdata, cache, ok = self._vmap_slots(one, token, kdata, cache)
-            return (token, kdata, cache), (token[:, 0, 0], ok[:, 0])
+            token, kdata, cache, ok, rows = self._vmap_slots(one, token, kdata, cache)
+            return (token, kdata, cache), (token[:, 0, 0], ok[:, 0], rows.sum())
 
-        (token, kdata, cache), (toks, okg) = jax.lax.scan(
+        (token, kdata, cache), (toks, okg, rows) = jax.lax.scan(
             body, (token, kdata, cache), None, length=steps
         )
-        return token, kdata, cache, toks, okg
+        return token, kdata, cache, toks, okg, rows.sum()
 
     def _segment_paged_fn(self, params, token, kdata, pstate, steps: int, dense: bool):
         """Paged twin of :meth:`_segment_fn`.  Each slot decodes against the
@@ -716,19 +720,22 @@ class Scheduler:
                 key, sub = jax.random.split(key)
                 nxt, c2, ok = decode(params, tok, c, sub)
                 rows = {n + "_new": c2[n + "_new"] for n in names}
-                return nxt, jax.random.key_data(key), rows, ok
+                return nxt, jax.random.key_data(key), rows, ok, pop_expert_rows(c2)[1]
 
-            token, kdata, rows, ok = self._vmap_slots(
+            # a slot holds a request while its table points past the
+            # reserved blocks; free slots' routing is not counted
+            live = pstate["table"][:, 0] >= self._layout.reserved
+            token, kdata, rows, ok, er = self._vmap_slots(
                 one, token, kdata, paged_view(pstate), in_axes=(0, 0, paged_in_axes(pstate))
             )
             with jax.named_scope("decode.attention"):
                 pstate = paged_scatter_token(pstate, rows)
-            return (token, kdata, pstate), (token[:, 0, 0], ok[:, 0])
+            return (token, kdata, pstate), (token[:, 0, 0], ok[:, 0], (er * live).sum())
 
-        (token, kdata, pstate), (toks, okg) = jax.lax.scan(
+        (token, kdata, pstate), (toks, okg, er) = jax.lax.scan(
             body, (token, kdata, pstate), None, length=steps
         )
-        return token, kdata, pstate, toks, okg
+        return token, kdata, pstate, toks, okg, er.sum()
 
     def _segment_spec_fn(self, params, token, kdata, cache, steps: int, dense: bool):
         """Speculative twin of :meth:`_segment_fn` (DESIGN.md §13): each scan
@@ -1591,24 +1598,26 @@ class Scheduler:
                 )
             return toks, nems, okg
         if self.paged:
-            self._token, self._kdata, self._pstate, toks, okg = self._seg_paged(
+            self._token, self._kdata, self._pstate, toks, okg, rows = self._seg_paged(
                 *args, self._pstate, self.segment, dense
             )
         else:
-            self._token, self._kdata, self._cache, toks, okg = self._seg(
+            self._token, self._kdata, self._cache, toks, okg, rows = self._seg(
                 *args, self._cache, self.segment, dense
             )
-        return toks, None, okg
+        return toks, None, okg, rows
 
     def _fetch(self, grids: tuple) -> tuple:
         """The segment's one sync: ``(tokens, accepted counts, flags)`` on
         the host, shaped ``(segment, slots, S)``, ``(segment, slots)``,
         ``(segment, slots, S)``; a plain segment reads as degenerate S=1
-        rounds, so one consumption loop serves both modes."""
-        toks, nems, okg = grids
+        rounds, so one consumption loop serves both modes.  The segment's
+        expert-row count comes in the same transfer and is counted here."""
+        toks, nems, okg, *rows = grids
         if nems is not None:
             return jax.device_get((toks, nems, okg))
-        toks_np, ok_np = jax.device_get((toks, okg))  # (segment, slots) x2
+        toks_np, ok_np, n = jax.device_get((toks, okg, rows[0]))  # (segment, slots) x2, ()
+        self._counters["expert_rows"] += int(n)
         return toks_np[:, :, None], np.ones(toks_np.shape, np.int64), ok_np[:, :, None]
 
     def _consume(self, active_idx: List[int], toks_np, nem_np, ok_np, t: float) -> None:
@@ -1702,7 +1711,10 @@ class Scheduler:
         ``packed_calls_folded`` and ``packed_calls_fallback`` count the
         packed-kernel calls per decode step (per round, speculative) of the
         last traced segment program that ran the slots as kernel rows, and
-        that took the grid-axis fallback."""
+        that took the grid-axis fallback.  ``expert_rows`` counts the
+        (row, held expert) pairs the decode steps routed, ``decode_steps``
+        the pool's steps (``slot_occupancy``'s), ``held_experts`` the
+        experts each of the ``expert_layers`` holds (0 and 0 without)."""
         done = sorted(self._completions.values(), key=lambda c: c.rid)
         lat = np.asarray([c.latency_s for c in done], np.float64)
         lat = lat[np.isfinite(lat)]
@@ -1790,4 +1802,8 @@ class Scheduler:
         )
         out.update(self._counters)
         out["packed_calls_folded"], out["packed_calls_fallback"] = self._kernel_calls
+        cfg = self.eng.cfg
+        out["decode_steps"] = self._seg_steps
+        out["held_experts"] = cfg.held_experts if cfg.held_moe else 0
+        out["expert_layers"] = cfg.n_layers - cfg.n_dense_layers if cfg.held_moe else 0
         return out

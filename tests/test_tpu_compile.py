@@ -25,7 +25,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.vusa_packed import vusa_fused_mlp_matmul, vusa_packed_matmul
+from repro.kernels.vusa_packed import (
+    vusa_fused_mlp_matmul,
+    vusa_fused_moe_matmul,
+    vusa_packed_matmul,
+)
 
 D_MODEL, D_FF, SLOTS, M, BATCH = 768, 3072, 48, 128, 8
 
@@ -152,3 +156,49 @@ def test_kernel_calls_carry_their_names(one_chip, no_persistent_cache, chip_k_bl
     (call_name, x_shape), = calls
     assert re.fullmatch(rf"{want}(\.\d+)?", call_name), calls
     assert x_shape == (BATCH, D_MODEL), calls
+
+
+# an expert layer's held experts at Moonlight-16B-A3B's widths: 8 of 64
+# experts held, each a (2048, 1408) SwiGLU, 11 ff windows
+EXPERTS, D_EXPERT, D_HIDDEN = 8, 1408, 2048
+
+
+@pytest.mark.parametrize("slot_axis", [False, True], ids=["engine", "scheduler"])
+@pytest.mark.parametrize("value_dtype", ["dense", "int8", "int4"])
+def test_grouped_expert_kernel_compiles_for_v5e(
+    one_chip, no_persistent_cache, value_dtype, slot_axis, monkeypatch
+):
+    """``vusa_fused_moe_matmul`` compiles for the chip at the k_blk the
+    chip picks, and under the Scheduler's slot vmap, where the rows and
+    their expert weights both carry the slot axis, it is one call on all
+    the slots' rows, named after the kernel."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_VUSA_KBLK", raising=False)
+    k_blk = ops.choose_k_blk(D_HIDDEN, SLOTS, M)
+    t = -(-D_EXPERT // M)
+    nib = 2 if value_dtype == "int4" else 1
+    vdt = jnp.bfloat16 if value_dtype == "dense" else jnp.int8
+
+    def pack(rows):
+        v = jax.ShapeDtypeStruct((EXPERTS, t, rows, SLOTS // nib), vdt, sharding=one_chip)
+        p = jax.ShapeDtypeStruct((EXPERTS, t, rows, SLOTS), jnp.int8, sharding=one_chip)
+        s = (None if value_dtype == "dense"
+             else jax.ShapeDtypeStruct((EXPERTS, t, rows), jnp.float32, sharding=one_chip))
+        return v, p, s
+
+    (v, p, s), (dv, dp, ds) = pack(D_HIDDEN), pack(D_HIDDEN)
+    kw = dict(m=M, k_blk=k_blk, interpret=False, value_dtype=value_dtype)
+
+    def call(x, w, v, p, s, dv, dp, ds):
+        return vusa_fused_moe_matmul(x, w, v, p, v, p, dv, dp, s, s, ds, **kw)
+
+    lead = (BATCH, 1) if slot_axis else (BATCH,)
+    x = jax.ShapeDtypeStruct(lead + (D_HIDDEN,), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct(lead + (EXPERTS,), jnp.float32, sharding=one_chip)
+    fn = jax.vmap(call, in_axes=(0, 0) + (None,) * 6) if slot_axis else call
+    text = jax.jit(fn).lower(x, w, v, p, s, dv, dp, ds).compile().as_text()
+    calls = _tpu_calls(text)
+    assert len(calls) == 1, calls
+    (call_name, x_shape), = calls
+    assert re.fullmatch(r"vusa_fused_moe_matmul(\.\d+)?", call_name), calls
+    assert x_shape == (BATCH, D_HIDDEN), calls
