@@ -330,7 +330,8 @@ def unpack_rows(p: RowPacked) -> np.ndarray:
     w = np.zeros((k, t, p.m), dtype=p.values.dtype)
     ti, r, s = np.nonzero(np.asarray(p.row_positions) >= 0)
     lane = np.asarray(p.row_positions)[ti, r, s].astype(np.int64)
-    # add.at accumulates repeated (row, lane) slots, as the kernel's sum does
+    # add.at accumulates repeated (row, lane) slots; a pack never holds any
+    # (the kernel selects, and would keep the last)
     np.add.at(w, (r, ti, lane), np.asarray(p.values)[ti, r, s])
     return w.reshape(k, t * p.m)[:, : p.c]
 

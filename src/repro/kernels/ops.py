@@ -152,7 +152,7 @@ from ..core.packing import (  # noqa: E402
 )
 from .ref import vusa_fused_mlp_ref, vusa_fused_moe_ref, vusa_packed_ref  # noqa: E402
 from .vusa_packed import (  # noqa: E402
-    DEFAULT_SLOT_CHUNK,
+    TILE_ROWS,
     vusa_fused_mlp_matmul,
     vusa_fused_moe_matmul,
     vusa_packed_matmul,
@@ -289,23 +289,24 @@ def pad_slots(p: RowPackedLinear, slots: int) -> RowPackedLinear:
 
 # -- k_blk / m tuning ------------------------------------------------------
 #
-# The kernel's only free parameters are the K block (bounds the one-hot
-# scratch: k_blk * min(slots, slot_chunk) * m * 4 bytes) and the window
+# The kernel's only free parameters are the K block (rows a grid step
+# streams in, and the fused kernels' reconstruction chunk) and the window
 # width m (fixed at pack time, <= 128).  ``choose_k_blk`` is the heuristic;
 # ``autotune_row_packed`` measures the candidates once per shape and caches
 # the winner so subsequent ``apply_row_packed`` calls use it.
 
 _KBLK_CACHE: dict = {}  # (k, slots, m, b, backend) -> k_blk
-_VMEM_SCRATCH_BUDGET = 2 * 1024 * 1024  # bytes for the one-hot scatter tensor
+_VMEM_TILE_BUDGET = 2 * 1024 * 1024  # bytes a reconstruction chunk keeps live
 
 
 def _kblk_candidates(k: int, slots: int, m: int):
     """K blocks worth trying for a reduction of ``k`` rows, ascending.
 
     On the TPU a block must be lane-aligned — a multiple of 128 that divides
-    ``k``, or all of ``k`` — and its one-hot scatter scratch, ``k_blk *
-    min(slots, slot_chunk) * m * 4`` bytes, must fit the VMEM budget: Mosaic
-    refuses the rest.  When no aligned block fits, the smallest aligned one
+    ``k``, or all of ``k`` — and what a chunk of that many rows keeps live
+    must fit the VMEM budget: per row, its slots widened to 32 bits and
+    transposed (``4 * slots * 4`` bytes) and a column of the transposed fp32
+    tile (``m * 4``).  When no aligned block fits, the smallest aligned one
     is the only candidate.  Off the TPU (interpret mode) there is no such
     wall, and powers of two from 64 plus ``k`` itself are tried."""
     if not on_tpu():
@@ -314,8 +315,8 @@ def _kblk_candidates(k: int, slots: int, m: int):
             c.append(k)
         return c or [k]
     aligned = [blk for blk in range(128, k + 1, 128) if k % blk == 0] or [k]
-    scratch = min(slots, DEFAULT_SLOT_CHUNK) * m * 4
-    return [blk for blk in aligned if blk * scratch <= _VMEM_SCRATCH_BUDGET] or aligned[:1]
+    live = 16 * slots + 4 * m
+    return [blk for blk in aligned if blk * live <= _VMEM_TILE_BUDGET] or aligned[:1]
 
 
 def _largest_divisor_leq(k: int, blk: int) -> int:
@@ -336,8 +337,10 @@ def _largest_divisor_leq(k: int, blk: int) -> int:
 
 
 def choose_k_blk(k: int, slots: int, m: int) -> int:
-    """Pick the K block without measuring: the largest candidate.  On the
-    TPU the candidates are already VMEM-budgeted; off it (interpret mode)
+    """Pick the K block without measuring.  On the TPU, one lane tile of
+    rows (``TILE_ROWS``) wherever it is a candidate: the rows a plain
+    kernel rebuilds at a time, and the fused kernels' chunk; else the
+    largest candidate.  Off it (interpret mode) the largest candidate:
     fewer, larger grid steps win (measured in benchmarks/run.py
     kernel_vusa_packed)."""
     env = os.environ.get("REPRO_VUSA_KBLK")
@@ -347,16 +350,15 @@ def choose_k_blk(k: int, slots: int, m: int) -> int:
         except ValueError as e:
             raise ValueError(f"REPRO_VUSA_KBLK must be an integer, got {env!r}") from e
         return _largest_divisor_leq(k, blk)  # snap down to a divisor of k
-    return _kblk_candidates(k, slots, m)[-1]
+    cands = _kblk_candidates(k, slots, m)
+    return TILE_ROWS if on_tpu() and TILE_ROWS in cands else cands[-1]
 
 
-def _tune_key(
-    xf: jax.Array, p: RowPackedLinear, interp: bool, reconstruct: str, slot_chunk: int
-):
-    # reconstruct/slot_chunk are part of the key: a k_blk tuned for the
-    # one-pass "onehot" reconstruction is generally wrong for the per-slot
-    # "loop" baseline (and vice versa) — the seed omitted both, so a cache
-    # entry from one mode silently drove the other
+def _tune_key(xf: jax.Array, p: RowPackedLinear, interp: bool, reconstruct: str):
+    # reconstruct is part of the key: a k_blk tuned for the "onehot"
+    # reconstruction is generally wrong for the "loop" baseline (and vice
+    # versa) — the seed omitted it, so a cache entry from one mode silently
+    # drove the other
     # value_dtype must be explicit: int8 and int4 packs share the jnp int8
     # array dtype, so str(dtype) alone would collide their cache entries
     # The REPRO_VUSA_KBLK override is part of the key: an entry tuned while
@@ -365,7 +367,7 @@ def _tune_key(
     return (
         xf.shape[-1], p.values.shape[2], p.m, xf.shape[0],
         str(p.values.dtype), p.value_dtype, interp, jax.default_backend(),
-        reconstruct, slot_chunk, os.environ.get("REPRO_VUSA_KBLK", ""),
+        reconstruct, os.environ.get("REPRO_VUSA_KBLK", ""),
     )
 
 
@@ -376,19 +378,18 @@ def autotune_row_packed(
     interpret: bool | None = None,
     iters: int = 5,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
 ) -> int:
     """Time the kernel over k_blk candidates; cache + return the winner."""
     interp = interpret_mode(interpret)
     xf = x.reshape(-1, x.shape[-1])
-    key = _tune_key(xf, p, interp, reconstruct, slot_chunk)
+    key = _tune_key(xf, p, interp, reconstruct)
     if key in _KBLK_CACHE:
         return _KBLK_CACHE[key]
     best_blk, best_t = None, float("inf")
     for blk in _kblk_candidates(xf.shape[-1], p.slots, p.m):
         f = lambda a: vusa_packed_matmul(
             a, p.values, p.positions, p.scales, m=p.m, k_blk=blk, interpret=interp,
-            reconstruct=reconstruct, slot_chunk=slot_chunk, value_dtype=p.value_dtype,
+            reconstruct=reconstruct, value_dtype=p.value_dtype,
         )
         f(xf).block_until_ready()  # compile
         t0 = time.perf_counter()
@@ -408,7 +409,6 @@ def apply_row_packed(
     interpret: bool | None = None,
     k_blk: int | None = None,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
     name: str = "vusa_packed_matmul",
 ) -> jax.Array:
     """y = x @ W for row-packed W.  x: (..., K) -> (..., C).
@@ -427,7 +427,7 @@ def apply_row_packed(
             k_blk = choose_k_blk(k, slots, p.m)
         else:
             k_blk = _KBLK_CACHE.get(
-                _tune_key(xf, p, interp, reconstruct, slot_chunk)
+                _tune_key(xf, p, interp, reconstruct)
             ) or choose_k_blk(k, slots, p.m)
     k_blk = min(k_blk, k)
     while k % k_blk:
@@ -441,7 +441,6 @@ def apply_row_packed(
         k_blk=max(k_blk, 1),
         interpret=interp,
         reconstruct=reconstruct,
-        slot_chunk=slot_chunk,
         value_dtype=p.value_dtype,
         name=name,
     )
@@ -480,13 +479,12 @@ def _fused_tune_key(
     down_t: RowPackedLinear,
     interp: bool,
     reconstruct: str,
-    slot_chunk: int,
 ):
     return (
         "fused", xf.shape[-1], down_t.k, xf.shape[0],
         gate.values.shape[2], up.values.shape[2], down_t.values.shape[2], gate.m,
         str(gate.values.dtype), gate.value_dtype, interp, jax.default_backend(),
-        reconstruct, slot_chunk, os.environ.get("REPRO_VUSA_KBLK", ""),
+        reconstruct, os.environ.get("REPRO_VUSA_KBLK", ""),
     )
 
 
@@ -499,7 +497,6 @@ def autotune_fused_mlp(
     interpret: bool | None = None,
     iters: int = 5,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
 ) -> int:
     """Time the fused megakernel over k_blk candidates; cache the winner.
 
@@ -509,7 +506,7 @@ def autotune_fused_mlp(
     interp = interpret_mode(interpret)
     xf = x.reshape(-1, x.shape[-1])
     _check_fused_packs(xf.shape[-1], gate, up, down_t)
-    key = _fused_tune_key(xf, gate, up, down_t, interp, reconstruct, slot_chunk)
+    key = _fused_tune_key(xf, gate, up, down_t, interp, reconstruct)
     if key in _KBLK_CACHE:
         return _KBLK_CACHE[key]
     best_blk, best_t = None, float("inf")
@@ -522,8 +519,7 @@ def autotune_fused_mlp(
             a, gate.values, gate.positions, up.values, up.positions,
             down_t.values, down_t.positions,
             gate.scales, up.scales, down_t.scales, m=gate.m, k_blk=blk,
-            interpret=interp, reconstruct=reconstruct, slot_chunk=slot_chunk,
-            value_dtype=gate.value_dtype,
+            interpret=interp, reconstruct=reconstruct, value_dtype=gate.value_dtype,
         )
         f(xf).block_until_ready()  # compile
         t0 = time.perf_counter()
@@ -545,7 +541,6 @@ def apply_fused_mlp(
     interpret: bool | None = None,
     k_blk: int | None = None,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
 ) -> jax.Array:
     """Whole SwiGLU MLP through the fused megakernel.
 
@@ -567,7 +562,7 @@ def apply_fused_mlp(
             k_blk = choose_k_blk(k, slots, gate.m)
         else:
             k_blk = _KBLK_CACHE.get(
-                _fused_tune_key(xf, gate, up, down_t, interp, reconstruct, slot_chunk)
+                _fused_tune_key(xf, gate, up, down_t, interp, reconstruct)
             ) or choose_k_blk(k, slots, gate.m)
     y = vusa_fused_mlp_matmul(
         xf,
@@ -584,7 +579,6 @@ def apply_fused_mlp(
         k_blk=max(int(k_blk), 1),
         interpret=interp,
         reconstruct=reconstruct,
-        slot_chunk=slot_chunk,
         value_dtype=gate.value_dtype,
     )
     return y.reshape(*lead, down_t.k).astype(x.dtype)
@@ -614,7 +608,6 @@ def apply_fused_moe(
     interpret: bool | None = None,
     k_blk: int | None = None,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
 ) -> jax.Array:
     """Held experts through the grouped kernel: ``gate``/``up``/``down_t``
     stack one fused-MLP pack per expert on a leading axis (values (E, T, K,
@@ -630,7 +623,7 @@ def apply_fused_moe(
         xf, wf, gate.values, gate.positions, up.values, up.positions,
         down_t.values, down_t.positions, gate.scales, up.scales, down_t.scales,
         m=gate.m, k_blk=max(int(k_blk), 1), interpret=interp, reconstruct=reconstruct,
-        slot_chunk=slot_chunk, value_dtype=gate.value_dtype,
+        value_dtype=gate.value_dtype,
     )
     return y.reshape(*lead, down_t.k).astype(x.dtype)
 

@@ -16,22 +16,31 @@ sparsity with (M=128, A=16, J=2) that is ~2.4x less weight traffic, which is
 the whole game for memory-bound decode (Edge-AI inference, the paper's
 target).
 
-Dense-tile reconstruction (DESIGN.md §3) has two implementations, selected
-by the static ``reconstruct`` argument:
+Dense-tile reconstruction (DESIGN.md §3) builds each window's tile
+*transposed*, ``(M, R)`` for ``R`` rows: the window's lanes in sublanes,
+the rows in lanes.  The rows' value and position blocks are widened and
+transposed in VMEM to ``(S, R)``, so each slot is one lane row.  Two
+implementations, selected by the static ``reconstruct`` argument:
 
-* ``"onehot"`` (default) — a vectorized contraction over chunks of slots:
-  ``positions == lanes[..., None]`` builds the one-hot scatter tensor and
-  one multiply-reduce produces the dense (K_blk, M) tile.  One VPU pass per
-  ``slot_chunk`` slots; this is the fast path and the only one that
-  compiles for the TPU.
-* ``"loop"`` — the original per-slot ``fori_loop`` select-accumulate
-  (``J*A`` sequential VPU passes).  Kept as the measured baseline for
+* ``"onehot"`` (default) — one select per slot, a static loop: the slot's
+  positions, broadcast over sublanes, against the sublane iota are its
+  one-hot columns, and ``where`` writes its values there.  A row holds
+  each lane at most once, so the select is exact and idle slots (-1)
+  match nothing.  There is no 3-D one-hot tensor and no reduction across
+  sublanes.  This is the fast path and the only one that compiles for the
+  TPU.
+* ``"loop"`` — the original per-slot ``fori_loop`` select-accumulate over
+  the same transposed blocks.  Kept as the measured baseline for
   ``benchmarks/run.py kernel_vusa_packed``; its traced-offset slot slice
   runs in interpret mode only.
 
-Values may be fp32 or bf16; accumulation is always fp32 (both the one-hot
-contraction and the MXU matmul run with ``preferred_element_type=float32``)
-and the kernel output is fp32.
+The MXU contracts the transposed tile where it lies: ``x (B, R)`` against
+its dim 1 (an NT matmul) for a plain window, ``h (B, M)`` against its dim 0
+for the transposed ``w_down`` pack.
+
+Values may be fp32 or bf16; accumulation is always fp32 (the tile is
+fp32 and the MXU matmul runs with ``preferred_element_type=float32``) and
+the kernel output is fp32.
 
 Quantized value slots (DESIGN.md §10): with ``value_dtype="int8"`` or
 ``"int4"`` the value operand is raw int8 bytes plus a per-(window, row) fp32
@@ -44,12 +53,13 @@ column for the transposed ``w_down`` pack.  HBM only ever moves quantized
 bytes.  Positions stay full-resolution int8 either way.
 
 Grid: (output windows, K blocks); K innermost for output-block accumulation.
-VMEM per step: x (B, K_blk), vals (K_blk, J*A), pos (K_blk, J*A),
-one-hot scratch (K_blk, slot_chunk, M) for "onehot", reconstructed W
-(K_blk, M) fp32, acc (B, M) fp32.  ``k_blk`` is the knob that bounds the
-scratch — see ``repro.kernels.ops.choose_k_blk``.  A ``jax.vmap`` over
-``x`` alone (the Scheduler's slot axis) keeps this grid: the vmapped rows
-become more rows of one call (``fold_slot_vmap``).
+VMEM per step: x (B, K_blk), vals (K_blk, J*A), pos (K_blk, J*A), acc
+(B, M) fp32; the block is rebuilt ``TILE_ROWS`` rows at a time, each chunk
+keeping its widened, transposed slots (2 x (S, R) 32-bit) and its
+transposed tile (M, R) fp32 live (``repro.kernels.ops.choose_k_blk`` picks
+``k_blk``).  A ``jax.vmap`` over ``x`` alone (the Scheduler's slot axis)
+keeps this grid: the vmapped rows become more rows of one call
+(``fold_slot_vmap``).
 
 ``vusa_fused_mlp_matmul`` is the whole-MLP megakernel (DESIGN.md §7): one
 ``pallas_call`` whose grid walks the ff windows.  Each step reconstructs
@@ -59,7 +69,7 @@ transposed, so its reduction dim is the windowed one) and accumulates
 straight into the ``(B, d_model)`` output — the ``(B, ff)`` intermediate
 never touches HBM and the per-layer dispatch count drops from three to one.
 Its chunk loops over ``k_blk`` rows are ``fori_loop``s, so only one chunk's
-scratch is live at a time.
+tile is live at a time.
 
 ``vusa_fused_moe_matmul`` runs an expert layer's held experts, each a fused
 MLP in that layout, in one call whose grid walks (expert, ff window); each
@@ -86,45 +96,41 @@ __all__ = [
     "calls_repeat",
     "FoldTally",
     "RECONSTRUCT_MODES",
-    "DEFAULT_SLOT_CHUNK",
+    "TILE_ROWS",
 ]
 
 RECONSTRUCT_MODES = ("onehot", "loop")
-DEFAULT_SLOT_CHUNK = 24  # slots per one-hot pass; bounds the scatter scratch
+TILE_ROWS = 128  # rows a plain kernel's block is rebuilt in: one lane tile
 
 
-def _reconstruct_onehot(vals, pos, m: int, slot_chunk: int):
-    """Vectorized scatter: slots in wide select-reduce chunks.
+def _reconstruct_onehot(vals_t, pos_t, m: int):
+    """Per-slot select into the transposed tile.
 
-    vals: (K_blk, S) fp32, pos: (K_blk, S) int32 (-1 = idle slot).
-    Returns the dense (K_blk, M) tile in fp32.  Idle slots compare unequal
-    to every lane, so they contribute exact zeros.  ``slot_chunk`` bounds
-    the (K_blk, chunk, M) scatter tensor; chunks are static slices, so a
-    chunk covering all S slots is a single VPU pass.
-    """
-    k_blk, s = vals.shape
-    chunk = min(slot_chunk, s)
-    w = jnp.zeros((k_blk, m), jnp.float32)
-    for s0 in range(0, s, chunk):
-        v = vals[:, s0 : s0 + chunk]
-        q = pos[:, s0 : s0 + chunk]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, q.shape + (m,), 2)
-        w += jnp.sum(jnp.where(q[..., None] == lanes, v[..., None], 0.0), axis=1)
+    vals_t: (S, R) fp32, pos_t: (S, R) int32 (-1 = idle slot).  Returns
+    the transposed dense (m, R) tile in fp32.  Slot ``a``'s row, broadcast
+    over sublanes, meets the sublane iota: each lane (a pack row) selects
+    its value into the sublane its position names.  A row holds each lane
+    at most once, so a select replaces the sum exactly."""
+    s, r = vals_t.shape
+    sub = jax.lax.broadcasted_iota(jnp.int32, (m, r), 0)
+    w = jnp.zeros((m, r), jnp.float32)
+    for a in range(s):
+        w = jnp.where(pos_t[a : a + 1] == sub, vals_t[a : a + 1], w)
     return w
 
 
-def _reconstruct_loop(vals, pos, m: int):
-    """Seed baseline: one VPU select-accumulate pass per slot (interpret
-    mode only: the slot offset is traced)."""
-    k_blk, slots = vals.shape
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (k_blk, m), 1)
+def _reconstruct_loop(vals_t, pos_t, m: int):
+    """Seed baseline: one VPU select-accumulate pass per slot, on the same
+    transposed blocks (interpret mode only: the slot offset is traced)."""
+    slots, r = vals_t.shape
+    sub = jax.lax.broadcasted_iota(jnp.int32, (m, r), 0)
 
     def slot(a, w):
-        v = jax.lax.dynamic_slice_in_dim(vals, a, 1, axis=1)  # (K_blk, 1)
-        p = jax.lax.dynamic_slice_in_dim(pos, a, 1, axis=1)
-        return w + jnp.where(lanes == p, v, 0.0)
+        v = jax.lax.dynamic_slice_in_dim(vals_t, a, 1, axis=0)  # (1, R)
+        p = jax.lax.dynamic_slice_in_dim(pos_t, a, 1, axis=0)
+        return w + jnp.where(sub == p, v, 0.0)
 
-    return jax.lax.fori_loop(0, slots, slot, jnp.zeros((k_blk, m), jnp.float32))
+    return jax.lax.fori_loop(0, slots, slot, jnp.zeros((m, r), jnp.float32))
 
 
 def _decode(raw, value_dtype: str):
@@ -141,62 +147,93 @@ def _decode(raw, value_dtype: str):
     return lo.astype(jnp.float32), hi.astype(jnp.float32)
 
 
-def _tile(raw, pos, m: int, reconstruct: str, slot_chunk: int, value_dtype: str):
-    """Dense (R, m) fp32 tile of one window's rows from their value and
-    position blocks."""
-    groups = _decode(raw, value_dtype)
-    pos = pos.astype(jnp.int32)
-    if reconstruct == "loop":
-        return _reconstruct_loop(jnp.concatenate(groups, axis=1), pos, m)
-    w, s0 = None, 0
-    for g in groups:  # each lane is hit by at most one slot: the sum is exact
-        part = _reconstruct_onehot(g, pos[:, s0 : s0 + g.shape[1]], m, slot_chunk)
-        w = part if w is None else w + part
-        s0 += g.shape[1]
-    return w
+def _tile(raw, pos, m: int, reconstruct: str, value_dtype: str):
+    """Transposed dense (m, R) fp32 tile of one window's R rows from their
+    value and position blocks: entry (i, r) is row r's weight at lane i.
+    The widened blocks are transposed to (S, R), an int4 pack's two
+    nibble groups each on its own."""
+    pos_t = pos.astype(jnp.int32).T
+    vals_t = jnp.concatenate([g.T for g in _decode(raw, value_dtype)], axis=0)
+    rebuild = _reconstruct_loop if reconstruct == "loop" else _reconstruct_onehot
+    return rebuild(vals_t, pos_t, m)
 
 
-def _contract(a, b, b_dim: int, exact_rows: bool):
-    """``a`` (B, n) contracted with axis ``b_dim`` of the 2-D ``b``, in fp32.
+def _contract(a, w_t, dim: int, exact_rows: bool):
+    """``a`` (B, n) contracted with axis ``dim`` of the transposed (m, R)
+    tile ``w_t``, in fp32: dim 1 where the tile's rows are the reduction
+    (``x @ W``, an NT matmul), dim 0 for the transposed ``w_down`` pack,
+    whose tile holds the window's ``w_down`` rows as they lie.
 
-    ``exact_rows`` (interpret mode) spells it as a broadcast multiply-reduce,
-    whose summation order does not depend on B.  XLA:CPU's gemms pick their
-    order by shape, so a B-row call would differ in the last bit from B
-    one-row calls, and both the batched speculative verify (DESIGN.md §13)
-    and a slot vmap folded into rows rely on the kernels being row-bitwise.
+    ``exact_rows`` (interpret mode) spells it as one rank-1 update per
+    contracted index in a ``fori_loop``, a summation order that depends on
+    neither B nor the slot count.  XLA:CPU picks a gemm's order by shape,
+    and a reduction's by what it fuses with (the tile's select chain grows
+    with the slots), so a B-row call would differ in the last bit from B
+    one-row calls, and a padded pack from its unpadded one.  The batched
+    speculative verify (DESIGN.md §13), a slot vmap folded into rows and
+    layer stacking's slot padding rely on the kernels being row-bitwise.
     Compiled, it is the MXU ``dot_general``."""
     if not exact_rows:
         return jax.lax.dot_general(
-            a, b, (((1,), (b_dim,)), ((), ())), preferred_element_type=jnp.float32
+            a, w_t, (((1,), (dim,)), ((), ())), preferred_element_type=jnp.float32
         )
-    if b_dim == 0:
-        return jnp.sum(a[:, :, None] * b[None], axis=1)
-    return jnp.sum(a[:, None, :] * b[None], axis=2)
+
+    def update(r, acc):
+        col = jax.lax.dynamic_index_in_dim(a, r, 1, keepdims=False)
+        row = jax.lax.dynamic_index_in_dim(w_t, r, dim, keepdims=False)
+        return acc + col[:, None] * row[None, :]
+
+    out = jnp.zeros((a.shape[0], w_t.shape[1 - dim]), jnp.float32)
+    return jax.lax.fori_loop(0, a.shape[1], update, out)
 
 
-def _kernel(x_ref, val_ref, pos_ref, *rest, m: int, reconstruct: str, slot_chunk: int,
-            value_dtype: str, exact_rows: bool):
-    """One (window, K block) step; ``rest`` is ``(scale_ref, y_ref)`` for a
-    quantized pack and ``(y_ref,)`` otherwise.  ``exact_rows``: as in
-    ``_contract``, so a call on a slot vmap's folded rows equals the
-    per-slot calls bit for bit in interpret mode."""
+def _row_chunks(n: int, k_blk: int, body, carry):
+    """``carry = body(r0, width, carry)`` over rows ``[0, n)`` in ``k_blk``
+    chunks: the full chunks in a ``fori_loop`` (one chunk's tile live at a
+    time, offsets hinted ``k_blk``-aligned) unless there is one, a ragged
+    tail statically."""
+    full = n // k_blk
+    if full == 1:
+        carry = body(0, k_blk, carry)
+    elif full:
+        carry = jax.lax.fori_loop(
+            0, full, lambda c, cr: body(pl.multiple_of(c * k_blk, k_blk), k_blk, cr), carry
+        )
+    if n % k_blk:
+        carry = body(full * k_blk, n % k_blk, carry)
+    return carry
+
+
+def _kernel(x_ref, val_ref, pos_ref, *rest, m: int, reconstruct: str, value_dtype: str,
+            exact_rows: bool):
+    """One (window, K block) step, the block rebuilt ``TILE_ROWS`` rows at
+    a time; ``rest`` is ``(scale_ref, y_ref)`` for a quantized pack and
+    ``(y_ref,)`` otherwise.  ``exact_rows``: as in ``_contract``, so a call
+    on a slot vmap's folded rows equals the per-slot calls bit for bit in
+    interpret mode."""
     scale_ref, y_ref = rest if len(rest) == 2 else (None, rest[0])
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    x = x_ref[...].astype(jnp.float32)
-    if scale_ref is not None:
-        x = x * scale_ref[0]  # (1, K_blk) row scales fold into x's columns
-    w = _tile(val_ref[0], pos_ref[0], m, reconstruct, slot_chunk, value_dtype)
-    y_ref[...] += _contract(x, w, 0, exact_rows)
+    def chunk(r0, width, acc):
+        rows = pl.ds(r0, width)
+        x = x_ref[:, rows].astype(jnp.float32)
+        if scale_ref is not None:
+            x = x * scale_ref[0, :, rows]  # (1, width) row scales fold into x's columns
+        w_t = _tile(val_ref[0, rows, :], pos_ref[0, rows, :], m, reconstruct, value_dtype)
+        return acc + _contract(x, w_t, 1, exact_rows)
+
+    y_ref[...] += _row_chunks(
+        x_ref.shape[1], TILE_ROWS, chunk, jnp.zeros(y_ref.shape, jnp.float32)
+    )
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype", "name",
+        "interpret", "k_blk", "m", "reconstruct", "value_dtype", "name",
     ),
 )
 def _packed_matmul(
@@ -209,7 +246,6 @@ def _packed_matmul(
     k_blk: int = 256,
     interpret: bool = False,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
     value_dtype: str = "dense",
     name: str = "vusa_packed_matmul",
 ) -> jax.Array:
@@ -246,8 +282,8 @@ def _packed_matmul(
         operands.append(scales.reshape(t, 1, k))
     return pl.pallas_call(
         functools.partial(
-            _kernel, m=m, reconstruct=reconstruct, slot_chunk=slot_chunk,
-            value_dtype=value_dtype, exact_rows=interpret,
+            _kernel, m=m, reconstruct=reconstruct, value_dtype=value_dtype,
+            exact_rows=interpret,
         ),
         grid=(t, k // k_blk),
         in_specs=in_specs,
@@ -263,23 +299,9 @@ def _packed_matmul(
 # --------------------------------------------------------------------------
 
 
-def _row_chunks(n: int, k_blk: int, body, carry):
-    """``carry = body(r0, width, carry)`` over rows ``[0, n)`` in ``k_blk``
-    chunks: the full chunks in a ``fori_loop`` (one chunk's scratch live at
-    a time, offsets hinted ``k_blk``-aligned), a ragged tail statically."""
-    full = n // k_blk
-    if full:
-        carry = jax.lax.fori_loop(
-            0, full, lambda c, cr: body(pl.multiple_of(c * k_blk, k_blk), k_blk, cr), carry
-        )
-    if n % k_blk:
-        carry = body(full * k_blk, n % k_blk, carry)
-    return carry
-
-
 def _fused_mlp_kernel(
-    x_ref, *refs, m: int, k_blk: int, reconstruct: str, slot_chunk: int,
-    value_dtype: str, exact_rows: bool, gated: bool = False, grid_rank: int = 1,
+    x_ref, *refs, m: int, k_blk: int, reconstruct: str, value_dtype: str,
+    exact_rows: bool, gated: bool = False, grid_rank: int = 1,
 ):
     """One ff window of the fused MLP: gate/up reconstruct + matmul,
     ``silu(gate) * up`` in VMEM, then the window's ``w_down`` rows
@@ -293,9 +315,7 @@ def _fused_mlp_kernel(
     gv_ref, gp_ref, uv_ref, up_ref, dv_ref, dp_ref, *rest = refs
     *scales, y_ref = rest
     gs_ref, us_ref, ds_ref = scales or (None, None, None)
-    tile = functools.partial(
-        _tile, m=m, reconstruct=reconstruct, slot_chunk=slot_chunk, value_dtype=value_dtype
-    )
+    tile = functools.partial(_tile, m=m, reconstruct=reconstruct, value_dtype=value_dtype)
     first = pl.program_id(0) == 0
     for axis in range(1, grid_rank):
         first = jnp.logical_and(first, pl.program_id(axis) == 0)
@@ -309,8 +329,8 @@ def _fused_mlp_kernel(
         x = x_ref[:, rows].astype(jnp.float32)
         xg = x if gs_ref is None else x * gs_ref[0, :, rows]
         xu = x if us_ref is None else x * us_ref[0, :, rows]
-        g = _contract(xg, tile(gv_ref[0, rows, :], gp_ref[0, rows, :]), 0, exact_rows)
-        u = _contract(xu, tile(uv_ref[0, rows, :], up_ref[0, rows, :]), 0, exact_rows)
+        g = _contract(xg, tile(gv_ref[0, rows, :], gp_ref[0, rows, :]), 1, exact_rows)
+        u = _contract(xu, tile(uv_ref[0, rows, :], up_ref[0, rows, :]), 1, exact_rows)
         return acc[0] + g, acc[1] + u
 
     b = x_ref.shape[0]
@@ -322,9 +342,9 @@ def _fused_mlp_kernel(
 
     def down(r0, width, carry):
         rows = pl.ds(r0, width)
-        # (width, m) rows of w_down.T — lanes are this window's ff rows
-        wd = tile(dv_ref[0, rows, :], dp_ref[0, rows, :])
-        y = _contract(h, wd, 1, exact_rows)
+        # (m, width): this window's ff rows of w_down, output columns in lanes
+        wd_t = tile(dv_ref[0, rows, :], dp_ref[0, rows, :])
+        y = _contract(h, wd_t, 0, exact_rows)
         if ds_ref is not None:
             y = y * ds_ref[0, :, rows]  # a w_down_t row is an output column
         y_ref[:, rows] += y
@@ -335,7 +355,7 @@ def _fused_mlp_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype"),
+    static_argnames=("interpret", "k_blk", "m", "reconstruct", "value_dtype"),
 )
 def _fused_mlp_matmul(
     x: jax.Array,  # (B, K)
@@ -353,7 +373,6 @@ def _fused_mlp_matmul(
     k_blk: int = 256,
     interpret: bool = False,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
     value_dtype: str = "dense",
 ) -> jax.Array:
     """Whole SwiGLU MLP in one ``pallas_call``: ``silu(x@Wg) * (x@Wu) @ Wd``.
@@ -412,7 +431,7 @@ def _fused_mlp_matmul(
     return pl.pallas_call(
         functools.partial(
             _fused_mlp_kernel, m=m, k_blk=k_blk, reconstruct=reconstruct,
-            slot_chunk=slot_chunk, value_dtype=value_dtype, exact_rows=interpret,
+            value_dtype=value_dtype, exact_rows=interpret,
         ),
         grid=(t,),
         in_specs=in_specs,
@@ -430,7 +449,7 @@ def _fused_mlp_matmul(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "k_blk", "m", "reconstruct", "slot_chunk", "value_dtype"),
+    static_argnames=("interpret", "k_blk", "m", "reconstruct", "value_dtype"),
 )
 def _fused_moe_matmul(
     x: jax.Array,  # (B, K)
@@ -449,7 +468,6 @@ def _fused_moe_matmul(
     k_blk: int = 256,
     interpret: bool = False,
     reconstruct: str = "onehot",
-    slot_chunk: int = DEFAULT_SLOT_CHUNK,
     value_dtype: str = "dense",
 ) -> jax.Array:
     """``sum_e weights[:, e] * mlp_e(x)`` over E stacked SwiGLU experts in
@@ -510,7 +528,7 @@ def _fused_moe_matmul(
     return pl.pallas_call(
         functools.partial(
             _fused_mlp_kernel, m=m, k_blk=k_blk, reconstruct=reconstruct,
-            slot_chunk=slot_chunk, value_dtype=value_dtype, exact_rows=interpret,
+            value_dtype=value_dtype, exact_rows=interpret,
             gated=True, grid_rank=2,
         ),
         grid=(e, t),

@@ -132,22 +132,58 @@ def test_byte_ratio_vs_sparsity_tracks_growth_model():
 @pytest.mark.parametrize(
     "k,slots,want",
     [
-        (768, 48, [128]),  # vusa_edge d_model rows at 85 %: 256 rows overflow
-        (3072, 48, [128]),  # vusa_edge d_ff rows (w_down)
-        (768, 8, [128, 256, 384]),  # few slots: larger aligned blocks fit
+        (768, 48, [128, 256, 384, 768]),  # vusa_edge d_model rows at 85 %
+        (3072, 48, [128, 256, 384, 512, 768, 1024, 1536]),  # d_ff rows (w_down)
+        (768, 8, [128, 256, 384, 768]),  # few slots: the whole K fits too
         (64, 48, [64]),  # K below one lane tile: the whole K
     ],
 )
 def test_kblk_candidates_on_chip(monkeypatch, k, slots, want):
-    """On the TPU only lane-aligned blocks whose one-hot scratch fits the
-    VMEM budget are candidates, so the autotuners never time a block Mosaic
-    refuses; choose_k_blk takes the largest."""
+    """On the TPU only lane-aligned blocks whose reconstruction chunk fits
+    the VMEM budget (per row: 4 x slots x 4 bytes of widened, transposed
+    slots and an m x 4 column of the tile) are candidates, so the
+    autotuners never time a block Mosaic refuses; choose_k_blk takes one
+    lane tile of rows, ``TILE_ROWS``, wherever it is a candidate."""
     from repro.kernels import ops
+    from repro.kernels.vusa_packed import TILE_ROWS
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     monkeypatch.delenv("REPRO_VUSA_KBLK", raising=False)
     assert ops._kblk_candidates(k, slots, 128) == want
-    assert ops.choose_k_blk(k, slots, 128) == want[-1]
+    assert ops.choose_k_blk(k, slots, 128) == (TILE_ROWS if TILE_ROWS in want else want[-1])
+
+
+@pytest.mark.parametrize("reconstruct", ["onehot", "loop"])
+@pytest.mark.parametrize("value_dtype", ["bf16", "int8", "int4"])
+def test_tile_is_the_transposed_window(value_dtype, reconstruct):
+    """A window's reconstructed tile is its dense columns transposed, (m,
+    rows), exactly: bf16, int8 and int4 packs whose rows leave slots idle,
+    over a ragged last window (200 columns in two 128-lane windows)."""
+    from repro.core.packing import dequantize_rows, pack_rows, quantize_rows, unpack_rows
+    from repro.kernels.vusa_packed import _tile
+
+    rng = np.random.default_rng(12)
+    k, c, m = 40, 200, 128
+    w = _sparse(rng, k, c, 0.85, np.float32)
+    w = np.asarray(jnp.asarray(w, jnp.bfloat16), np.float32)  # exact in bf16
+    rp = pack_rows(w, m=m, a=8)
+    assert (rp.row_positions < 0).any()  # idle slots
+    if value_dtype == "bf16":
+        values, positions, scales = jnp.asarray(rp.values, jnp.bfloat16), rp.row_positions, None
+        dense, vd = unpack_rows(rp), "dense"
+    else:
+        q = quantize_rows(rp, value_dtype)
+        values, positions = jnp.asarray(q.values), q.row_positions
+        scales = np.asarray(q.scales, np.float32)
+        dense, vd = unpack_rows(dequantize_rows(q)), value_dtype
+    for t in range(rp.values.shape[0]):
+        w_t = np.asarray(_tile(values[t], jnp.asarray(positions[t]), m, reconstruct, vd))
+        assert w_t.shape == (m, k)
+        if scales is not None:
+            w_t = w_t * scales[t][None, :]  # a row's scale: a lane of the tile
+        cols = dense[:, t * m : (t + 1) * m]
+        np.testing.assert_array_equal(w_t[: cols.shape[1]], cols.T)
+        np.testing.assert_array_equal(w_t[cols.shape[1] :], 0.0)  # padded lanes
 
 
 def test_interpret_mode_only_on_cpu(monkeypatch):

@@ -9,7 +9,9 @@ compile ``vusa_packed_matmul`` and ``vusa_fused_mlp_matmul`` at
 slot axis.  Nothing runs: a pass means the chip's compiler accepts the
 kernels and that they lower to a Pallas TPU call named after the kernel,
 the name a device trace shows for each call.  Under the slot vmap the
-program holds one such call, on all the slots' rows.
+program holds one such call, on all the slots' rows.  The three kernels
+also compile at the chip benchmark cells' own widths (InternLM2-1.8B's MLP
+and head, Moonlight-16B-A3B's held experts), int8 under the slot vmap.
 
 This is the only test file that describes the chip.  The topology is
 described inside a module fixture (never at import or collection time), so
@@ -202,3 +204,58 @@ def test_grouped_expert_kernel_compiles_for_v5e(
     (call_name, x_shape), = calls
     assert re.fullmatch(r"vusa_fused_moe_matmul(\.\d+)?", call_name), calls
     assert x_shape == (BATCH, D_HIDDEN), calls
+
+
+# the chip benchmark cells' own widths, int8 at 85 % sparsity (48 slots):
+# InternLM2-1.8B's fused MLP (2048 x 8192) and 92544-wide head, 8 slots a
+# step; Moonlight-16B-A3B's 8 held experts (2048 x 1408), 64 slots a step
+CELL_D, CELL_FF, CELL_VOCAB = 2048, 8192, 92544
+
+
+@pytest.mark.parametrize("case", ["internlm2_mlp", "internlm2_head", "moonlight_experts"])
+def test_cell_width_kernels_compile_for_v5e(one_chip, no_persistent_cache, case, monkeypatch):
+    """Each kernel compiles at a benchmark cell's widths, at the k_blk
+    ``choose_k_blk`` picks on the chip, int8, under the Scheduler's slot
+    vmap: one call on all the slots' rows, named after the kernel."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_VUSA_KBLK", raising=False)
+    kw = dict(m=M, k_blk=ops.choose_k_blk(CELL_D, SLOTS, M), interpret=False,
+              value_dtype="int8")
+    rows = 64 if case == "moonlight_experts" else BATCH
+    x = jax.ShapeDtypeStruct((rows, 1, CELL_D), jnp.bfloat16, sharding=one_chip)
+    if case == "moonlight_experts":
+        def pack(lead):
+            return (
+                jax.ShapeDtypeStruct(lead + (CELL_D, SLOTS), jnp.int8, sharding=one_chip),
+                jax.ShapeDtypeStruct(lead + (CELL_D, SLOTS), jnp.int8, sharding=one_chip),
+                jax.ShapeDtypeStruct(lead + (CELL_D,), jnp.float32, sharding=one_chip),
+            )
+
+        v, p, s = pack((EXPERTS, -(-D_EXPERT // M)))
+        w = jax.ShapeDtypeStruct((rows, 1, EXPERTS), jnp.float32, sharding=one_chip)
+
+        def call(x, w, v, p, s):
+            return vusa_fused_moe_matmul(x, w, v, p, v, p, v, p, s, s, s, **kw)
+
+        fn, args, want = (jax.vmap(call, in_axes=(0, 0, None, None, None)),
+                          (x, w, v, p, s), "vusa_fused_moe_matmul")
+    else:
+        t = CELL_FF // M if case == "internlm2_mlp" else -(-CELL_VOCAB // M)
+        v, p, s = _pack_shapes(one_chip, t, CELL_D, "int8")
+        if case == "internlm2_mlp":
+            def call(x, v, p, s):
+                return vusa_fused_mlp_matmul(x, v, p, v, p, v, p, s, s, s, **kw)
+
+            want = "vusa_fused_mlp_matmul"
+        else:
+            def call(x, v, p, s):
+                return vusa_packed_matmul(x, v, p, s, name="vusa_packed_matmul_head", **kw)
+
+            want = "vusa_packed_matmul_head"
+        fn, args = jax.vmap(call, in_axes=(0, None, None, None)), (x, v, p, s)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = _tpu_calls(text)
+    assert len(calls) == 1, calls
+    (call_name, x_shape), = calls
+    assert re.fullmatch(rf"{want}(\.\d+)?", call_name), calls
+    assert x_shape == (rows, CELL_D), calls
